@@ -181,6 +181,22 @@ def _xla_attention(q, k, v, *, causal: bool, q_offset: int = 0,
     return out.reshape(b, h, tq, dh).transpose(0, 2, 1, 3)
 
 
+def attention_path(cfg: LlamaConfig, t: int) -> dict:
+    """The attention inner a length-``t`` call traces to on this backend:
+    ``{"impl": "pallas" | "xla", "interpret": bool | None}`` (``interpret``
+    is the Pallas mode, None on the XLA path). ``attention`` dispatches on
+    it and the trainers write it into the run manifest, so a run records
+    whether its step was built with the compiled flash kernel."""
+    use_pallas = cfg.attention_impl == "pallas" or (
+        cfg.attention_impl == "auto"
+        and t >= cfg.flash_min_seq
+        and jax.default_backend() == "tpu")
+    if not use_pallas:
+        return {"impl": "xla", "interpret": None}
+    from ..ops.flash_attention import default_interpret
+    return {"impl": "pallas", "interpret": default_interpret()}
+
+
 def attention(block: dict, x: jnp.ndarray, cfg: LlamaConfig,
               cos: jnp.ndarray, sin: jnp.ndarray,
               attn_fn: Optional[Callable] = None,
@@ -199,18 +215,16 @@ def attention(block: dict, x: jnp.ndarray, cfg: LlamaConfig,
     h_local = q.shape[2]                             # = num_heads / tp_size
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
-    use_pallas = cfg.attention_impl == "pallas" or (
-        cfg.attention_impl == "auto"
-        and t >= cfg.flash_min_seq
-        and jax.default_backend() == "tpu")
+    path = attention_path(cfg, t)
     if attn_fn is not None:
         out = attn_fn(q, k, v)
-    elif use_pallas:
+    elif path["impl"] == "pallas":
         from ..ops.flash_attention import flash_attention
         blk = min(t, cfg.flash_block)
         out = flash_attention(q, k, v, causal=True,
                               dh_major=cfg.flash_dh_major,
-                              block_q=blk, block_k=blk)
+                              block_q=blk, block_k=blk,
+                              interpret=path["interpret"])
     else:
         out = _xla_attention(q, k, v, causal=True,
                              softmax_dtype=cfg.softmax_dtype)

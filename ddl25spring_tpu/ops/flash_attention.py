@@ -25,8 +25,13 @@ Design notes (see /opt/skills/guides/pallas_guide.md):
 - Causal blocks strictly above the diagonal are skipped via `pl.when`
   (predicated out — no FLOPs), and their block index maps are clamped so the
   pipeline elides the HBM fetch entirely.
-- On non-TPU backends `interpret=True` keeps tests runnable on the virtual
-  CPU mesh; production CPU paths should use the XLA einsum attention
+- ``interpret`` is the caller's to name. Left at None it follows the
+  backend (``default_interpret``): compiled on a TPU, interpreted elsewhere,
+  which keeps the CPU tests runnable. Whatever must prove the Mosaic
+  lowering (``chip_smoke.py``, ``tests/test_tpu_compile.py``) passes
+  ``interpret=False`` itself, and the trainers record the mode their step
+  was built with (``models/llama.attention_path`` -> run manifest).
+  Production CPU paths should use the XLA einsum attention
   (models/llama._xla_attention) instead.
 """
 
@@ -42,6 +47,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 _LANES = 128
 _NEG_INF = -1e30
+
+
+def default_interpret() -> bool:
+    """Pallas interpret mode for a caller that names none: False (the
+    compiled kernel) on a TPU backend, True elsewhere."""
+    return jax.default_backend() != "tpu"
 
 
 # ------------------------------------------------------------------ forward
@@ -706,7 +717,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     measured comparison.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     if dh_major:
         return _flash_t(q, k, v, causal, block_q, block_k, interpret)
     return _flash(q, k, v, causal, block_q, block_k, interpret)

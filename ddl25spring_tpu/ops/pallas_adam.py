@@ -27,7 +27,8 @@ least 64 K go through the kernel reshaped to [N/512, 512] lanes-dense tiles;
 everything else (norm vectors, odd shapes, non-fp32) falls back to the jnp
 rule. At the canonical 288/6/6 config the kernel covers >99.9 % of the 24 M
 parameters. Semantics match ``optax.adam`` within float re-association
-(asserted in tests/test_pallas_adam.py, interpret mode on CPU).
+(asserted in tests/test_pallas_adam.py, interpret mode on CPU; against
+``ops.adam.fused_adam`` with the compiled kernel in ``chip_smoke.py``).
 """
 
 from __future__ import annotations
@@ -41,6 +42,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .adam import FusedAdamState, adam_leaf_math, fused_adam
+from .flash_attention import default_interpret
 
 _LANES = 512          # flattened-leaf row width: 4 × the 128-lane vector
 _ROW_BLOCK = 512      # rows per grid step → 1 MB fp32 per operand block
@@ -99,41 +101,6 @@ def _leaf_jnp(p, m, v, g, c1, c2, *, lr, b1, b2, eps):
     return p + u, m, v
 
 
-def smoke_check(atol: float = 1e-5) -> None:
-    """One-step Mosaic-lowering smoke: run the compiled kernel (interpret
-    only if off-TPU) on one eligible leaf and assert it matches the jnp
-    rule. The bench gates the '+padam' variant on this so a kernel whose
-    actual TPU lowering is wrong can never produce a trusted number —
-    interpret-mode CPU tests exercise the math, not the lowering.
-    Raises on mismatch; returns None when the kernel is trustworthy."""
-    key = jax.random.key(0)
-    kp, km, kv, kg = jax.random.split(key, 4)
-    # 972 rows of 512 lanes: >_ROW_BLOCK rows forces a multi-step grid with
-    # a ragged last block — the configuration the real 24 M-param leaves
-    # hit (e.g. the 6×288×288 stack is rows=972) — so the gate exercises
-    # index_map stepping, cross-step scalar prefetch, and multi-block
-    # aliasing, not just a single-block lowering.
-    shape = (972 * _LANES,)
-    p = jax.random.normal(kp, shape, jnp.float32)
-    m = 0.1 * jax.random.normal(km, shape, jnp.float32)
-    v = jnp.abs(0.1 * jax.random.normal(kv, shape, jnp.float32))
-    g = jax.random.normal(kg, shape, jnp.float32)
-    hyper = dict(lr=1e-3, b1=0.9, b2=0.999, eps=1e-8)
-    c1, c2 = 1.0 - 0.9 ** 3, 1.0 - 0.999 ** 3
-    corrections = jnp.asarray([c1, c2], jnp.float32)
-    interpret = jax.default_backend() != "tpu"
-    got = _adam_leaf_pallas(p, m, v, g, corrections, interpret=interpret,
-                            **hyper)
-    want = _leaf_jnp(p, m, v, g, c1, c2, **hyper)
-    for name, a, b in zip(("p", "m", "v"), got, want):
-        err = float(jnp.max(jnp.abs(a - b)))
-        if not err <= atol:      # NaN-safe: NaN fails the comparison
-            raise AssertionError(
-                f"pallas Adam smoke: {name} max|Δ|={err:.3e} > {atol} on "
-                f"backend {jax.default_backend()!r} — kernel lowering is "
-                "not trustworthy")
-
-
 def _pallas_eligible(p, g) -> bool:
     return (p.dtype == jnp.float32 and g.dtype == jnp.float32
             and p.size >= _MIN_PALLAS and p.size % _LANES == 0)
@@ -152,8 +119,10 @@ class FusedApplyAdam:
                  b2: float = 0.999, eps: float = 1e-8,
                  interpret: Optional[bool] = None):
         self.lr, self.b1, self.b2, self.eps = learning_rate, b1, b2, eps
-        # interpret=None: resolved at trace time — pallas interpret mode off
-        # TPU keeps the same code path testable on the virtual CPU mesh.
+        # interpret=None follows the backend at trace time
+        # (flash_attention.default_interpret): interpreted off TPU, which
+        # keeps the CPU tests runnable. chip_smoke.py and
+        # tests/test_tpu_compile.py pass False themselves.
         self.interpret = interpret
         self._fallback = fused_adam(learning_rate, b1, b2, eps)
 
@@ -166,8 +135,8 @@ class FusedApplyAdam:
 
     # ---- fused fast path -----------------------------------------------
     def apply_gradients(self, params, grads, state: FusedAdamState):
-        interpret = (jax.default_backend() != "tpu"
-                     if self.interpret is None else self.interpret)
+        interpret = (default_interpret() if self.interpret is None
+                     else self.interpret)
         count = state.count + 1
         cf = count.astype(jnp.float32)
         c1 = 1.0 - self.b1 ** cf

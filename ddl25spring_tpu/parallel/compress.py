@@ -42,11 +42,10 @@ from typing import Any, Callable, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 import optax
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..telemetry import comm
-from ._compat import axis_size, shard_map
 
 from .dp import TrainState, apply_optimizer, init_state, replicate
 
@@ -287,7 +286,7 @@ def ring_reduce_scatter(x, axis_name: str, *, wire: str = "fp32",
         # place of accumulated EF state (the write-back below only covers
         # the int8 schedule).
         raise ValueError(f"residual is int8_ef-only (got wire={wire!r})")
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return x, residual
     chunk = x.shape[0] // n

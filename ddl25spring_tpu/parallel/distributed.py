@@ -28,16 +28,7 @@ from .mesh import AXES
 
 
 def _is_initialized() -> bool:
-    """``jax.distributed.is_initialized()`` with a fallback for jax builds
-    that predate it (same API-drift posture as parallel/_compat.py): the
-    distributed client living in jax's global state is the signal."""
-    if hasattr(jax.distributed, "is_initialized"):
-        return bool(jax.distributed.is_initialized())
-    try:
-        from jax._src.distributed import global_state
-        return global_state.client is not None
-    except Exception:
-        return False
+    return bool(jax.distributed.is_initialized())
 
 
 def initialize(coordinator_address: Optional[str] = None,

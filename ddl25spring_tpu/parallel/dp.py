@@ -32,13 +32,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.adam import apply_optimizer  # noqa: F401  (canonical home moved;
 #                                         re-exported for existing callers)
 from ..telemetry import comm
-from ._compat import shard_map
 
 
 class TrainState(NamedTuple):

@@ -22,11 +22,10 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import optax
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..telemetry import comm
-from ._compat import shard_map
 
 from ..config import MoEConfig
 from ..models import moe

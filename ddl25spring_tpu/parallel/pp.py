@@ -44,11 +44,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..telemetry import comm
-from ._compat import shard_map
 
 from ..config import LlamaConfig
 from ..models import llama

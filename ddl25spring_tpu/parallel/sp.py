@@ -42,11 +42,10 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import optax
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..telemetry import comm
-from ._compat import axis_size, shard_map
 
 from ..config import LlamaConfig
 from ..models import llama
@@ -72,7 +71,7 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     ppermutes below already self-scale by the ring length; the backward
     ring autodiff synthesizes is the documented under-count).
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     s = lax.axis_index(axis_name)
     b, tl, h, dh = q.shape
     scale = 1.0 / (dh ** 0.5)

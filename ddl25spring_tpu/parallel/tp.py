@@ -33,12 +33,11 @@ from typing import Any, Callable, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import optax
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import nn
 from ..telemetry import comm
-from ._compat import shard_map
 
 from ..config import LlamaConfig
 from ..models import llama

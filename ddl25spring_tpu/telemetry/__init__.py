@@ -10,7 +10,7 @@ One observability subsystem the whole stack reports through:
   collectives in parallel/{dp,tp,sp,ep,pp,compress}.py — bytes per
   psum/all-gather per step, computed statically, zero in-jit overhead.
 - ``costs``: compiled-HLO cost analysis via lower().compile()
-  .cost_analysis(), guarded for jax API drift; cross-checks bench.py's
+  .cost_analysis(); cross-checks bench.py's
   analytic FLOPs.
 - ``memory``: unified memory observability (schema v9) — guarded
   ``memory_analysis()`` program footprints, the jax-free ``MemoryMeter``
@@ -39,7 +39,7 @@ from .events import (EventLog, SCHEMA_VERSION, default_run_id, read_events,
                      validate_event)
 from .heartbeat import Heartbeat, read_heartbeat
 from .introspect import (CompileWatch, FlightRecorder, NumericsSummary,
-                         bind_events, make_summarizer, platform_peaks,
+                         bind_events, device_peaks, make_summarizer,
                          watch)
 from .memory import (MemoryMeter, allocator_census, compiled_memory,
                      host_rss_bytes, preflight, program_memory)
@@ -66,8 +66,8 @@ __all__ = [
     "SCHEMA_VERSION",
     "Span", "SpanContext", "Spans", "Telemetry", "Tracer",
     "allocator_census", "bind_events", "compiled_memory",
-    "default_run_id", "device_trace", "flops_crosscheck", "hlo_cost",
-    "host_rss_bytes", "make_summarizer", "measure_comm", "platform_peaks",
+    "default_run_id", "device_peaks", "device_trace", "flops_crosscheck",
+    "hlo_cost", "host_rss_bytes", "make_summarizer", "measure_comm",
     "preflight", "program_memory", "read_events",
     "read_heartbeat", "trace_trees", "tree_check", "validate_event", "watch",
 ]

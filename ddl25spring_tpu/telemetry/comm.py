@@ -64,22 +64,14 @@ class CommRecord:
     op: str                  # pmean | psum | pmax | all_gather | ...
     label: str               # call-site semantic name ("grad_allreduce", ...)
     axis: str                # mesh axis name
-    axis_size: Optional[int]  # None when not resolvable at trace time
+    axis_size: int           # participants on the axis, static at trace time
     payload_bytes: int       # local operand bytes in the wire dtype
     scale: int               # executions per step (scan trip count, ...)
 
     @property
     def wire_bytes_per_device(self) -> float:
-        """Ring-algorithm per-device wire estimate for ONE execution.
-
-        An unresolvable axis size (both `_axis_size` probes failed — future
-        API drift) must NOT silently zero the reduce factors the way n=1
-        legitimately does: report factor 1.0 (within 2x of any real ring
-        reduce) and let the record's ``axis_size: None`` flag the estimate
-        as degraded."""
+        """Ring-algorithm per-device wire estimate for ONE execution."""
         n = self.axis_size
-        if n is None:
-            return float(self.payload_bytes)
         if self.op in ("pmean", "psum", "pmax"):
             factor = 2.0 * (n - 1) / n
         elif self.op == "all_gather":
@@ -217,29 +209,13 @@ def tree_bytes(tree: Any) -> int:
 _tree_bytes = tree_bytes          # internal alias (pre-v3 call sites)
 
 
-def _axis_size(axis_name: str) -> Optional[int]:
-    """Static axis size at trace time, across this jax's API drift
-    (0.4.37: ``core.axis_frame(name)`` returns a plain int; newer builds
-    have ``lax.axis_size``; see parallel/_compat.py, not imported here to
-    keep telemetry dependency-free of the parallel layer)."""
-    try:
-        return int(lax.axis_size(axis_name))          # newer jax
-    except Exception:
-        pass
-    try:
-        frame = jax.core.axis_frame(axis_name)        # jax 0.4.37
-        return int(getattr(frame, "size", frame))
-    except Exception:
-        return None
-
-
 def _record(op: str, label: Optional[str], axis_name: str, operand: Any,
             scale: int) -> None:
     col = _collector.get()
     if col is None:
         return
     col.append(CommRecord(op=op, label=label or op, axis=axis_name,
-                          axis_size=_axis_size(axis_name),
+                          axis_size=int(lax.axis_size(axis_name)),
                           payload_bytes=_tree_bytes(operand),
                           scale=int(scale)))
 

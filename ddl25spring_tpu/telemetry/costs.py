@@ -1,19 +1,13 @@
-"""Compiled-HLO cost accounting, guarded for jax API drift.
+"""Compiled-HLO cost accounting.
 
 `bench.py` computes MFU from a hand-derived analytic FLOP formula
 (``train_step_flops_per_token``). This module pulls the OTHER source of
 truth — XLA's own cost model for the compiled step, via
-``jitted.lower(...).compile().cost_analysis()`` — so the two can
-cross-check each other. The API has drifted across jax versions (dict vs
-list-of-dicts results, methods missing on some backends, backends that
-return None), so everything here follows the repo's version-shim precedent
-(parallel/_compat.py, experiments/_cpu_pin.py): probe, normalize, and
-degrade to None rather than crash — a bench must never die because a
-jaxlib can't count its own FLOPs.
-
-On this container's jax 0.4.37 / jaxlib 0.4.36 CPU backend,
-``cost_analysis()`` returns ``[{"flops": ..., "bytes accessed": ...}]``
-(verified; tests/test_telemetry.py pins the guard behavior).
+``jitted.lower(...).compile().cost_analysis()`` (a dict with ``"flops"`` and
+``"bytes accessed"``) — so the two can cross-check each other. A callable
+that is not jitted, a program that does not compile and a backend that
+reports no count (``-1``) give None rather than an exception: the callers
+are observers (CompileWatch, reports) that must not sink what they observe.
 """
 
 from __future__ import annotations
@@ -26,7 +20,7 @@ def hlo_cost(jitted_fn, *args, **kwargs) -> Optional[dict]:
 
     Returns ``{"flops": float, "bytes_accessed": float | None}`` or None
     when any link of the lower→compile→cost_analysis chain is unavailable
-    on this jax/jaxlib/backend. Arguments may be real pytrees or
+    (module docstring). Arguments may be real pytrees or
     ``jax.ShapeDtypeStruct``s. NOTE: compiles the program if it isn't
     already — call where a compile is acceptable (bench/report time), not
     on a hot path.
@@ -53,28 +47,16 @@ def compiled_cost(compiled) -> Optional[dict]:
 
 
 def _normalize(analysis: Any) -> Optional[dict]:
-    """list-of-dicts (one per partition; 0.4.x) or plain dict → one dict."""
-    if isinstance(analysis, (list, tuple)):
-        analysis = analysis[0] if analysis else None
+    """``cost_analysis()``'s dict → ``{"flops", "bytes_accessed"}``."""
     if not isinstance(analysis, dict):
         return None
     flops = analysis.get("flops")
-    if flops is None:
+    if flops is None or float(flops) < 0:  # some backends report -1
         return None
-    try:
-        flops = float(flops)
-    except (TypeError, ValueError):
-        return None
-    if flops < 0:                          # some backends report -1
-        return None
-    bytes_accessed = analysis.get("bytes accessed",
-                                  analysis.get("bytes_accessed"))
-    try:
-        bytes_accessed = (float(bytes_accessed)
-                          if bytes_accessed is not None else None)
-    except (TypeError, ValueError):
-        bytes_accessed = None
-    return {"flops": flops, "bytes_accessed": bytes_accessed}
+    bytes_accessed = analysis.get("bytes accessed")
+    return {"flops": float(flops),
+            "bytes_accessed": (float(bytes_accessed)
+                               if bytes_accessed is not None else None)}
 
 
 def flops_crosscheck(analytic_flops: float, hlo: Optional[dict],
@@ -85,7 +67,7 @@ def flops_crosscheck(analytic_flops: float, hlo: Optional[dict],
     - ``"hlo"`` when the compiled-program count is available and within
       ``tolerance`` relative error of the analytic formula — the formula is
       then cross-checked by the compiler;
-    - ``"analytic"`` when cost_analysis is unavailable on this jaxlib or
+    - ``"analytic"`` when cost_analysis is unavailable or
       the two diverge beyond tolerance (caller should warn: either the
       formula or the lowering changed).
 

@@ -19,9 +19,10 @@ breaches, StepGuard skips); this module says *why*:
   ``costs.hlo_cost`` and emits a ``compile`` event (schema v5) — with a
   retrace detector for factories whose documented invariant is ONE
   compiled program (serving's two engine steps, fleet's cohort steps).
-- **Attainment accounting** (``platform_peaks``): the roofline
-  denominators — ROOFLINE.md's measured chip peaks, or a calibrated CPU
-  baseline on fallback — land in the run manifest so obs_report /
+- **Attainment accounting** (``device_peaks``): the roofline
+  denominators — the chip's published peaks by ``device_kind``, or a
+  calibrated baseline for the CPU the tests run on — land in the run
+  manifest so obs_report /
   slo_monitor can turn (compile event flops, span/step durations) into
   achieved FLOP/s, HBM GB/s and MFU without jax.
 - **Anomaly flight recorder** (``FlightRecorder``): a bounded ring of
@@ -283,7 +284,7 @@ class CompileWatch:
     into ``compile`` events.
 
     Detection is ``_cache_size()`` growth across a call (eval_shape /
-    ``lower().compile()`` do not grow it on this jaxlib — probed), so the
+    ``lower().compile()`` do not grow it), so the
     steady-state overhead is one int comparison per dispatch. On growth:
     the call's wall time is recorded, the program is costed via
     ``costs.compiled_cost`` AND byte-accounted via
@@ -420,11 +421,13 @@ def bind_events(fn, events) -> None:
 
 # ------------------------------------------------------ roofline peaks
 
-# ROOFLINE.md's measured TPU v5e (lite) peaks — the denominators every
-# attainment number in this repo is quoted against.
-PLATFORM_PEAKS: Dict[str, Dict[str, Any]] = {
-    "tpu": {"flops_per_sec": 197e12, "hbm_bytes_per_sec": 819e9,
-            "source": "ROOFLINE.md (TPU v5e, bf16 peak / HBM)"},
+# Published per-chip peaks, keyed by ``jax.Device.device_kind`` — the
+# denominators every attainment number in this repo is quoted against.
+# A device that is not in the table is an error, not a default.
+DEVICE_PEAKS: Dict[str, Dict[str, Any]] = {
+    "TPU v5 lite": {"flops_per_sec": 197e12, "hbm_bytes_per_sec": 819e9,
+                    "source": 'Google Cloud documentation, "TPU v5e" '
+                              "(bf16 peak / HBM bandwidth)"},
 }
 
 _cpu_peak_cache: Dict[str, Any] = {}
@@ -432,7 +435,7 @@ _cpu_peak_cache: Dict[str, Any] = {}
 
 def calibrate_cpu_peak(*, n: int = 384, repeats: int = 3) -> Dict[str, Any]:
     """Measured-not-guessed CPU roofline: time a small f32 matmul chain
-    and report achieved FLOP/s — the calibrated baseline CPU-fallback
+    and report achieved FLOP/s — the calibrated baseline a CPU test run's
     attainment is quoted against (an absolute-peak claim for an
     oversubscribed CI host would be fiction; a measured one is a fair
     yardstick). Cached per process; ~10 ms."""
@@ -459,15 +462,23 @@ def calibrate_cpu_peak(*, n: int = 384, repeats: int = 3) -> Dict[str, Any]:
     return dict(_cpu_peak_cache)
 
 
-def platform_peaks(platform: str) -> Dict[str, Any]:
-    """Roofline denominators for ``platform`` ("tpu"/"cpu"/...). Known
-    accelerators come from ``PLATFORM_PEAKS`` (ROOFLINE.md); anything
-    else gets the calibrated CPU baseline. Lands in the run manifest so
-    jax-free readers (obs_report, slo_monitor) never re-derive it."""
-    peaks = PLATFORM_PEAKS.get(platform)
-    if peaks is not None:
-        return dict(peaks)
-    return calibrate_cpu_peak()
+def device_peaks(device) -> Dict[str, Any]:
+    """Roofline denominators for ``device`` (a ``jax.Device``): the
+    published peaks of its ``device_kind`` from ``DEVICE_PEAKS``. The CPU
+    the tests run on gets the calibrated baseline, labelled as such in its
+    ``source``; no accelerator ever does — an accelerator kind that is not
+    in the table raises. Lands in the run manifest so jax-free readers
+    (obs_report, slo_monitor) never re-derive it."""
+    if device.platform == "cpu":
+        return calibrate_cpu_peak()
+    peaks = DEVICE_PEAKS.get(device.device_kind)
+    if peaks is None:
+        raise ValueError(
+            f"no published peaks for device kind {device.device_kind!r} "
+            f"(platform {device.platform!r}): add it to "
+            "telemetry/introspect.py DEVICE_PEAKS with its source before "
+            "quoting a utilisation on it")
+    return dict(peaks)
 
 
 def attainment(flops: Optional[float], bytes_accessed: Optional[float],

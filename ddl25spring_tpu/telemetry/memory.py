@@ -6,11 +6,10 @@ pieces sharing one schema-v9 ``memory`` event shape:
 
 - **Static program footprint** — ``program_memory`` /
   ``compiled_memory`` pull ``compiled.memory_analysis()`` (argument /
-  output / temp / generated-code bytes) behind ONE API-drift guard,
-  following ``costs.hlo_cost``'s probe-normalize-degrade idiom: the
-  jaxlib 0.4.x ``CompiledMemoryStats`` attribute names are probed, a
-  missing method or a backend that can't account returns None, never a
-  crash. ``introspect.CompileWatch`` stamps these onto every ``compile``
+  output / temp / generated-code bytes) through ONE reader: a backend
+  that can't account a field reports it negative and the field is
+  dropped; one that accounts nothing gives None.
+  ``introspect.CompileWatch`` stamps these onto every ``compile``
   event; the two benches that used to call ``memory_analysis()`` ad hoc
   (sp_bench, pp_schedules) route through here.
 - **Live accounting** — ``MemoryMeter``, a jax-free sampler emitting one
@@ -46,11 +45,10 @@ from __future__ import annotations
 import sys
 from typing import Any, Dict, Optional
 
-# jaxlib 0.4.36 CompiledMemoryStats attribute names (verified on this
-# container), probed one by one so a partial drift degrades field-wise
-# instead of all-or-nothing. ``alias`` counts donated input buffers that
-# XLA reuses for outputs — subtracted from the peak total below so a
-# donated-state trainer is not double-billed for its state.
+# CompiledMemoryStats attribute names. A field a backend does not account
+# is negative and is dropped on its own. ``alias`` counts donated input
+# buffers that XLA reuses for outputs — subtracted from the peak total
+# below so a donated-state trainer is not double-billed for its state.
 _STAT_FIELDS = (
     ("argument_bytes", "argument_size_in_bytes"),
     ("output_bytes", "output_size_in_bytes"),
@@ -67,18 +65,11 @@ _DEVICE_COMPONENTS = ("params_bytes", "opt_state_bytes", "residual_bytes",
 
 
 def compiled_memory(compiled) -> Optional[dict]:
-    """Static footprint of an ALREADY-compiled program, or None when this
-    jaxlib/backend can't account it. The one API-drift guard the repo's
-    three ``memory_analysis()`` call sites share (CompileWatch, sp_bench,
+    """Static footprint of an ALREADY-compiled program, or None when the
+    backend can't account it. Shared by the repo's three
+    ``memory_analysis()`` call sites (CompileWatch, sp_bench,
     pp_schedules)."""
-    fn = getattr(compiled, "memory_analysis", None)
-    if fn is None:
-        return None
-    try:
-        stats = fn()
-    except Exception:
-        return None
-    return _normalize_stats(stats)
+    return _normalize_stats(compiled.memory_analysis())
 
 
 def program_memory(jitted_fn, *args, **kwargs) -> Optional[dict]:
@@ -100,24 +91,13 @@ def program_memory(jitted_fn, *args, **kwargs) -> Optional[dict]:
 
 
 def _normalize_stats(stats: Any) -> Optional[dict]:
-    """CompiledMemoryStats (attrs) or a dict (hypothetical drift) → one
-    flat dict of floats; None when nothing usable was reported."""
-    if isinstance(stats, (list, tuple)):
-        stats = stats[0] if stats else None
-    if stats is None:
-        return None
+    """``CompiledMemoryStats`` → one flat dict of floats; None when the
+    backend reported nothing usable (None, or every field negative)."""
     out: Dict[str, Any] = {}
     for name, attr in _STAT_FIELDS:
-        if isinstance(stats, dict):
-            v = stats.get(attr, stats.get(name))
-        else:
-            v = getattr(stats, attr, None)
-        try:
-            v = float(v) if v is not None else None
-        except (TypeError, ValueError):
-            v = None
+        v = getattr(stats, attr, None)
         if v is not None and v >= 0:
-            out[name] = v
+            out[name] = float(v)
     if not any(k in out for k, _ in _STAT_FIELDS[:3]):
         return None                       # no byte accounting at all
     # Peak device residency of one dispatch: inputs + outputs + transients

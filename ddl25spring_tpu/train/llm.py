@@ -182,19 +182,25 @@ def _emit_manifest(telemetry, *, trainer: str, model_cfg, train_cfg,
             if profile is not None else None)
     except Exception:
         pass                       # telemetry must never sink a trainer
-    platform = jax.devices()[0].platform
+    device = mesh.devices.flat[0]
     telemetry.events.manifest(
         trainer=trainer, jax_version=jax.__version__,
-        platform=platform, n_devices=len(jax.devices()),
+        platform=device.platform, device_kind=device.device_kind,
+        n_devices=len(jax.devices()),
         mesh={k: int(v) for k, v in mesh.shape.items()},
         model_cfg=dataclasses.asdict(model_cfg),
         train_cfg=dataclasses.asdict(train_cfg),
         start_step=start_step, comm=comm_profile,
-        # Roofline denominators (introspect.platform_peaks: ROOFLINE.md's
-        # measured chip peaks, or a calibrated CPU baseline) — recorded
-        # HERE so the jax-free readers (obs_report's attainment section,
-        # slo_monitor's MFU floor) never have to re-derive them.
-        peaks=introspect.platform_peaks(platform),
+        # Which attention inner the step was built with, and in which
+        # Pallas mode (llama.attention_path — the same call the model
+        # dispatches on): a run that missed the compiled flash kernel
+        # says so here.
+        attention=llama.attention_path(model_cfg, train_cfg.seq_len),
+        # Roofline denominators (introspect.device_peaks: the chip's
+        # published peaks by device_kind, an unknown accelerator raises) —
+        # recorded HERE so the jax-free readers (obs_report's attainment
+        # section, slo_monitor's MFU floor) never have to re-derive them.
+        peaks=introspect.device_peaks(device),
         # Preflight fit estimate (telemetry/memory.py, schema v9): the
         # predicted per-device byte budget, recorded next to the comm
         # profile so obs_report's memory section can table

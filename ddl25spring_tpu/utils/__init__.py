@@ -1,5 +1,2 @@
-# Deliberately NO eager submodule imports: utils.probe must be importable
-# without pulling jax into the process (bench.py probes the platform in a
-# subprocess BEFORE its own jax import; an import-time accelerator-runtime
-# wedge would otherwise hang the caller). Import submodules explicitly:
+# No eager submodule imports: import submodules explicitly, e.g.
 # ``from ddl25spring_tpu.utils import pytree``.

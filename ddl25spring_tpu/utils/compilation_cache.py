@@ -1,65 +1,30 @@
-"""Persistent XLA compilation-cache enablement, gated per jaxlib version.
+"""Persistent XLA compilation cache, placed from outside or at one fixed path.
 
-The tier-1 suite compiles the same tiny-model programs over and over across
-test processes; the persistent cache (``jax_compilation_cache_dir``) turns
-those recompiles into disk loads (~28% wall-time measured on the suite) —
-relief the 870 s CI budget needs.
-
-It is NOT safe everywhere: on jaxlib 0.4.36 (this container) reloading a
-cached executable whose input buffers are donated SEGFAULTS the CPU
-backend — reproduced in the trainer-resume tests, and every step factory in
-parallel/ donates its state. So enablement is gated on the jaxlib version:
-known-bad 0.4.x builds decline and run exactly as before; newer builds
-(CI installs current jax) get the cache. One probe, one place — the same
-degrade-don't-abort posture as experiments/_cpu_pin.py's XLA-flag probe.
+The cache's directory is part of its key, so a directory that moves never
+hits. Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and this
+module sets no other; where it is not, the cache lives at ``<repo>/.jax_cache``
+(git-ignored), the same path for every process of a checkout. The tests
+(``tests/conftest.py``), ``bench.py`` and ``chip_smoke.py`` all go through
+``enable_compilation_cache``.
 """
 
 from __future__ import annotations
 
 import os
-import tempfile
-from typing import Optional
 
-# First generation where the donated-input reload path is trusted. 0.4.36
-# is reproducibly bad (see module docstring); no 0.4.x build has been
-# cleared, so the gate is conservative: 0.5+ only.
-_MIN_SAFE = (0, 5, 0)
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+DEFAULT_CACHE_DIR = os.path.join(_REPO_ROOT, ".jax_cache")
 
 
-def _jaxlib_version() -> tuple:
-    try:
-        import jaxlib
-        return tuple(int(p) for p in jaxlib.__version__.split(".")[:3])
-    except Exception:
-        return (0, 0, 0)
+def enable_compilation_cache() -> str:
+    """Turn the persistent compilation cache on; returns the directory in
+    use. Call before the first compilation."""
+    import jax
 
-
-def compilation_cache_supported() -> bool:
-    """True when this jaxlib is trusted to reload donated-input executables
-    from the persistent cache without crashing (see module docstring)."""
-    return _jaxlib_version() >= _MIN_SAFE
-
-
-def enable_compilation_cache(cache_dir: Optional[str] = None) -> Optional[str]:
-    """Enable the persistent compilation cache when safe on this jaxlib.
-
-    ``cache_dir`` defaults to ``$DDL25_COMPILATION_CACHE_DIR`` or a stable
-    path under the system tempdir (stable, so separate test/bench processes
-    in one session share warm entries; CI scopes it to the runner's
-    tempdir via the env var). Returns the directory in use, or None when
-    the gate declined — callers treat None as "run exactly as before".
-    Never raises: cache trouble must not sink a test session or a bench.
-    """
-    if not compilation_cache_supported():
-        return None
-    try:
-        import jax
-        cache_dir = (cache_dir
-                     or os.environ.get("DDL25_COMPILATION_CACHE_DIR")
-                     or os.path.join(tempfile.gettempdir(),
-                                     "ddl25-xla-cache"))
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        return cache_dir
-    except Exception:
-        return None
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    os.makedirs(DEFAULT_CACHE_DIR, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+    return DEFAULT_CACHE_DIR

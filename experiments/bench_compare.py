@@ -1,24 +1,20 @@
-"""Perf-trajectory comparator over committed BENCH_r*.json headlines.
+"""Perf-trajectory comparator over bench headline JSON files.
 
-The repo commits one ``BENCH_rNN.json`` per growth round (the driver's
-wrapper: ``{"parsed": {headline row}, "tail": <bench.py stdout>, ...}``)
-plus ``BASELINE.json``; tier1.yml additionally produces a per-PR
-``bench-headline.json`` (raw ``bench.py`` stdout in DDL25_BENCH_QUICK
-mode). This tool — pure stdlib, no jax — reads any mix of those formats,
-prints the trajectory per (metric, platform, variant) group, and exits
-nonzero when the newest comparable row regresses more than
-``--max-regression`` percent against the best committed row of the SAME
-platform tag: CPU-fallback numbers must never be judged against a TPU
-row (the committed history mixes both — see ROADMAP "Perf trajectory").
+Reads any mix of the driver's per-round wrapper format
+(``{"parsed": {headline row}, "tail": <bench.py stdout>, ...}``),
+``BASELINE.json`` and raw ``bench.py`` stdout. (The ``BENCH_rNN.json``
+files it used to default to were deleted in PR 21, and tier1.yml no longer
+runs ``bench.py``: name the files to compare.) This tool — pure stdlib, no
+jax — prints the trajectory per (metric, platform, variant) group, and
+exits nonzero when the newest comparable row regresses more than
+``--max-regression`` percent against the best row of the SAME platform
+tag: a CPU number must never be judged against a TPU row.
 Rows are direction-aware: throughput-like metrics regress downward, while
 ``wire_bytes_*`` / ``payload_bytes_*`` rows (the comm-wire smoke's) are
 lower-is-better and gate when the candidate RISES above the best (lowest)
 committed row — see ``lower_is_better``.
 
-``--warn-only`` (how tier1.yml runs it, over the reduced bench smoke)
-prints the verdict but always exits 0: the QUICK-mode smoke is noisy by
-design, so CI gets visibility without a flaky gate; the strict mode is
-for hardware rounds.
+``--warn-only`` prints the verdict but always exits 0.
 
 Example:
     python -m experiments.bench_compare --candidate bench-headline.json \\

@@ -11,10 +11,10 @@ roughly constant per step, so the tokens/s column shows how throughput decays
 as T grows — i.e. what the O(T^2) attention leg costs in a real step when
 the rest of the step is O(T).
 
-Each (seq, attention) point runs in a subprocess with a hard timeout (same
-wedge-proofing as bench.py: libtpu is single-client and this platform fails
-by hanging). Results -> ``experiments/results/longctx_bench.csv`` with a
-``platform`` column; rows are only claim-bearing when it says tpu.
+One process times every (seq, attention) point in turn and fails without a
+TPU; a failed point ends the run. Results ->
+``experiments/results/longctx_bench.csv`` with ``platform`` and
+``device_kind`` columns as JAX reports them.
 
 Run (on the chip):
     python -m experiments.longctx_bench
@@ -23,7 +23,7 @@ Run (on the chip):
 from __future__ import annotations
 
 import argparse
-import subprocess
+import dataclasses
 import sys
 
 # (seq_len, per-step batch): ~16k tokens/step at every row, the measured
@@ -40,49 +40,32 @@ VARIANTS = {
 }
 
 
-def _child(variant: str, seq: int, batch: int) -> None:
-    """Time one (variant, seq) train-step point; print 'tok/s step_ms'."""
+def main(quick: bool = False) -> None:
     import jax
-
-    if jax.default_backend() not in ("tpu",):
-        print("no accelerator in child", file=sys.stderr)
-        sys.exit(3)
-    import dataclasses
 
     from ddl25spring_tpu.bench_utils import time_train_step
     from ddl25spring_tpu.config import LlamaConfig
     from ddl25spring_tpu.parallel import make_mesh
 
-    cfg = dataclasses.replace(
-        LlamaConfig(dtype="bfloat16", ctx_size=seq), **VARIANTS[variant])
-    mesh = make_mesh({"data": 1})
-    steps = 10
-    tps = time_train_step(mesh, cfg, batch, seq=seq, timed_steps=steps)
-    print(tps, batch * seq / tps * 1e3)
-
-
-def main(quick: bool = False) -> None:
     from . import common
 
+    device = jax.devices()[0]
+    if device.platform != "tpu":
+        sys.exit(f"longctx_bench measures the TPU and found "
+                 f"{device.platform!r}; there is no CPU path")
+    mesh = make_mesh({"data": 1})
     sink = common.sink("longctx_bench.csv")
     grid = GRID[:2] if quick else GRID
     for seq, batch in grid:
-        for variant in VARIANTS:
-            cmd = [sys.executable, "-m", "experiments.longctx_bench",
-                   "--one", variant, str(seq), str(batch)]
-            try:
-                proc = subprocess.run(cmd, capture_output=True, text=True,
-                                      timeout=900)
-                if proc.returncode != 0:
-                    raise RuntimeError(proc.stderr.strip().splitlines()[-1]
-                                       if proc.stderr.strip() else "failed")
-                tps, step_ms = map(float, proc.stdout.split())
-            except Exception as e:
-                print(f"T={seq:5d} {variant:5s}: failed "
-                      f"({type(e).__name__}: {e})", flush=True)
-                continue
+        for variant, overrides in VARIANTS.items():
+            cfg = dataclasses.replace(
+                LlamaConfig(dtype="bfloat16", ctx_size=seq), **overrides)
+            tps = time_train_step(mesh, cfg, batch, seq=seq, timed_steps=10)
+            step_ms = batch * seq / tps * 1e3
             sink.write({"seq": seq, "batch": batch, "variant": variant,
-                        "platform": "tpu", "tokens_per_sec": round(tps, 1),
+                        "platform": device.platform,
+                        "device_kind": device.device_kind,
+                        "tokens_per_sec": round(tps, 1),
                         "step_ms": round(step_ms, 3)})
             print(f"T={seq:5d} {variant:5s}: {tps:10.0f} tok/s "
                   f"({step_ms:.1f} ms/step)", flush=True)
@@ -90,9 +73,6 @@ def main(quick: bool = False) -> None:
 
 
 if __name__ == "__main__":
-    if len(sys.argv) == 5 and sys.argv[1] == "--one":
-        _child(sys.argv[2], int(sys.argv[3]), int(sys.argv[4]))
-    else:
-        ap = argparse.ArgumentParser()
-        ap.add_argument("--quick", action="store_true")
-        main(quick=ap.parse_args().quick)
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--quick", action="store_true")
+    main(quick=ap.parse_args().quick)

@@ -23,13 +23,13 @@ Objectives (each enabled by passing its threshold):
   ``compile`` events' HLO flops (normalized per step by each event's own
   ``steps_per_dispatch``, so ragged tail-chunk programs don't skew the
   window) × the window's step count ÷ the window's step time, against
-  the manifest's recorded roofline peaks (ROOFLINE.md numbers on chip,
-  the calibrated CPU baseline on fallback; schema v5). Caveat, same as
-  bench.py's FLOP crosscheck: on jaxlibs whose ``cost_analysis`` counts
-  a ``lax.scan`` body once (this container's 0.4.36), a fused K-step
-  program's flops read as ONE step's, so chunked-mode MFU is biased low
-  by ~K — set the floor from the same stream's observed steady-state
-  values, not from first principles;
+  the manifest's recorded roofline peaks (the chip's published peaks
+  by device_kind, the calibrated baseline on the CPU; schema v5).
+  Caveat, same as bench.py's FLOP crosscheck: ``cost_analysis`` counts
+  a ``lax.scan`` body once (still so under jax 0.9.0), so a fused
+  K-step program's flops read as ONE step's and chunked-mode MFU is
+  biased low by ~K — set the floor from the same stream's observed
+  steady-state values, not from first principles;
 - ``--slo-gradnorm``  grad-norm spike-rate ceiling: the fraction of the
   window's ``numerics`` samples whose global grad norm exceeds
   ``--gradnorm-factor`` × the window median (the drift signal that

@@ -175,9 +175,8 @@ def test_llm_end_to_end(maker):
 # preempt/resume cycle EXACTLY (bitwise trajectory across the restart) —
 # on the new driver and on the legacy per-step int8 path.
 
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-from ddl25spring_tpu.parallel._compat import shard_map
 
 
 def _mesh4(devices):

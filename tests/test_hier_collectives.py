@@ -30,10 +30,10 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ddl25spring_tpu.parallel import compress, dp, make_mesh
-from ddl25spring_tpu.parallel._compat import shard_map
 from ddl25spring_tpu.parallel.distributed import hier_data_mesh
 
 FACTORIZATIONS = [(1, 8), (2, 4), (4, 2), (8, 1)]

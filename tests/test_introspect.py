@@ -316,6 +316,32 @@ def test_schema_v5_validation_and_backcompat():
     assert problems and "numerics" in problems[0]
 
 
+# ------------------------------------------------------- roofline peaks
+
+@pytest.mark.parametrize("platform,kind,want", [
+    ("tpu", "TPU v5 lite", 197e12),
+    ("cpu", "cpu", "calibrated"),
+    ("tpu", "TPU v4", ValueError),
+    ("gpu", "NVIDIA H100", ValueError),
+])
+def test_device_peaks_keyed_by_device_kind(platform, kind, want):
+    """One table keyed by device_kind: v5e's published peaks for a v5e, the
+    labelled calibrated baseline for the CPU the tests run on, and an error
+    — never another chip's peaks or the CPU's — for any other accelerator."""
+    from types import SimpleNamespace
+
+    device = SimpleNamespace(platform=platform, device_kind=kind)
+    if want is ValueError:
+        with pytest.raises(ValueError, match=kind):
+            introspect.device_peaks(device)
+    elif want == "calibrated":
+        assert introspect.device_peaks(device)["source"].startswith(want)
+    else:
+        peaks = introspect.device_peaks(device)
+        assert peaks["flops_per_sec"] == want
+        assert peaks["hbm_bytes_per_sec"] == 819e9
+
+
 # ------------------------------------------------- slo monitor (v5 SLOs)
 
 

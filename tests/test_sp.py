@@ -9,12 +9,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ddl25spring_tpu.config import LlamaConfig
 from ddl25spring_tpu.models import llama
 from ddl25spring_tpu.ops import causal_lm_loss
-from ddl25spring_tpu.parallel._compat import shard_map
 from ddl25spring_tpu.parallel import make_mesh, sp
 
 

@@ -183,7 +183,7 @@ def test_tp_psa_int8_error_feedback_property(devices):
     bounded by ONE quantization step, not two. (The per-step error is
     allowed to wobble — EF compensates cumulatively, it is not a
     per-step contraction.)"""
-    from ddl25spring_tpu.parallel._compat import shard_map
+    from jax import shard_map
 
     mesh = make_mesh({"model": 4}, devices=devices[:4])
     y = np.linspace(-1.0, 1.0, 4 * 8 * 16, dtype=np.float32).reshape(4, 8, 16)
