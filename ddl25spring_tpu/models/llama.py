@@ -108,6 +108,11 @@ def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarra
 
 
 # ------------------------------------------------------------------ attention
+#
+# ``attention``, ``mlp``, ``embed``, ``head`` and ``head_loss`` run under a
+# ``jax.named_scope`` of that name (``attn`` for attention): it costs nothing
+# at run time, changes no number, and is how a device trace tells the step's
+# parts apart (docs/COMPONENTS.md lists the names).
 
 def qkv_proj(block: dict, x: jnp.ndarray, head_dim: int
              ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
@@ -197,6 +202,7 @@ def attention_path(cfg: LlamaConfig, t: int) -> dict:
     return {"impl": "pallas", "interpret": default_interpret()}
 
 
+@jax.named_scope("attn")
 def attention(block: dict, x: jnp.ndarray, cfg: LlamaConfig,
               cos: jnp.ndarray, sin: jnp.ndarray,
               attn_fn: Optional[Callable] = None,
@@ -234,6 +240,7 @@ def attention(block: dict, x: jnp.ndarray, cfg: LlamaConfig,
     return y
 
 
+@jax.named_scope("mlp")
 def mlp(block: dict, x: jnp.ndarray,
         tp_axis: Optional[str] = None) -> jnp.ndarray:
     """SwiGLU MLP. With ``tp_axis``: w_gate/w_up column-sharded (local ffn
@@ -263,6 +270,7 @@ def block_apply(block: dict, x: jnp.ndarray, cfg: LlamaConfig,
 # LLamaFirstStage.embed / LLamaStage / LLamaLastStage surface
 # (reference: intro_PP_1F1B.py:29-39,53).
 
+@jax.named_scope("embed")
 def embed(params: dict, tokens: jnp.ndarray, cfg: LlamaConfig) -> jnp.ndarray:
     """tokens [B, T] -> activations [B, T, D] in the compute dtype.
 
@@ -300,6 +308,7 @@ def blocks_apply(blocks: dict, h: jnp.ndarray, cfg: LlamaConfig,
     return out
 
 
+@jax.named_scope("head")
 def head(params: dict, h: jnp.ndarray, cfg: LlamaConfig) -> jnp.ndarray:
     """activations [B, T, D] -> logits [B, T, V] (fp32 for a stable loss)."""
     h = nn.rmsnorm(params["final_norm"], h, eps=cfg.norm_eps)
@@ -314,6 +323,7 @@ def forward(params: dict, tokens: jnp.ndarray, cfg: LlamaConfig,
     return head(params, h, cfg)
 
 
+@jax.named_scope("head_loss")
 def head_loss(params: dict, h: jnp.ndarray, tokens: jnp.ndarray,
               cfg: LlamaConfig, chunk_size: int = 512) -> jnp.ndarray:
     """Fused final-norm + lm_head + next-token cross-entropy.

@@ -160,6 +160,7 @@ def _fwd(qb, kb, vb, causal: bool, block_q: int, block_k: int,
             pltpu.VMEM((block_q, dh), jnp.float32),           # acc
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(qb, kb, vb)
 
 
@@ -285,6 +286,7 @@ def _bwd_kernels(qb, kb, vb, dob, lse, delta, causal: bool, block_q: int,
         out_shape=jax.ShapeDtypeStruct((bh, t_pad, dh), qb.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, dh), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_dq",
     )(qb, kb, vb, dob, lse, delta)
 
     # Grid reordered to (bh, ik, iq). Below-diagonal skipped steps clamp the
@@ -311,6 +313,7 @@ def _bwd_kernels(qb, kb, vb, dob, lse, delta, causal: bool, block_q: int,
         scratch_shapes=[pltpu.VMEM((block_k, dh), jnp.float32),
                         pltpu.VMEM((block_k, dh), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_dkv",
     )(qb, kb, vb, dob, lse, delta)
     return dq, dk, dv
 
@@ -427,6 +430,7 @@ def _fwd_t(qb, kb, vb, causal: bool, block_q: int, block_k: int,
             pltpu.VMEM((dh, block_q), jnp.float32),           # acc
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(qb, kb, vb)
 
 
@@ -554,6 +558,7 @@ def _bwd_kernels_t(qb, kb, vb, dob, lse, delta, causal: bool, block_q: int,
         out_shape=jax.ShapeDtypeStruct((bh, dh, t_pad), qb.dtype),
         scratch_shapes=[pltpu.VMEM((dh, block_q), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_dq",
     )(qb, kb, vb, dob, lse, delta)
 
     if causal:
@@ -578,6 +583,7 @@ def _bwd_kernels_t(qb, kb, vb, dob, lse, delta, causal: bool, block_q: int,
         scratch_shapes=[pltpu.VMEM((dh, block_k), jnp.float32),
                         pltpu.VMEM((dh, block_k), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_dkv",
     )(qb, kb, vb, dob, lse, delta)
     return dq, dk, dv
 

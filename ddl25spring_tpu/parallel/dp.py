@@ -145,25 +145,31 @@ def _make_local_grad_step(loss_fn: Callable, optimizer, accum_steps: int,
         # The one payload collective per iter (telemetry.comm wrappers are
         # lax pass-throughs that record bytes at trace time — see
         # telemetry/comm.py; compiled HLO is unchanged).
-        grads = comm.pmean(grads, "data", label="grad_allreduce",
-                           scale=comm_scale)
-        loss = comm.pmean(loss, "data", label="loss_allreduce",
-                          scale=comm_scale)
-        params, opt_state = apply_optimizer(optimizer, grads,
-                                            state.opt_state, state.params)
+        # Named scopes (``grad_sync``, ``optimizer``, ``guard``) cost
+        # nothing at run time: they are how a device trace tells the
+        # step's parts apart (docs/COMPONENTS.md).
+        with jax.named_scope("grad_sync"):
+            grads = comm.pmean(grads, "data", label="grad_allreduce",
+                               scale=comm_scale)
+            loss = comm.pmean(loss, "data", label="loss_allreduce",
+                              scale=comm_scale)
+        with jax.named_scope("optimizer"):
+            params, opt_state = apply_optimizer(optimizer, grads,
+                                                state.opt_state, state.params)
         summary = (numerics.summarize(state.params, grads, params)
                    if numerics is not None else None)
         if guard_nonfinite:
-            ok = jnp.isfinite(loss)
-            for g in jax.tree.leaves(grads):
-                ok &= jnp.all(jnp.isfinite(g))
-            # Select-back, not zeroed grads: a zero-grad optimizer update
-            # still decays Adam moments and bumps count — only keeping the
-            # incoming state makes the skip a true no-op.
-            params = jax.tree.map(lambda n, o: jnp.where(ok, n, o),
-                                  params, state.params)
-            opt_state = jax.tree.map(lambda n, o: jnp.where(ok, n, o),
-                                     opt_state, state.opt_state)
+            with jax.named_scope("guard"):
+                ok = jnp.isfinite(loss)
+                for g in jax.tree.leaves(grads):
+                    ok &= jnp.all(jnp.isfinite(g))
+                # Select-back, not zeroed grads: a zero-grad optimizer
+                # update still decays Adam moments and bumps count — only
+                # keeping the incoming state makes the skip a true no-op.
+                params = jax.tree.map(lambda n, o: jnp.where(ok, n, o),
+                                      params, state.params)
+                opt_state = jax.tree.map(lambda n, o: jnp.where(ok, n, o),
+                                         opt_state, state.opt_state)
             new_state = TrainState(params, opt_state,
                                    state.step + ok.astype(state.step.dtype))
         else:
@@ -428,31 +434,36 @@ def _make_zero1_local_step(loss_fn: Callable, optimizer, n: int, pad: int,
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
         flat_g = jnp.pad(pt.flatten(grads)[0].astype(jnp.float32), (0, pad))
         # Averaged 1/n-th of the gradient lands on its owner shard.
-        g_mine = comm.psum_scatter(flat_g, "data", scatter_dimension=0,
-                                   tiled=True, label="zero1_grad_scatter",
-                                   scale=comm_scale) / n
+        with jax.named_scope("grad_sync"):
+            g_mine = comm.psum_scatter(flat_g, "data", scatter_dimension=0,
+                                       tiled=True, label="zero1_grad_scatter",
+                                       scale=comm_scale) / n
         raw_flat, unravel = pt.flatten(params)
         flat_p = jnp.pad(raw_flat.astype(jnp.float32), (0, pad))
         shard = lax.axis_index("data")
         p_mine = lax.dynamic_slice_in_dim(flat_p, shard * local, local)
-        new_p_mine, opt_state = apply_optimizer(optimizer, g_mine,
-                                                state.opt_state, p_mine)
-        loss = comm.pmean(loss, "data", label="loss_allreduce",
-                          scale=comm_scale)
+        with jax.named_scope("optimizer"):
+            new_p_mine, opt_state = apply_optimizer(optimizer, g_mine,
+                                                    state.opt_state, p_mine)
+        with jax.named_scope("grad_sync"):
+            loss = comm.pmean(loss, "data", label="loss_allreduce",
+                              scale=comm_scale)
         if guard_nonfinite:
-            ok = jnp.all(jnp.isfinite(g_mine)) & jnp.isfinite(loss)
-            ok = comm.psum(ok.astype(jnp.int32), "data",
-                           label="zero1_guard_verdict",
-                           scale=comm_scale) == n
-            new_p_mine = jnp.where(ok, new_p_mine, p_mine)
-            opt_state = jax.tree.map(lambda nw, o: jnp.where(ok, nw, o),
-                                     opt_state, state.opt_state)
+            with jax.named_scope("guard"):
+                ok = jnp.all(jnp.isfinite(g_mine)) & jnp.isfinite(loss)
+                ok = comm.psum(ok.astype(jnp.int32), "data",
+                               label="zero1_guard_verdict",
+                               scale=comm_scale) == n
+                new_p_mine = jnp.where(ok, new_p_mine, p_mine)
+                opt_state = jax.tree.map(lambda nw, o: jnp.where(ok, nw, o),
+                                         opt_state, state.opt_state)
             step = state.step + ok.astype(state.step.dtype)
         else:
             step = state.step + 1
-        flat_new = comm.all_gather(new_p_mine, "data", tiled=True,
-                                   label="zero1_param_gather",
-                                   scale=comm_scale)[:total]
+        with jax.named_scope("grad_sync"):
+            flat_new = comm.all_gather(new_p_mine, "data", tiled=True,
+                                       label="zero1_param_gather",
+                                       scale=comm_scale)[:total]
         # Cast back before unravel: for single-dtype trees ravel_pytree's
         # unravel is dtype-polymorphic and would silently rebuild non-fp32
         # params (e.g. param_dtype="bfloat16") as fp32.

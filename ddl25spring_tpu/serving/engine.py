@@ -56,6 +56,7 @@ from jax import lax
 from ..config import LlamaConfig
 from .. import nn
 from ..models import generate, llama
+from ..telemetry.trace import Spans
 from .kvcache import (TRASH_BLOCK, BlockAllocator, PagedKVConfig, blocks_for,
                       init_pool)
 
@@ -151,32 +152,42 @@ def _block_paged(block: dict, pk: jnp.ndarray, pv: jnp.ndarray,
     math around them is identical."""
     s, t, d = x.shape
     dh = cfg.head_dim
-    xn = nn.rmsnorm(block["attn_norm"], x, eps=cfg.norm_eps)
-    qkv = xn @ block["w_qkv"].astype(x.dtype)
-    dl = qkv.shape[-1] // 3
-    h_local = dl // dh
-    q = qkv[..., :dl].reshape(s, t, h_local, dh)
-    k = qkv[..., dl:2 * dl].reshape(s, t, h_local, dh)
-    v = qkv[..., 2 * dl:].reshape(s, t, h_local, dh)
-    cos, sin = llama.rope_angles(positions.reshape(-1), dh, cfg.rope_theta)
-    cos = cos.reshape(s, t, -1)
-    sin = sin.reshape(s, t, -1)
-    q = _apply_rope_slots(q, cos, sin)
-    k = _apply_rope_slots(k, cos, sin)       # cached K is stored post-RoPE
+    # The named scopes cost nothing at run time and change no number: they
+    # are how a device trace tells the block's parts apart (``paged.*`` is
+    # what the paged-attention metrics time; docs/COMPONENTS.md).
+    with jax.named_scope("qkv"):
+        xn = nn.rmsnorm(block["attn_norm"], x, eps=cfg.norm_eps)
+        qkv = xn @ block["w_qkv"].astype(x.dtype)
+        dl = qkv.shape[-1] // 3
+        h_local = dl // dh
+        q = qkv[..., :dl].reshape(s, t, h_local, dh)
+        k = qkv[..., dl:2 * dl].reshape(s, t, h_local, dh)
+        v = qkv[..., 2 * dl:].reshape(s, t, h_local, dh)
+        cos, sin = llama.rope_angles(positions.reshape(-1), dh,
+                                     cfg.rope_theta)
+        cos = cos.reshape(s, t, -1)
+        sin = sin.reshape(s, t, -1)
+        q = _apply_rope_slots(q, cos, sin)
+        k = _apply_rope_slots(k, cos, sin)   # cached K is stored post-RoPE
     # Per-token scatter into the block pool. Distinct (block, offset)
     # targets are guaranteed by block ownership; only TRASH_BLOCK collides
     # (inactive slots, padded tails) and its contents are never read
     # un-masked.
-    pk = pk.at[wblk, woff].set(k.astype(pk.dtype))
-    pv = pv.at[wblk, woff].set(v.astype(pv.dtype))
-    ck = pk[tables].reshape(s, -1, h_local, dh)    # [S, Tmax, H, Dh]
-    cv = pv[tables].reshape(s, -1, h_local, dh)
-    out = _attend_paged(q, ck, cv, positions)
-    x = x + out.reshape(s, t, h_local * dh) @ block["wo"].astype(x.dtype)
-    xn = nn.rmsnorm(block["mlp_norm"], x, eps=cfg.norm_eps)
-    gu = xn @ block["w_gu"].astype(x.dtype)
-    f = gu.shape[-1] // 2
-    x = x + (jax.nn.silu(gu[..., :f]) * gu[..., f:]) @ block["w_down"].astype(x.dtype)
+    with jax.named_scope("paged.write"):
+        pk = pk.at[wblk, woff].set(k.astype(pk.dtype))
+        pv = pv.at[wblk, woff].set(v.astype(pv.dtype))
+    with jax.named_scope("paged.gather"):
+        ck = pk[tables].reshape(s, -1, h_local, dh)    # [S, Tmax, H, Dh]
+        cv = pv[tables].reshape(s, -1, h_local, dh)
+    with jax.named_scope("paged.attend"):
+        out = _attend_paged(q, ck, cv, positions)
+    with jax.named_scope("attn_out"):
+        x = x + out.reshape(s, t, h_local * dh) @ block["wo"].astype(x.dtype)
+    with jax.named_scope("mlp"):
+        xn = nn.rmsnorm(block["mlp_norm"], x, eps=cfg.norm_eps)
+        gu = xn @ block["w_gu"].astype(x.dtype)
+        f = gu.shape[-1] // 2
+        x = x + (jax.nn.silu(gu[..., :f]) * gu[..., f:]) @ block["w_down"].astype(x.dtype)
     return x, pk, pv
 
 
@@ -195,7 +206,13 @@ def _forward_paged(params: dict, fused_blocks: dict, tokens: jnp.ndarray,
                                    tables, wblk, woff, cfg)
         return out, (pk, pv)
 
-    h, (pk, pv) = lax.scan(body, h, (fused_blocks, pool["k"], pool["v"]))
+    # ``layers`` names what the scan itself does to its stacked inputs and
+    # outputs (each layer's pool sliced out of the stacked pool and written
+    # back): those operations read ``.../layers/while/body/<op>`` with no
+    # inner scope, which is what ``decode_unscoped_ms.serve`` times.
+    with jax.named_scope("layers"):
+        h, (pk, pv) = lax.scan(body, h,
+                               (fused_blocks, pool["k"], pool["v"]))
     return h, {"k": pk, "v": pv}
 
 
@@ -256,8 +273,9 @@ def make_prefill_chunk(cfg: LlamaConfig, paged: PagedKVConfig,
         last = jnp.take_along_axis(
             h, (n_valid - 1).reshape(1, 1, 1).astype(jnp.int32), axis=1)
         logits = llama.head(params, last, cfg)[:, 0, :]            # [1, V]
-        key, sub = jax.random.split(key)
-        tok = _sample_slot(sub, logits, temperature, top_k, top_p)
+        with jax.named_scope("sample"):
+            key, sub = jax.random.split(key)
+            tok = _sample_slot(sub, logits, temperature, top_k, top_p)
         return pool, tok[0], key
 
     return prefill_chunk
@@ -299,16 +317,18 @@ def make_decode_step(cfg: LlamaConfig, paged: PagedKVConfig,
                                  tables, pos[:, None],
                                  wblk[:, None], woff[:, None], cfg)
         logits = llama.head(params, h, cfg)[:, 0, :]               # [S, V]
-        split = jax.vmap(jax.random.split)(keys)                   # [S, 2, 2]
-        subs = split[:, 1]
-        # Only ACTIVE slots consume randomness: a slot still mid-prefill
-        # (or free) must keep its key untouched, or its stream would start
-        # shifted relative to ``generate``'s by however many decode steps
-        # happened to run before its admission finished.
-        new_keys = jnp.where(active[:, None], split[:, 0], keys)
-        toks = jax.vmap(
-            lambda k, l, t: _sample_slot(k, l[None], t, top_k, top_p)[0]
-        )(subs, logits, temps)
+        with jax.named_scope("sample"):
+            split = jax.vmap(jax.random.split)(keys)               # [S, 2, 2]
+            subs = split[:, 1]
+            # Only ACTIVE slots consume randomness: a slot still
+            # mid-prefill (or free) must keep its key untouched, or its
+            # stream would start shifted relative to ``generate``'s by
+            # however many decode steps happened to run before its
+            # admission finished.
+            new_keys = jnp.where(active[:, None], split[:, 0], keys)
+            toks = jax.vmap(
+                lambda k, l, t: _sample_slot(k, l[None], t, top_k, top_p)[0]
+            )(subs, logits, temps)
         if not return_probs:
             return pool, toks, new_keys
         # Greedy slots' q is unused (their acceptance is the argmax
@@ -357,15 +377,28 @@ class _Slot:
 
 
 class Engine:
-    """Slots + compiled steps + block plumbing. Queueing, time and
-    telemetry live one layer up (scheduler.py); this class only knows how
-    to admit a request into a free slot, advance prefill by one chunk,
+    """Slots + compiled steps + block plumbing. Queueing, time and the
+    request events live one layer up (scheduler.py); this class only knows
+    how to admit a request into a free slot, advance prefill by one chunk,
     decode one token for everyone, and retire finished slots (freeing
     their blocks immediately).
 
     ``step()`` is one token boundary: at most one prefill chunk (FCFS over
     mid-prefill slots — the chunked-prefill interleave), then one decode
     step if any slot is decoding. Returns the ``TokenEvent``s produced.
+
+    What a step costs the host is timed by cause in ``self.spans``
+    (telemetry/trace.py ``Spans``: no event log, nothing of requests):
+    ``engine.step`` round ``engine.prefill.{stage,dispatch,fetch}`` and
+    ``engine.decode.{stage,dispatch,fetch,book}``. Under a live profiler
+    the same spans stand on its timeline, the dispatch spans with the
+    step's work as counters (``engine.prefill.dispatch``: ``slot``,
+    ``seq``, ``off``, ``n_valid``, ``final``; ``engine.decode.dispatch``:
+    ``dispatch`` = ``decode_dispatches`` as the step began, ``active``
+    decoding slots, ``live_positions`` = the cache positions the step must
+    attend to, sum of ``pos + 1`` over them, ``gathered_positions`` =
+    ``num_slots`` x table width passed x ``block_len``, what the program
+    reads as built; ``engine.decode.book``: ``emitted``).
     """
 
     def __init__(self, params: dict, cfg: LlamaConfig, paged: PagedKVConfig,
@@ -448,6 +481,7 @@ class Engine:
         # one-dispatch k+1-position verify program.
         self.spec = speculate
         self.last_spec: Optional[dict] = None
+        self.spans = Spans()           # host seconds of a step, by cause
         self.decode_dispatches = 0     # verify or plain decode calls
         self.decode_tokens = 0         # tokens those dispatches emitted
         self.draft_dispatches = 0
@@ -624,14 +658,16 @@ class Engine:
         verify round — over the decoding slots."""
         events: List[TokenEvent] = []
         self.last_spec = None
-        prefilling = [(sl.seq, i) for i, sl in enumerate(self.slots)
-                      if sl is not None and sl.phase == "prefill"]
-        if prefilling:
-            events.extend(self._advance_prefill(min(prefilling)[1]))
-        if any(sl is not None and sl.phase == "decode" for sl in self.slots):
-            events.extend(self._advance_spec_decode()
-                          if self.spec is not None
-                          else self._advance_decode())
+        with self.spans("engine.step"):
+            prefilling = [(sl.seq, i) for i, sl in enumerate(self.slots)
+                          if sl is not None and sl.phase == "prefill"]
+            if prefilling:
+                events.extend(self._advance_prefill(min(prefilling)[1]))
+            if any(sl is not None and sl.phase == "decode"
+                   for sl in self.slots):
+                events.extend(self._advance_spec_decode()
+                              if self.spec is not None
+                              else self._advance_decode())
         return events
 
     def _register_prefix_blocks(self, s: int) -> None:
@@ -654,34 +690,40 @@ class Engine:
 
     def _advance_prefill(self, s: int) -> List[TokenEvent]:
         slot = self.slots[s]
-        tc = self.prefill_chunk_len
-        off = slot.prefill_off
-        n_valid = min(tc, len(slot.prompt) - off)
-        chunk = np.zeros(tc, np.int32)
-        chunk[:n_valid] = slot.prompt[off:off + n_valid]
-        is_final = off + n_valid >= len(slot.prompt)
-        write_from = slot.shared * self.paged.block_len
-        table_row = jnp.array(self.tables[s])
-        chunk_j = jnp.array(chunk)
-        self.pool, tok, new_key = self._prefill(
-            self.pool, self.params, self.fused,
-            table_row, chunk_j,
-            jnp.int32(off), jnp.int32(n_valid), jnp.int32(write_from),
-            self.keys[s], jnp.float32(self.temps[s]))
-        if self.draft is not None:
-            # Mirror the chunk into the draft pool (same table row, same
-            # positions, the draft's weights) so proposals can attend over
-            # the full prompt. Shared blocks are shared there too — the
-            # donor's draft prefill wrote them — so the same write_from
-            # masking applies.
-            self.draft.prefill_chunk(table_row, chunk_j, jnp.int32(off),
-                                     jnp.int32(n_valid),
-                                     jnp.int32(write_from), self.temps[s])
-            # The mirror is a real draft dispatch: without it the JSON's
-            # draft-cost line under-reports by one dispatch per prefill
-            # chunk (~15% on the CI smoke's workload) and a real small
-            # draft sized from it would look cheaper than it is.
-            self.draft_dispatches += 1
+        with self.spans("engine.prefill.stage"):
+            tc = self.prefill_chunk_len
+            off = slot.prefill_off
+            n_valid = min(tc, len(slot.prompt) - off)
+            chunk = np.zeros(tc, np.int32)
+            chunk[:n_valid] = slot.prompt[off:off + n_valid]
+            is_final = off + n_valid >= len(slot.prompt)
+            write_from = slot.shared * self.paged.block_len
+            table_row = jnp.array(self.tables[s])
+            chunk_j = jnp.array(chunk)
+            scalars = (jnp.int32(off), jnp.int32(n_valid),
+                       jnp.int32(write_from))
+            temp = jnp.float32(self.temps[s])
+        with self.spans("engine.prefill.dispatch", slot=s, seq=slot.seq,
+                        off=off, n_valid=n_valid, final=int(is_final)):
+            self.pool, tok, new_key = self._prefill(
+                self.pool, self.params, self.fused,
+                table_row, chunk_j, *scalars, self.keys[s], temp)
+            if self.draft is not None:
+                # Mirror the chunk into the draft pool (same table row,
+                # same positions, the draft's weights) so proposals can
+                # attend over the full prompt. Shared blocks are shared
+                # there too — the donor's draft prefill wrote them — so the
+                # same write_from masking applies.
+                self.draft.prefill_chunk(table_row, chunk_j, *scalars,
+                                         self.temps[s])
+                # The mirror is a real draft dispatch: without it the
+                # JSON's draft-cost line under-reports by one dispatch per
+                # prefill chunk (~15% on the CI smoke's workload) and a
+                # real small draft sized from it would look cheaper than
+                # it is.
+                self.draft_dispatches += 1
+            if is_final:
+                self.keys = self.keys.at[s].set(new_key)
         slot.prefill_off = off + n_valid
         if self.prefix_share:
             self._register_prefix_blocks(s)
@@ -690,8 +732,8 @@ class Engine:
             # key are discarded so the slot's RNG stream stays exactly
             # generate's (one split for the whole prefill).
             return []
-        self.keys = self.keys.at[s].set(new_key)
-        first = int(tok)
+        with self.spans("engine.prefill.fetch"):
+            first = int(tok)            # the host waits for the device
         slot.phase = "decode"
         slot.produced = 1
         self.pos[s] = len(slot.prompt)
@@ -728,30 +770,45 @@ class Engine:
         self.gather_bytes_saved += self.num_slots * (mb - cols) * per_block
         return self.tables[:, :cols]
 
+    def _dispatch_counters(self, active: np.ndarray, tables) -> dict:
+        """The counters of ``engine.decode.dispatch`` (class docstring)."""
+        return {"dispatch": self.decode_dispatches,
+                "active": int(active.sum()),
+                "live_positions": int((self.pos[active] + 1).sum()),
+                "gathered_positions": (self.num_slots * int(tables.shape[1])
+                                       * self.paged.block_len)}
+
     def _advance_decode(self) -> List[TokenEvent]:
-        active = np.array([sl is not None and sl.phase == "decode"
-                           for sl in self.slots])
-        tables = self._gathered_tables(active, 1)
-        self.pool, toks, new_keys = self._decode(
-            self.pool, self.params, self.fused,
-            jnp.array(tables), jnp.array(self.last_tok),
-            jnp.array(self.pos), self.keys,
-            jnp.array(self.temps), jnp.array(active))
-        toks = np.asarray(toks)
+        with self.spans("engine.decode.stage"):
+            active = np.array([sl is not None and sl.phase == "decode"
+                               for sl in self.slots])
+            tables = self._gathered_tables(active, 1)
+            args = (jnp.array(tables), jnp.array(self.last_tok),
+                    jnp.array(self.pos), self.keys,
+                    jnp.array(self.temps), jnp.array(active))
+        with self.spans("engine.decode.dispatch",
+                        **self._dispatch_counters(active, tables)):
+            self.pool, toks, new_keys = self._decode(
+                self.pool, self.params, self.fused, *args)
+        with self.spans("engine.decode.fetch"):
+            toks = np.asarray(toks)     # the host waits for the device
         self.keys = new_keys
         events = []
-        for s in np.nonzero(active)[0]:
-            slot = self.slots[s]
-            tok = int(toks[s])
-            slot.produced += 1
-            self.pos[s] += 1
-            self.last_tok[s] = tok
-            done = slot.produced >= slot.max_new
-            if done:
-                self._retire(s)
-            events.append(TokenEvent(int(s), tok, first=False, done=done))
-        self.decode_dispatches += 1
-        self.decode_tokens += len(events)
+        with self.spans("engine.decode.book") as book:
+            for s in np.nonzero(active)[0]:
+                slot = self.slots[s]
+                tok = int(toks[s])
+                slot.produced += 1
+                self.pos[s] += 1
+                self.last_tok[s] = tok
+                done = slot.produced >= slot.max_new
+                if done:
+                    self._retire(s)
+                events.append(TokenEvent(int(s), tok, first=False,
+                                         done=done))
+            self.decode_dispatches += 1
+            self.decode_tokens += len(events)
+            book.set_metadata(emitted=len(events))
         return events
 
     def _advance_spec_decode(self) -> List[TokenEvent]:
@@ -763,53 +820,62 @@ class Engine:
         — and records the round's proposal accounting in ``last_spec``
         (the scheduler's ``speculate`` event, schema v7)."""
         k = self.spec.k
-        active_l = [sl is not None and sl.phase == "decode"
-                    for sl in self.slots]
-        active = np.array(active_l)
-        remaining = np.array([sl.max_new - sl.produced if a else 0
-                              for a, sl in zip(active_l, self.slots)],
-                             np.int32)
-        live = np.minimum(k + 1, np.maximum(remaining, 1)).astype(np.int32)
-        tables = jnp.array(self._gathered_tables(active, k + 1))
-        pos = jnp.array(self.pos)
-        temps = jnp.array(self.temps)
-        active_j = jnp.array(active)
-        live_j = jnp.array(live)
-        drafts, draft_probs = self.draft.propose(
-            tables, jnp.array(self.last_tok), pos, temps, active_j, live_j)
-        self.draft_dispatches += k + 1
-        window = jnp.concatenate([jnp.array(self.last_tok)[:, None],
-                                  drafts], axis=1)
-        self.pool, out, accepted, new_keys = self._verify(
-            self.pool, self.params, self.fused, tables, window,
-            draft_probs, pos, live_j, self.keys, temps, active_j)
-        out = np.asarray(out)
-        accepted = np.asarray(accepted)
+        with self.spans("engine.decode.stage"):
+            active_l = [sl is not None and sl.phase == "decode"
+                        for sl in self.slots]
+            active = np.array(active_l)
+            remaining = np.array([sl.max_new - sl.produced if a else 0
+                                  for a, sl in zip(active_l, self.slots)],
+                                 np.int32)
+            live = np.minimum(k + 1,
+                              np.maximum(remaining, 1)).astype(np.int32)
+            narrowed = self._gathered_tables(active, k + 1)
+            tables = jnp.array(narrowed)
+            pos = jnp.array(self.pos)
+            temps = jnp.array(self.temps)
+            active_j = jnp.array(active)
+            live_j = jnp.array(live)
+        with self.spans("engine.decode.dispatch",
+                        **self._dispatch_counters(active, narrowed)):
+            drafts, draft_probs = self.draft.propose(
+                tables, jnp.array(self.last_tok), pos, temps, active_j,
+                live_j)
+            self.draft_dispatches += k + 1
+            window = jnp.concatenate([jnp.array(self.last_tok)[:, None],
+                                      drafts], axis=1)
+            self.pool, out, accepted, new_keys = self._verify(
+                self.pool, self.params, self.fused, tables, window,
+                draft_probs, pos, live_j, self.keys, temps, active_j)
+        with self.spans("engine.decode.fetch"):
+            out = np.asarray(out)
+            accepted = np.asarray(accepted)
         self.keys = new_keys
         self.decode_dispatches += 1
         events: List[TokenEvent] = []
         n_active = int(active.sum())
         used = proposed = 0
-        for s in np.nonzero(active)[0]:
-            slot = self.slots[s]
-            emit = min(int(accepted[s]) + 1, int(remaining[s]))
-            # The draft really proposed min(k, remaining) tokens for this
-            # slot — the propose loop masks rows past the live window, so
-            # horizon truncation is not a draft failure and must not read
-            # as rejection in the acceptance rate.
-            proposed += min(k, int(remaining[s]))
-            used += min(int(accepted[s]), emit)
-            for i in range(emit):
-                tok = int(out[s, i])
-                slot.produced += 1
-                self.pos[s] += 1
-                self.last_tok[s] = tok
-                done = slot.produced >= slot.max_new
-                if done:
-                    self._retire(s)
-                events.append(TokenEvent(int(s), tok, first=False,
-                                         done=done))
-        self.decode_tokens += len(events)
+        with self.spans("engine.decode.book") as book:
+            for s in np.nonzero(active)[0]:
+                slot = self.slots[s]
+                emit = min(int(accepted[s]) + 1, int(remaining[s]))
+                # The draft really proposed min(k, remaining) tokens for
+                # this slot — the propose loop masks rows past the live
+                # window, so horizon truncation is not a draft failure and
+                # must not read as rejection in the acceptance rate.
+                proposed += min(k, int(remaining[s]))
+                used += min(int(accepted[s]), emit)
+                for i in range(emit):
+                    tok = int(out[s, i])
+                    slot.produced += 1
+                    self.pos[s] += 1
+                    self.last_tok[s] = tok
+                    done = slot.produced >= slot.max_new
+                    if done:
+                        self._retire(s)
+                    events.append(TokenEvent(int(s), tok, first=False,
+                                             done=done))
+            self.decode_tokens += len(events)
+            book.set_metadata(emitted=len(events))
         self.last_spec = {"k": k, "slots": n_active,
                           "proposed": proposed, "accepted": used,
                           "rejected": proposed - used,

@@ -62,6 +62,16 @@ chunk (one compiled call serves every slot — the per-slot share is not
 observable from the host), flagged with the chunk index; reassemble with
 ``telemetry.trace.trace_trees`` or export via
 ``experiments/trace_export.py``.
+
+Host time by cause (``Scheduler.spans``, a ``telemetry.trace.Spans``, with
+or without ``events=``): every ``tick()`` is a ``serve.tick`` span (counters
+``n`` the tick's index, ``queued``, ``in_flight``, ``blocks_in_use`` as it
+began) round ``serve.admit`` (``admitted``), the engine's own
+``engine.step`` and ``serve.emit`` (the loop over the engine's events to
+the return: ``tokens``, ``retired``). They are on real time, never in the
+event stream, and under a live profiler they stand on its timeline with
+their counters (telemetry/trace.py), which is where a reader attributes
+the chip's idle gaps inside a tick.
 """
 
 from __future__ import annotations
@@ -74,7 +84,7 @@ import jax
 import numpy as np
 
 from ..telemetry.events import EventLog
-from ..telemetry.trace import Span, Tracer
+from ..telemetry.trace import Span, Spans, Tracer
 from .engine import Engine
 
 
@@ -196,6 +206,11 @@ class Scheduler:
         self.tracer = (Tracer(events,
                               clock_ns=lambda: int(self.clock() * 1e9))
                        if events is not None else None)
+        # Host time of a tick by cause (module docstring): always made,
+        # never in the event stream, on the profiler's timeline when one
+        # is live.
+        self.spans = Spans()
+        self._tick_n = 0
         self._spans: Dict[str, Dict[str, Span]] = {}   # rid -> open spans
         self._chunks: Dict[str, int] = {}              # rid -> chunks done
         # Live memory census (telemetry/memory.py, schema v9): every
@@ -273,27 +288,44 @@ class Scheduler:
     def tick(self) -> List[Tuple[str, int]]:
         """One token boundary: admit, advance the engine, retire. Returns
         the (rid, token) pairs emitted this boundary."""
-        self._admit()
-        if not self.engine.busy:
-            return []
+        with self.spans("serve.tick", n=self._tick_n, queued=len(self.queue),
+                        in_flight=len(self._by_slot),
+                        blocks_in_use=self.engine.blocks_in_use()):
+            self._tick_n += 1
+            with self.spans("serve.admit") as admit:
+                admit.set_metadata(admitted=self._admit())
+            if not self.engine.busy:
+                return []
+            chunk_spans: List[Tuple[str, Span]] = []
+            if self.tracer:
+                # Slots without a first token advance exactly one prefill
+                # chunk in this step (engine contract); open their chunk
+                # spans BEFORE the step so the span covers the compiled
+                # call.
+                for slot, req in self._by_slot.items():
+                    if self.records[req.rid].first_token_t is None:
+                        i = self._chunks.get(req.rid, 0)
+                        self._chunks[req.rid] = i + 1
+                        chunk_spans.append((req.rid, self.tracer.start(
+                            "prefill_chunk",
+                            parent=self._spans[req.rid]["prefill"].ctx,
+                            chunk=i)))
+            events = self.engine.step()
+            now = self.clock()  # post-step: token timestamps include the step
+            for _, s in chunk_spans:
+                s.end()
+            with self.spans("serve.emit") as emit:
+                retired = self.completed
+                emitted = self._emit(events, now)
+                emit.set_metadata(tokens=len(emitted),
+                                  retired=self.completed - retired)
+            return emitted
+
+    def _emit(self, events, now: float) -> List[Tuple[str, int]]:
+        """``tick()``'s second half: the engine's events into the records
+        and the event stream, retirements, the speculation and memory
+        accounting. Returns the (rid, token) pairs delivered."""
         emitted: List[Tuple[str, int]] = []
-        chunk_spans: List[Tuple[str, Span]] = []
-        if self.tracer:
-            # Slots without a first token advance exactly one prefill
-            # chunk in this step (engine contract); open their chunk spans
-            # BEFORE the step so the span covers the compiled call.
-            for slot, req in self._by_slot.items():
-                if self.records[req.rid].first_token_t is None:
-                    i = self._chunks.get(req.rid, 0)
-                    self._chunks[req.rid] = i + 1
-                    chunk_spans.append((req.rid, self.tracer.start(
-                        "prefill_chunk",
-                        parent=self._spans[req.rid]["prefill"].ctx,
-                        chunk=i)))
-        events = self.engine.step()
-        now = self.clock()   # post-step: token timestamps include the step
-        for _, s in chunk_spans:
-            s.end()
         eos_retired: set = set()
         eos_dropped = 0
         for ev in events:
@@ -462,14 +494,17 @@ class Scheduler:
                                           .blocks, i))
         return None
 
-    def _admit(self) -> None:
+    def _admit(self) -> int:
         """Admit while the policy yields a fitting request; stop when the
         (priority-ordered) head blocks the line — under "fcfs" that is
-        strict arrival order, byte-for-byte the historical behavior."""
+        strict arrival order, byte-for-byte the historical behavior.
+        Returns how many were admitted."""
+        admitted = 0
         while self.queue:
             pick = self._pick_admittable()
             if pick is None:
-                return
+                break
+            admitted += 1
             head = self.queue.pop(pick)
             key = (jax.random.PRNGKey(head.seed)
                    if head.temperature > 0 else None)
@@ -493,3 +528,4 @@ class Scheduler:
                     queue_wait_s=rec.queue_wait_s,
                     blocks_in_use=self.engine.blocks_in_use(),
                     **self._tag)
+        return admitted

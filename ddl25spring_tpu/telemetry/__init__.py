@@ -22,7 +22,10 @@ One observability subsystem the whole stack reports through:
 - ``trace``: span contexts (trace/span/parent ids, explicit propagation)
   over the event stream — per-request/per-round causal timelines,
   exported to Perfetto by experiments/trace_export.py and watched live by
-  experiments/slo_monitor.py.
+  experiments/slo_monitor.py — and the program's host spans on the
+  profiler's clock: every ``with`` span (``Tracer.span``, ``Spans``) is a
+  ``jax.profiler.TraceAnnotation`` with its counters whenever jax is
+  imported, whoever started the profiler.
 
 ``Telemetry`` bundles the per-run pieces (event log + heartbeat +
 registry) behind one handle the trainers/servers accept.

@@ -306,12 +306,27 @@ class CompileWatch:
     Attribute access delegates to the wrapped callable, so
     ``_cache_size()`` / ``lower`` / ``eval_shape`` users see the original
     jit object.
+
+    A watched program is identified by its names too. Its
+    ``jax.named_scope``s and kernel names are what a device trace's
+    readers match, and JAX's persistent compilation cache leaves an
+    operation's metadata out of its key by default: an executable built
+    by a commit with other names would be handed back with those names
+    in every profile (seen on the chip, PERF.md section 6, PR 26). So the
+    first watch of a process puts the metadata into the key
+    (``jax_compilation_cache_include_metadata_in_key``): here, and
+    nowhere else in the repo. The metadata holds source lines, so an edit
+    that moves lines of a watched program compiles it again; what the
+    flag costs a warm set-up is in PERF.md section 6, PR 27.
     """
 
     def __init__(self, fn: Callable, *, name: str,
                  max_caches: Optional[int] = 1, cost: bool = True,
                  events=None, meta: Optional[Dict[str, Any]] = None,
                  meta_fn: Optional[Callable] = None):
+        import jax
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
         self._fn = fn
         self.name = name
         self.max_caches = max_caches

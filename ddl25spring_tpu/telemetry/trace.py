@@ -20,12 +20,22 @@ span layer on top (ISSUE 8 tentpole):
   re-exports them — and a ``Tracer(phases=Spans())`` feeds every completed
   span into the accumulator, so ``MetricsRegistry.absorb_spans`` works off
   the one tracing path instead of a parallel one.
-- ``device_trace``: the jax.profiler wrapper, upgraded: while a device
-  trace is active, every ``Tracer`` span also enters a
-  ``jax.profiler.TraceAnnotation``, so HOST spans land on the XLA profiler
-  timeline next to the device ops they dispatched. Outside an active
-  device trace the hook is a single flag check — host-only runs pay
-  nothing and the module stays importable without jax.
+- The profiler's clock: a span that opens and closes in one ``with``
+  (``Tracer.span``, ``Spans.__call__``) also enters a
+  ``jax.profiler.TraceAnnotation`` under its own name (``annotation=``
+  gives it another) whenever ``jax`` is already imported, so HOST spans
+  land on the XLA profiler timeline next to the device ops they
+  dispatched — whoever started the profiler (``device_trace`` below, or
+  ``jax.profiler.start_trace`` in a harness). ``TraceAnnotation`` is
+  itself inert while no trace is live, and a jax-free process never
+  imports jax here. Counters ride on the annotation as its keyword
+  arguments (integers known at entry) or through the annotation's
+  ``set_metadata`` (known only at the end), so a reader finds them in the
+  same file and on the same clock as the device ops. Spans held open
+  across calls (``Tracer.start``: the serving request spans) overlap
+  instead of nesting and stay out of the profiler's trace.
+- ``device_trace``: the plain start and stop of ``jax.profiler`` round a
+  block, for operators.
 - ``trace_trees`` / ``tree_check``: jax-free reassembly of a recorded
   stream into per-trace span trees, with the orphan/imbalance self-checks
   obs_report and the serving smoke's completeness bar use.
@@ -44,6 +54,7 @@ depends on it.
 from __future__ import annotations
 
 import contextlib
+import sys
 import threading
 import time
 from collections import defaultdict
@@ -81,6 +92,33 @@ class SpanContext:
                 and self.as_dict() == other.as_dict())
 
 
+class _NoAnnotation:
+    """What ``annotate`` hands out in a process without jax."""
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    def set_metadata(self, **counters: Any) -> None:
+        pass
+
+
+_NO_ANNOTATION = _NoAnnotation()
+
+
+def annotate(name: str, **counters: Any):
+    """The profiler's side of a span: a ``jax.profiler.TraceAnnotation``
+    named ``name`` carrying ``counters``, inert while no trace is live; in
+    a process that has not imported jax, an object that does nothing. Its
+    ``set_metadata(**counters)`` adds counters known only at the end."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:
+        return _NO_ANNOTATION
+    return profiler.TraceAnnotation(name, **counters)
+
+
 class Span:
     """One open span. ``end()`` emits the event (idempotent: the second
     call is a no-op, so a manual-lifecycle caller crossing error paths
@@ -89,27 +127,22 @@ class Span:
     manager."""
 
     __slots__ = ("_tracer", "ctx", "name", "start_ns", "attrs", "_phase",
-                 "_annotation", "_ended")
+                 "_ended")
 
     def __init__(self, tracer: "Tracer", ctx: SpanContext, name: str,
-                 start_ns: int, attrs: Dict[str, Any], phase: Optional[str],
-                 annotation):
+                 start_ns: int, attrs: Dict[str, Any], phase: Optional[str]):
         self._tracer = tracer
         self.ctx = ctx
         self.name = name
         self.start_ns = start_ns
         self.attrs = attrs
         self._phase = phase
-        self._annotation = annotation
         self._ended = False
 
     def end(self, **attrs: Any) -> None:
         if self._ended:
             return
         self._ended = True
-        if self._annotation is not None:
-            with contextlib.suppress(Exception):
-                self._annotation.__exit__(None, None, None)
         self.attrs.update(attrs)
         self._tracer._finish(self)
 
@@ -167,31 +200,28 @@ class Tracer:
         else:
             ctx = SpanContext(trace if trace is not None else "main",
                               self._next_id())
-        annotation = None
-        if _profiling():
-            # Host span → XLA profiler timeline (jax.profiler
-            # TraceAnnotation), only while a device trace is live: outside
-            # one this is a single module-flag check, and the import never
-            # happens in jax-free processes.
-            with contextlib.suppress(Exception):
-                import jax
-                annotation = jax.profiler.TraceAnnotation(name)
-                annotation.__enter__()
         return Span(self, ctx, name, int(self.clock_ns()), dict(attrs),
-                    phase, annotation)
+                    phase)
 
     @contextlib.contextmanager
     def span(self, name: str, *, parent: Optional[SpanContext] = None,
              trace: Optional[str] = None, phase=None,
+             annotation: Optional[str] = None,
+             counters: Optional[Dict[str, Any]] = None,
              **attrs: Any) -> Iterator[Span]:
-        s = self.start(name, parent=parent, trace=trace, phase=phase,
-                       **attrs)
-        try:
-            yield s
-        except BaseException:
-            s.end(error=True)
-            raise
-        s.end()
+        """A span that opens and closes here, and so also stands on the
+        profiler's timeline: under ``annotation`` (default: its own name)
+        with ``counters`` as the annotation's arguments. Neither reaches
+        the JSONL event, which carries ``attrs`` as before."""
+        with annotate(annotation or name, **(counters or {})):
+            s = self.start(name, parent=parent, trace=trace, phase=phase,
+                           **attrs)
+            try:
+                yield s
+            except BaseException:
+                s.end(error=True)
+                raise
+            s.end()
 
     def _finish(self, span: Span) -> None:
         dur_ns = max(0, int(self.clock_ns()) - span.start_ns)
@@ -228,12 +258,17 @@ class Spans:
         self._count: Dict[str, int] = defaultdict(int)
 
     @contextlib.contextmanager
-    def __call__(self, name: str) -> Iterator[None]:
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.add(name, time.perf_counter() - t0)
+    def __call__(self, name: str, *, annotation: Optional[str] = None,
+                 **counters: Any) -> Iterator[Any]:
+        """Time the block under ``name``; on the profiler's timeline it
+        stands under ``annotation`` (default: ``name``) with ``counters``.
+        Yields the annotation: ``set_metadata`` adds counters at the end."""
+        with annotate(annotation or name, **counters) as ann:
+            t0 = time.perf_counter()
+            try:
+                yield ann
+            finally:
+                self.add(name, time.perf_counter() - t0)
 
     def add(self, name: str, seconds: float) -> None:
         with self._lock:
@@ -300,32 +335,19 @@ class StepTimer:
 
 # ------------------------------------------------------------- device traces
 
-# Set while a jax.profiler device trace is live (device_trace below):
-# Tracer.start checks it before paying any jax import or TraceAnnotation
-# cost, so tracing stays free for host-only runs and jax-free processes.
-_DEVICE_TRACE_DEPTH = 0
-
-
-def _profiling() -> bool:
-    return _DEVICE_TRACE_DEPTH > 0
-
-
 @contextlib.contextmanager
 def device_trace(log_dir: str) -> Iterator[None]:
-    """jax.profiler device trace (XLA ops, HBM, ICI) → TensorBoard/Perfetto
-    trace in ``log_dir``. While active, every ``Tracer`` span also enters a
-    ``jax.profiler.TraceAnnotation``, so the host-side spans (queue waits,
-    chunk staging, checkpoint writes) appear ON the device timeline — the
-    correlation the ACCO-style overlap work needs to verify that "overlap"
-    is real rather than inferred from aggregate step times."""
-    global _DEVICE_TRACE_DEPTH
+    """Start the jax.profiler (XLA ops, HBM, ICI) round a block and stop it
+    after: a TensorBoard/Perfetto trace in ``log_dir``. Nothing else: the
+    program's spans (``serve.*``, ``engine.*``, ``train.*``; the names are
+    listed in docs/COMPONENTS.md) and its named scopes are on that
+    timeline whoever started the profiler, this or
+    ``jax.profiler.start_trace``."""
     import jax
     jax.profiler.start_trace(log_dir)
-    _DEVICE_TRACE_DEPTH += 1
     try:
         yield
     finally:
-        _DEVICE_TRACE_DEPTH -= 1
         jax.profiler.stop_trace()
 
 

@@ -235,6 +235,24 @@ def _notify_checkpoint(hook, step: int, state, log_fn) -> None:
                f"({type(e).__name__}: {e}); continuing")
 
 
+def _phase_spans(tracer: Tracer, spans: Spans):
+    """The loops' one way to time a host phase. ``_phase(name, parent,
+    span_name, **counters)`` accumulates under ``name`` (``data``,
+    ``dispatch``, ``sink``, ``checkpoint``) in ``spans``, through a child
+    span ``span_name`` of ``parent`` in the event stream when there is one,
+    and either way stands on the profiler's timeline as ``train.<name>``
+    with ``counters`` (telemetry/trace.py)."""
+
+    def _phase(name: str, parent, span_name: str, **counters):
+        if parent is not None:
+            return tracer.span(span_name, parent=parent.ctx, phase=name,
+                               annotation="train." + name,
+                               counters=counters)
+        return spans(name, annotation="train." + name, **counters)
+
+    return _phase
+
+
 def _run_loop(step_fn, state, batches, train_cfg: TrainConfig, shard_fn, *,
               n_data: int, start_step: int, ckpt, checkpoint_every: int,
               loss_sink, sink_every: int, log_every: int, log_fn,
@@ -333,11 +351,7 @@ def _run_loop(step_fn, state, batches, train_cfg: TrainConfig, shard_fn, *,
     # stream); chunked mode traces every dispatch (already coarse).
     tracer = Tracer(telemetry.events if telemetry is not None else None,
                     phases=spans)
-
-    def _phase(name: str, parent, span_name: str):
-        if parent is not None:
-            return tracer.span(span_name, parent=parent.ctx, phase=name)
-        return spans(name)
+    _phase = _phase_spans(tracer, spans)
 
     last_event_t = time.perf_counter()
     last_event_it = start_step - 1
@@ -436,9 +450,9 @@ def _run_loop(step_fn, state, batches, train_cfg: TrainConfig, shard_fn, *,
                 t_iter = time.perf_counter()
                 n_compiles = (len(compile_watch.compiles)
                               if compile_watch is not None else 0)
-                with _phase("dispatch", droot, "compute") as csp:
+                with _phase("dispatch", droot, "compute", it=it) as csp:
                     state, out = step_fn(state, shard_fn(host_batch))
-                    if (csp is not None and compile_watch is not None
+                    if (droot is not None and compile_watch is not None
                             and len(compile_watch.compiles) > n_compiles):
                         csp.attrs["compiled"] = True
                 loss, naux = introspect.split_step_output(out)
@@ -564,9 +578,9 @@ def _run_loop(step_fn, state, batches, train_cfg: TrainConfig, shard_fn, *,
                 t_iter = time.perf_counter()
                 n_compiles = (len(compile_watch.compiles)
                               if compile_watch is not None else 0)
-                with _phase("dispatch", droot, "compute") as csp:
+                with _phase("dispatch", droot, "compute", it=it0) as csp:
                     state, out = step_fn(state, window_shard_fn(window))
-                    if (csp is not None and compile_watch is not None
+                    if (droot is not None and compile_watch is not None
                             and len(compile_watch.compiles) > n_compiles):
                         csp.attrs["compiled"] = True
                 losses, naux = introspect.split_step_output(out)
@@ -716,11 +730,7 @@ def _run_elastic_loop(controller, step_fn, state, batches,
     spans = Spans()
     tracer = Tracer(telemetry.events if telemetry is not None else None,
                     phases=spans)
-
-    def _phase(name: str, parent, span_name: str):
-        if parent is not None:
-            return tracer.span(span_name, parent=parent.ctx, phase=name)
-        return spans(name)
+    _phase = _phase_spans(tracer, spans)
 
     K = max(1, steps_per_dispatch)
     last_event_t = time.perf_counter()
@@ -836,7 +846,7 @@ def _run_elastic_loop(controller, step_fn, state, batches,
             t_iter = time.perf_counter()
             this_dispatch, dispatch_idx = dispatch_idx, dispatch_idx + 1
             try:
-                with _phase("dispatch", droot, "compute"):
+                with _phase("dispatch", droot, "compute", it=it0):
                     state, losses = step_fn(state,
                                             window_shard_fn(window))
             except (ReplicaLossError, ReplicaReturnSignal) as err:

@@ -5,8 +5,10 @@ The tracing/profiling half of this module moved to
 ``StepTimer`` and ``device_trace`` are re-exported here unchanged so
 existing imports keep working, but there is now ONE tracing path — the
 span Tracer feeds the same ``Spans`` accumulators the registry absorbs,
-and ``device_trace`` additionally bridges host spans onto the XLA profiler
-timeline. New code should import from ``telemetry.trace`` directly.
+and every ``with`` span of either also enters a
+``jax.profiler.TraceAnnotation``, whoever started the profiler
+(``device_trace`` is the plain start and stop of it). New code should
+import from ``telemetry.trace`` directly.
 
 What still lives here is result persistence:
 
