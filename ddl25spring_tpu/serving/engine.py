@@ -18,9 +18,11 @@ math into TWO reusable compiled programs over a fixed slot axis ``[S]``:
   scores a draft's whole proposal, so decode throughput scales with the
   acceptance rate instead of paying one dispatch per token.
 
-Each is compiled exactly once per engine (static shapes; the pool is
-donated so XLA updates blocks in place — with gather narrowing, once per
-bucketed table width), and all are built from the same
+Each is compiled exactly once per engine (static shapes — with gather
+narrowing, once per bucketed table width). The stacked pool is donated and
+is the layer scan's carry, written and gathered by (layer, block, offset),
+so argument, loop state and result are one buffer and no program slices a
+layer's pool out of it or writes one back. All are built from the same
 building blocks as ``generate`` — ``_fuse_blocks``, ``llama.embed/head``,
 the fp32-softmax attention layout of ``_attend_cached`` — deliberately
 op-for-op, because the acceptance bar is BITWISE: a request decoded here,
@@ -140,16 +142,19 @@ def _apply_rope_slots(x: jnp.ndarray, cos: jnp.ndarray,
     return jnp.concatenate([x1 * c - x2 * s, x1 * s + x2 * c], axis=-1)
 
 
-def _block_paged(block: dict, pk: jnp.ndarray, pv: jnp.ndarray,
-                 x: jnp.ndarray, positions: jnp.ndarray,
+def _block_paged(block: dict, layer: jnp.ndarray, pk: jnp.ndarray,
+                 pv: jnp.ndarray, x: jnp.ndarray, positions: jnp.ndarray,
                  tables: jnp.ndarray, wblk: jnp.ndarray, woff: jnp.ndarray,
                  cfg: LlamaConfig):
-    """One pre-fused block over x [S, T, D] at per-slot absolute
-    ``positions`` [S, T], writing this call's K/V into pool blocks at
-    (``wblk``, ``woff``) [S, T] and attending over each slot's gathered
-    block table. The paged twin of ``generate._block_with_cache``; the
-    scatter/gather replaces its dynamic_update_slice/full-cache read, the
-    math around them is identical."""
+    """One pre-fused block, layer number ``layer``, over x [S, T, D] at
+    per-slot absolute ``positions`` [S, T]. ``pk``/``pv`` are the WHOLE
+    stacked pool [L, num_blocks, block_len, H, Dh]: this call's K/V is
+    scattered into it at (``layer``, ``wblk``, ``woff``) [S, T] and each
+    slot's block table is gathered from it at (``layer``, ``tables``); no
+    operation produces or consumes one layer's pool. The paged twin of
+    ``generate._block_with_cache``; the scatter/gather replaces its
+    dynamic_update_slice/full-cache read, the math around them is
+    identical."""
     s, t, d = x.shape
     dh = cfg.head_dim
     # The named scopes cost nothing at run time and change no number: they
@@ -169,16 +174,16 @@ def _block_paged(block: dict, pk: jnp.ndarray, pv: jnp.ndarray,
         sin = sin.reshape(s, t, -1)
         q = _apply_rope_slots(q, cos, sin)
         k = _apply_rope_slots(k, cos, sin)   # cached K is stored post-RoPE
-    # Per-token scatter into the block pool. Distinct (block, offset)
+    # Per-token scatter into the stacked pool. Distinct (block, offset)
     # targets are guaranteed by block ownership; only TRASH_BLOCK collides
-    # (inactive slots, padded tails) and its contents are never read
-    # un-masked.
+    # (inactive slots, padded tails; each layer has its own) and its
+    # contents are never read un-masked.
     with jax.named_scope("paged.write"):
-        pk = pk.at[wblk, woff].set(k.astype(pk.dtype))
-        pv = pv.at[wblk, woff].set(v.astype(pv.dtype))
+        pk = pk.at[layer, wblk, woff].set(k.astype(pk.dtype))
+        pv = pv.at[layer, wblk, woff].set(v.astype(pv.dtype))
     with jax.named_scope("paged.gather"):
-        ck = pk[tables].reshape(s, -1, h_local, dh)    # [S, Tmax, H, Dh]
-        cv = pv[tables].reshape(s, -1, h_local, dh)
+        ck = pk[layer, tables].reshape(s, -1, h_local, dh)  # [S, Tmax, H, Dh]
+        cv = pv[layer, tables].reshape(s, -1, h_local, dh)
     with jax.named_scope("paged.attend"):
         out = _attend_paged(q, ck, cv, positions)
     with jax.named_scope("attn_out"):
@@ -195,24 +200,29 @@ def _forward_paged(params: dict, fused_blocks: dict, tokens: jnp.ndarray,
                    pool: dict, tables: jnp.ndarray, positions: jnp.ndarray,
                    wblk: jnp.ndarray, woff: jnp.ndarray, cfg: LlamaConfig):
     """tokens [S, T] at per-slot absolute ``positions`` [S, T] → (hidden
-    [S, T, D], updated pool). One lax.scan over the stacked layers,
-    threading each layer's block-pool slice — the paged twin of
-    ``generate._forward_fused`` (which threads cache slices)."""
+    [S, T, D], updated pool). One lax.scan over (layer number, fused
+    block) with the hidden state AND the whole stacked pool as its carry:
+    under the programs' donation of the pool, argument, loop state and
+    result are one buffer, written and gathered in place by (layer, block,
+    offset). (``generate._forward_fused`` scans its cache as stacked
+    inputs and outputs; a pool of gigabytes cannot afford the slice out
+    and the write back that costs, every layer of every run.)"""
     h = llama.embed(params, tokens, cfg)
+    layers = jnp.arange(pool["k"].shape[0], dtype=jnp.int32)
 
-    def body(carry, layer):
-        block, pk, pv = layer
-        out, pk, pv = _block_paged(block, pk, pv, carry, positions,
-                                   tables, wblk, woff, cfg)
-        return out, (pk, pv)
+    def body(carry, layer_block):
+        x, pk, pv = carry
+        layer, block = layer_block
+        return _block_paged(block, layer, pk, pv, x, positions, tables,
+                            wblk, woff, cfg), None
 
-    # ``layers`` names what the scan itself does to its stacked inputs and
-    # outputs (each layer's pool sliced out of the stacked pool and written
-    # back): those operations read ``.../layers/while/body/<op>`` with no
-    # inner scope, which is what ``decode_unscoped_ms.serve`` times.
+    # ``layers`` names the scan. The block's operations read
+    # ``.../layers/while/body/closed_call/<scope>/<op>``; what stands under
+    # ``layers`` with no inner scope (what the compiler hoists out of one)
+    # is what ``decode_unscoped_ms.serve`` times.
     with jax.named_scope("layers"):
-        h, (pk, pv) = lax.scan(body, h,
-                               (fused_blocks, pool["k"], pool["v"]))
+        (h, pk, pv), _ = lax.scan(body, (h, pool["k"], pool["v"]),
+                                  (layers, fused_blocks))
     return h, {"k": pk, "v": pv}
 
 

@@ -9,8 +9,9 @@ they use them or not. Here sequences share ONE pool of fixed-size blocks
 static-shape/one-compile discipline):
 
 - The device side is a pair of static-shape arrays ``[L, num_blocks,
-  block_len, H, Dh]`` (layer-major, so the engine's per-layer ``lax.scan``
-  threads one block-pool slice per layer exactly like ``generate``'s cache).
+  block_len, H, Dh]`` (layer-major; the engine's per-layer ``lax.scan``
+  carries the whole pair and writes and gathers it in place by (layer,
+  block, offset) — it never slices one layer's pool out or writes one back).
   ``kv_dtype`` reuses ``init_cache``'s storage-dtype option: bf16 blocks
   halve the decode loop's dominant HBM stream (experiments/ROOFLINE.md,
   decode section — the batch-32 KV-bound regime is the serving case).
@@ -83,8 +84,9 @@ def blocks_for(n_tokens: int, block_len: int) -> int:
 
 def init_pool(cfg: LlamaConfig, paged: PagedKVConfig) -> dict:
     """Zeroed block pool: {"k","v"} each [L, num_blocks, block_len, H, Dh].
-    Layer-major for the same reason ``init_cache`` is: the engine scans the
-    leading axis, threading one layer's blocks per scan step."""
+    Layer-major like ``init_cache``, but the engine does not scan the
+    leading axis: the whole stacked pool is its layer scan's carry, and each
+    layer scatters into and gathers from it at (layer, block, offset)."""
     dt = jnp.dtype(paged.kv_dtype or cfg.dtype)
     shape = (cfg.n_layers, paged.num_blocks, paged.block_len,
              cfg.num_heads, cfg.head_dim)

@@ -26,6 +26,7 @@ from ddl25spring_tpu.serving.engine import (Engine, make_decode_step,
                                             make_prefill_chunk)
 from ddl25spring_tpu.serving.kvcache import PagedKVConfig, init_pool
 from ddl25spring_tpu.serving.scheduler import Request, Scheduler
+from ddl25spring_tpu.serving.speculate import make_verify_step
 from ddl25spring_tpu.telemetry import EventLog, Telemetry, read_events
 from ddl25spring_tpu.telemetry import trace as trace_mod
 from ddl25spring_tpu.telemetry.trace import Tracer
@@ -215,8 +216,8 @@ def scope_parts(lowered) -> set:
 
 
 def lower_programs(devices) -> dict:
-    """The train step, ``decode_step`` and ``prefill_chunk``, each built
-    anew and lowered at a tiny size."""
+    """The train step, ``decode_step``, ``prefill_chunk`` and
+    ``verify_step``, each built anew and lowered at a tiny size."""
     params = llama.init_llama(jax.random.key(0), TINY)
     mesh = make_mesh({"data": 2}, devices=devices[:2])
     opt = optax.adam(1e-3)
@@ -236,8 +237,15 @@ def lower_programs(devices) -> dict:
         pool, params, fused, jnp.zeros(8, jnp.int32),
         jnp.zeros(8, jnp.int32), jnp.int32(0), jnp.int32(8), jnp.int32(0),
         jnp.zeros(2, jnp.uint32), jnp.float32(0))
+    k = 2
+    verify = make_verify_step(TINY, PAGED, s, k, None, None).lower(
+        pool, params, fused, jnp.zeros((s, 8), jnp.int32),
+        jnp.zeros((s, k + 1), jnp.int32),
+        jnp.zeros((s, k, TINY.vocab_size)), jnp.zeros(s, jnp.int32),
+        jnp.ones(s, jnp.int32), jnp.zeros((s, 2), jnp.uint32), jnp.zeros(s),
+        jnp.zeros(s, bool))
     return {"train": step.lower(state, batch), "decode": decode,
-            "prefill": prefill}
+            "prefill": prefill, "verify": verify}
 
 
 @pytest.fixture(scope="module")
@@ -254,11 +262,15 @@ SERVING_SCOPES = ["embed", "layers", "qkv", "paged.write", "paged.gather",
                   "paged.attend", "attn_out", "mlp", "head", "sample"]
 TRAIN_SCOPES = ["embed", "attn", "mlp", "head_loss", "grad_sync",
                 "optimizer", "guard"]
+SERVING_PROGRAMS = {"decode": "decode_step", "prefill": "prefill_chunk",
+                    "verify": "verify_step"}
 
 
 @pytest.mark.parametrize("program,scope", [
     ("train", s) for s in TRAIN_SCOPES
-] + [(p, s) for p in ("decode", "prefill") for s in SERVING_SCOPES])
+] + [(p, s) for p in SERVING_PROGRAMS for s in SERVING_SCOPES
+     # verify_step samples at every window position under no scope of its own
+     if (p, s) != ("verify", "sample")])
 def test_lowered_program_holds_the_scope(lowered_scopes, program, scope):
     assert scope in lowered_scopes[program]
 
@@ -269,22 +281,65 @@ def test_jax_writes_forward_and_backward_into_the_scope_path(lowered_scopes):
     assert {"jvp", "transpose"} <= lowered_scopes["train"]
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill"])
-def test_the_scans_own_work_on_the_stacked_pool_reads_layers_while_body(
+def tensor_types(shape) -> tuple:
+    """The MLIR types of ``shape``, as it is and with a leading 1."""
+    dims = "x".join(str(d) for d in shape)
+    return (f"tensor<{dims}x", f"tensor<1x{dims}x")
+
+
+@pytest.mark.parametrize("program", sorted(SERVING_PROGRAMS))
+def test_the_stacked_pool_is_the_layer_scans_carry_and_is_never_sliced(
         lowered, program):
-    """``layers`` names the ``lax.scan`` of ``_forward_paged``: what the
-    block does stands under it and a scope of its own, what the scan does
-    to its stacked inputs and outputs under it alone (what
-    ``decode_unscoped_ms.serve`` times)."""
+    """``_forward_paged`` carries the whole stacked pool through the
+    ``layers`` scan and ``_block_paged`` scatters into it and gathers from
+    it by (layer, block, offset): no operation takes one layer's pool out
+    of the stacked pool or puts one back (a ``dynamic_slice`` /
+    ``dynamic_update_slice`` pair on the pool is two copies of a layer's
+    pool for every layer of every run)."""
+    text = lowered[program].as_text()
     names = set(loc_names(lowered[program]))
-    body = f"jit({program}_step)/layers/while/body/".replace(
-        "prefill_step", "prefill_chunk")
-    # the block is called from the scan's body (its own operations are named
-    # from there on: ``closed_call/paged.gather/gather`` in a device trace)
+    pool_shape = init_pool(TINY, PAGED)["k"].shape
+    stacked = tensor_types(pool_shape)[0]
+    # This reads JAX's printed MLIR by type. TINY and PAGED are chosen so
+    # that no other tensor of these programs (the gathered cache
+    # [S, Tmax, H, Dh] = [2, 32, 2, 8], the attention's operands, a
+    # layer's weights) has the shape of a layer's pool
+    # [num_blocks, block_len, H, Dh] = [33, 4, 2, 8]: change
+    # them and a match below may be another tensor's. The positive match
+    # on ``stacked`` keeps the negative ones from passing on a printing
+    # that spells types another way.
+    # the scan's loop carries K and V of the stacked pool, and the block,
+    # called from its body, takes and returns them
+    whiles = [ln for ln in text.splitlines() if "stablehlo.while" in ln
+              and ln.split(") :")[-1].count(stacked) == 2]
+    assert len(whiles) == 1
+    body = f"jit({SERVING_PROGRAMS[program]})/layers/while/body/"
     assert body + "closed_call" in names
-    assert "paged.gather/gather" in names
-    # each layer's pool sliced out of the stacked pool and written back
-    assert {body + "dynamic_slice", body + "dynamic_update_slice"} <= names
+    # the block's operations keep the paths the trace's readers match
+    # (``closed_call/paged.gather/gather`` in a device trace)
+    assert {"paged.write/scatter", "paged.gather/gather"} <= names
+    assert any(n.startswith("paged.attend/") for n in names)
+    # no tensor anywhere is one layer's pool, with or without a leading 1
+    for per_layer in tensor_types(pool_shape[1:]):
+        assert per_layer not in text
+    # and nothing but the two scatters makes a stacked pool (the scan's
+    # own ``dynamic_slice``s take a layer's weights and its number)
+    for ln in text.splitlines():
+        if re.search(r"stablehlo\.(dynamic_slice|dynamic_update_slice|slice"
+                     r"|concatenate|copy)\b", ln):
+            assert stacked not in ln, ln
+
+
+def test_compiled_decode_step_aliases_the_pool_arguments_to_its_results(
+        lowered):
+    """The donated pool comes back in the buffers it came in: with the
+    pool as the loop's carry, argument, loop state and result can be one
+    buffer (CPU compile; the chip's is read in PERF.md)."""
+    head = lowered["decode"].compile().as_text().split("\n", 1)[0]
+    alias = re.search(r"input_output_alias=\{(.*?\)) \}", head).group(1)
+    # result 0 is argument 0 (pool K), result 1 is argument 1 (pool V)
+    assert re.findall(r"\{(\d+)\}: \((\d+), \{\}", alias) == [
+        ("0", "0"), ("1", "1")]
 
 
 # ------------------------- section D: the set-up path is the parent's
@@ -316,7 +371,7 @@ def lowered_without_our_scopes(devices):
         cls.__enter__ = enter
 
 
-@pytest.mark.parametrize("program", ["train", "decode", "prefill"])
+@pytest.mark.parametrize("program", ["train", *sorted(SERVING_PROGRAMS)])
 def test_named_scopes_add_remove_and_reorder_no_operation(
         lowered, lowered_without_our_scopes, program):
     """The lowered text (locations left out) is the same with the scopes as
