@@ -111,6 +111,189 @@ class LlamaConfig:
 
 
 @dataclass(frozen=True)
+class LatentAttention:
+    """Latent attention (MLA): queries through a rank-``q_rank`` bottleneck,
+    keys and values through ONE latent row a position, ``kv_rank`` values
+    (after their norm) beside ``rope_dim`` rotated values that every head
+    shares. The cache holds that row and nothing per head. RoPE is YaRN
+    (``rope_factor`` 1: plain)."""
+
+    q_rank: int
+    kv_rank: int
+    nope_dim: int                  # a head's un-rotated query/key part
+    rope_dim: int                  # the rotated part, one key row for all heads
+    v_dim: int                     # a head's value size
+    rope_factor: float = 1.0
+    rope_original_ctx: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @property
+    def row_dim(self) -> int:
+        """Values a cache position holds in one layer."""
+        return self.kv_rank + self.rope_dim
+
+    @property
+    def qk_dim(self) -> int:
+        return self.nope_dim + self.rope_dim
+
+
+@dataclass(frozen=True)
+class ExpertLayer:
+    """A layer of routed experts beside ``n_shared`` shared ones, as ONE chip
+    of an expert-parallel deployment sees it: the router scores all
+    ``n_experts`` (its published width), and this chip holds the contiguous
+    range ``[held_start, held_start + held_count)`` of them."""
+
+    n_experts: int
+    top_k: int
+    width: int                     # hidden width of each expert
+    n_shared: int
+    scale: float                   # routed_scaling_factor
+    norm_topk: bool
+    held_start: int
+    held_count: int
+
+    def __post_init__(self):
+        if not (0 <= self.held_start
+                and self.held_start + self.held_count <= self.n_experts
+                and self.held_count >= 1 and self.top_k <= self.n_experts):
+            raise ValueError(f"bad expert layer: {self}")
+
+
+@dataclass(frozen=True)
+class ModelDescription:
+    """A decoder as the serving engine reads it: one kind per layer
+    (``"dense"``: SwiGLU of width ``ffn_hidden``; ``"experts"``:
+    ``experts``), one attention kind for all layers (``attention`` None:
+    K and V per head with heads of ``dmodel / num_heads``; else latent),
+    and the sizes that follow. ``LlamaConfig`` models are described by
+    ``describe``; a published ``config.json`` by ``from_published``."""
+
+    vocab_size: int
+    dmodel: int
+    num_heads: int
+    layer_kinds: Tuple[str, ...]
+    ffn_hidden: int
+    norm_eps: float = 1e-5
+    rope_theta: float = 10000.0
+    ctx_size: int = 256
+    dtype: str = "float32"
+    param_dtype: str = "float32"
+    attention: Optional[LatentAttention] = None
+    experts: Optional[ExpertLayer] = None
+    init_std: float = 0.02         # ``initializer_range``
+
+    def __post_init__(self):
+        bad = set(self.layer_kinds) - {"dense", "experts"}
+        if bad or not self.layer_kinds:
+            raise ValueError(f"layer kinds {sorted(bad)}: the engine has "
+                             "'dense' and 'experts'")
+        if "experts" in self.layer_kinds and self.experts is None:
+            raise ValueError("expert layers without an ExpertLayer")
+
+    @property
+    def n_layers(self) -> int:
+        return len(self.layer_kinds)
+
+    @property
+    def plain(self) -> bool:
+        """K and V per head and dense layers only: what ``LlamaConfig``
+        states, and the engine's original path."""
+        return self.attention is None and set(self.layer_kinds) == {"dense"}
+
+    @property
+    def cache_row(self) -> int:
+        """Values one cache position holds in one layer."""
+        if self.attention is not None:
+            return self.attention.row_dim
+        return 2 * self.dmodel
+
+    def runs(self) -> Tuple[Tuple[str, int, int], ...]:
+        """The layers as runs of one kind: (kind, first layer, count)."""
+        out, start = [], 0
+        for i in range(1, self.n_layers + 1):
+            if i == self.n_layers or self.layer_kinds[i] != self.layer_kinds[start]:
+                out.append((self.layer_kinds[start], start, i - start))
+                start = i
+        return tuple(out)
+
+    def replace(self, **kw) -> "ModelDescription":
+        return dataclasses.replace(self, **kw)
+
+    @classmethod
+    def from_published(cls, cfg: dict, *, ctx_size: int, dtype: str,
+                       param_dtype: str) -> "ModelDescription":
+        """From the keys of a public ``config.json``, as
+        ``benchmarks/configs/*.json`` carry them. Where the file states a
+        chip's share, ``n_routed_experts`` counts the experts held here,
+        ``first_held_expert`` says where the range starts and
+        ``published["n_routed_experts"]`` is the router's width."""
+        n = int(cfg["num_hidden_layers"])
+        attention = experts = None
+        kinds = ("dense",) * n
+        if "kv_lora_rank" in cfg:
+            rs = cfg.get("rope_scaling") or {}
+            attention = LatentAttention(
+                q_rank=int(cfg["q_lora_rank"]),
+                kv_rank=int(cfg["kv_lora_rank"]),
+                nope_dim=int(cfg["qk_nope_head_dim"]),
+                rope_dim=int(cfg["qk_rope_head_dim"]),
+                v_dim=int(cfg["v_head_dim"]),
+                rope_factor=float(rs.get("factor", 1.0)),
+                rope_original_ctx=int(rs.get(
+                    "original_max_position_embeddings", 4096)),
+                beta_fast=float(rs.get("beta_fast", 32)),
+                beta_slow=float(rs.get("beta_slow", 1)),
+                mscale=float(rs.get("mscale", 1)),
+                mscale_all_dim=float(rs.get("mscale_all_dim", 0)))
+        elif cfg.get("num_key_value_heads",
+                     cfg["num_attention_heads"]) != cfg["num_attention_heads"]:
+            raise ValueError("grouped K/V heads: the engine has none")
+        if cfg.get("n_routed_experts"):
+            held = int(cfg["n_routed_experts"])
+            experts = ExpertLayer(
+                n_experts=int(cfg.get("published", {}).get(
+                    "n_routed_experts", held)),
+                top_k=int(cfg["num_experts_per_tok"]),
+                width=int(cfg["moe_intermediate_size"]),
+                n_shared=int(cfg.get("n_shared_experts", 0)),
+                scale=float(cfg.get("routed_scaling_factor", 1.0)),
+                norm_topk=bool(cfg.get("norm_topk_prob", False)),
+                held_start=int(cfg.get("first_held_expert", 0)),
+                held_count=held)
+            first = int(cfg.get("first_k_dense_replace", 0))
+            freq = int(cfg.get("moe_layer_freq", 1))
+            kinds = tuple("experts" if i >= first and i % freq == 0
+                          else "dense" for i in range(n))
+        return cls(vocab_size=int(cfg["vocab_size"]),
+                   dmodel=int(cfg["hidden_size"]),
+                   num_heads=int(cfg["num_attention_heads"]),
+                   layer_kinds=kinds,
+                   ffn_hidden=int(cfg["intermediate_size"]),
+                   norm_eps=float(cfg["rms_norm_eps"]),
+                   rope_theta=float(cfg["rope_theta"]),
+                   ctx_size=ctx_size, dtype=dtype, param_dtype=param_dtype,
+                   attention=attention, experts=experts,
+                   init_std=float(cfg.get("initializer_range", 0.02)))
+
+
+def describe(cfg) -> ModelDescription:
+    """The ``ModelDescription`` of a ``LlamaConfig`` (or ``cfg`` itself
+    where it is one already)."""
+    if isinstance(cfg, ModelDescription):
+        return cfg
+    return ModelDescription(
+        vocab_size=cfg.vocab_size, dmodel=cfg.dmodel,
+        num_heads=cfg.num_heads, layer_kinds=("dense",) * cfg.n_layers,
+        ffn_hidden=cfg.ffn_dim, norm_eps=cfg.norm_eps,
+        rope_theta=cfg.rope_theta, ctx_size=cfg.ctx_size, dtype=cfg.dtype,
+        param_dtype=cfg.param_dtype)
+
+
+@dataclass(frozen=True)
 class MoEConfig:
     """Mixture-of-Experts tiny-Llama configuration (parity-plus: the
     reference has no MoE/expert parallelism — SURVEY.md §2.10 marks EP

@@ -18,6 +18,16 @@ math into TWO reusable compiled programs over a fixed slot axis ``[S]``:
   scores a draft's whole proposal, so decode throughput scales with the
   acceptance rate instead of paying one dispatch per token.
 
+What a program runs is read from the model's description
+(``config.describe``): a ``LlamaConfig`` model's dense block over a pool of
+K and V per head (``_block_paged``; everything below about bitwise parity is
+of this path, and its lowered text is the parent's), or, for a
+``ModelDescription`` with latent attention and expert layers, one scan a run
+of layers of one kind over a pool of one latent row a position a layer
+(``_forward_described``; ``models/latent.py``, ``models/experts.py``, imported
+only then; checked against a float32 reference, tests/test_latent_experts.py).
+The engine keeps ONE copy of each weight, in the layout its programs read.
+
 Each is compiled exactly once per engine (static shapes — with gather
 narrowing, once per bucketed table width). The stacked pool is donated and
 is the layer scan's carry, written and gathered by (layer, block, offset),
@@ -55,7 +65,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..config import LlamaConfig
+from ..config import LlamaConfig, ModelDescription, describe
 from .. import nn
 from ..models import generate, llama
 from ..telemetry.trace import Spans
@@ -83,9 +93,22 @@ def check_swappable(old, new) -> None:
                 "retrace the engine's two compiled programs")
 
 
+class LeafSpec:
+    """What the engine remembers of a leaf of the tree it was booted with,
+    once it holds the weights in its own layout: enough for
+    ``check_swappable`` and ``_match_placement``."""
+    __slots__ = ("shape", "dtype", "committed", "sharding")
+
+    def __init__(self, leaf):
+        self.shape, self.dtype = leaf.shape, leaf.dtype
+        self.committed = bool(getattr(leaf, "committed", False))
+        self.sharding = getattr(leaf, "sharding", None)
+
+
 def _match_placement(new, old):
     """Return ``new`` placed EXACTLY like ``old`` (device + committed-ness,
-    leaf by leaf). The jit cache key includes argument placement, so a
+    leaf by leaf; ``old`` may be a tree of ``LeafSpec``). The jit cache
+    key includes argument placement, so a
     hot-swapped tree must be indistinguishable in placement from the boot
     params or both compiled programs would silently retrace — and a tree
     restored from a checkpoint arrives device_put-COMMITTED while
@@ -94,7 +117,7 @@ def _match_placement(new, old):
     params-sized copy per publish, trivial next to the disk read that
     produced the tree."""
     def fix(n, o):
-        if not isinstance(n, jax.Array) or not isinstance(o, jax.Array):
+        if not isinstance(n, jax.Array) or not hasattr(o, "sharding"):
             return n
         nc = bool(getattr(n, "committed", False))
         oc = bool(getattr(o, "committed", False))
@@ -196,17 +219,101 @@ def _block_paged(block: dict, layer: jnp.ndarray, pk: jnp.ndarray,
     return x, pk, pv
 
 
-def _forward_paged(params: dict, fused_blocks: dict, tokens: jnp.ndarray,
+def _block_latent(block: dict, kind: str, layer: jnp.ndarray,
+                  pc: jnp.ndarray, x: jnp.ndarray, positions: jnp.ndarray,
+                  tables: jnp.ndarray, wblk: jnp.ndarray, woff: jnp.ndarray,
+                  valid: jnp.ndarray, desc: ModelDescription,
+                  group_offset=None):
+    """One layer of a latent-attention model (``models/latent.py``), layer
+    number ``layer`` of kind ``kind``, over x [S, T, D]: the paged twin of
+    ``_block_paged`` for a pool ``pc`` [L, num_blocks, block_len, row_stride]
+    of ONE row a position a layer. The row is written after its norm and
+    rotation; attention reads the gathered rows as they lie, folded into
+    latent space for a decode step, expanded for a prefill chunk
+    (``latent.attend`` picks by T). Returns (x, pc, routing stats of an
+    expert layer or None)."""
+    from ..models import latent
+
+    s, t, _ = x.shape
+    with jax.named_scope("q_proj"):
+        xn = nn.rmsnorm(block["attn_norm"], x, eps=desc.norm_eps)
+        cos, sin = latent.rope_tables(positions, desc.attention,
+                                      desc.rope_theta)
+        q = latent.queries(block, xn, cos, sin, desc)
+    with jax.named_scope("kv_proj"):
+        row = latent.latent_row(block, xn, cos, sin, desc)
+    with jax.named_scope("latent.write"):
+        # whole vectors of lanes a row, the rest zero (kvcache.row_stride)
+        row = jnp.pad(row, ((0, 0), (0, 0), (0, pc.shape[-1] - row.shape[-1])))
+        pc = pc.at[layer, wblk, woff].set(row.astype(pc.dtype))
+    with jax.named_scope("latent.gather"):
+        rows = pc[layer, tables].reshape(s, -1, pc.shape[-1])
+    with jax.named_scope("latent.attend"):
+        out = latent.attend(block["w_kvb"], q, rows, positions, desc)
+    with jax.named_scope("attn_out"):
+        x = x + out.reshape(s, t, -1) @ block["w_o"].astype(x.dtype)
+    x, stats = latent.second_half(block, kind, x, desc, valid, group_offset)
+    return x, pc, stats
+
+
+def _forward_described(head: dict, runs: tuple, tokens: jnp.ndarray,
+                       pool: dict, tables, positions, wblk, woff, valid,
+                       desc: ModelDescription):
+    """``_forward_paged`` for a model whose layers differ: one lax.scan a
+    run of layers of one kind (``ModelDescription.runs``; a leading dense
+    layer, then the expert layers), the hidden state and the whole pool
+    the carry of each, the routing stats of an expert run its stacked
+    result [layers of the run, 3]."""
+    with jax.named_scope("embed"):
+        h = head["embed"][tokens].astype(jnp.dtype(desc.dtype))
+    pc, stats = pool["c"], []
+    for (kind, start, count), blocks in zip(desc.runs(), runs):
+        layers = start + jnp.arange(count, dtype=jnp.int32)
+        whole = {}
+        if kind == "experts":
+            # The routed experts stay out of the scanned tree, the run's
+            # layers side by side as one stack of groups, and each layer
+            # names its own by offset (``experts.expert_layer``).
+            whole = {k: blocks[k].reshape((-1,) + blocks[k].shape[2:])
+                     for k in ("we_gu", "we_down")}
+            blocks = {k: v for k, v in blocks.items() if k not in whole}
+        held = desc.experts.held_count if whole else 0
+
+        def body(carry, layer_block):       # traced here, by this scan
+            x, pc = carry
+            layer, block = layer_block
+            x, pc, st = _block_latent(
+                dict(block, **whole), kind, layer, pc, x, positions, tables,
+                wblk, woff, valid, desc,
+                (layer - start) * held if whole else None)
+            return (x, pc), st
+
+        with jax.named_scope("layers"):
+            (h, pc), st = lax.scan(body, (h, pc), (layers, blocks))
+        if st is not None:
+            stats.append(st)
+    return h, {"c": pc}, (jnp.concatenate(stats) if stats else None)
+
+
+def _forward_paged(params: dict, fused_blocks, tokens: jnp.ndarray,
                    pool: dict, tables: jnp.ndarray, positions: jnp.ndarray,
-                   wblk: jnp.ndarray, woff: jnp.ndarray, cfg: LlamaConfig):
+                   wblk: jnp.ndarray, woff: jnp.ndarray, cfg,
+                   valid: Optional[jnp.ndarray] = None):
     """tokens [S, T] at per-slot absolute ``positions`` [S, T] → (hidden
-    [S, T, D], updated pool). One lax.scan over (layer number, fused
-    block) with the hidden state AND the whole stacked pool as its carry:
-    under the programs' donation of the pool, argument, loop state and
-    result are one buffer, written and gathered in place by (layer, block,
-    offset). (``generate._forward_fused`` scans its cache as stacked
-    inputs and outputs; a pool of gigabytes cannot afford the slice out
-    and the write back that costs, every layer of every run.)"""
+    [S, T, D], updated pool, routing stats or None). Dispatches on the
+    model's description: a model of other layer kinds than ``LlamaConfig``
+    states goes through ``_forward_described`` (``valid`` [S, T] marks its
+    real tokens for the expert layers). Else one lax.scan over (layer
+    number, fused block) with the hidden state AND the whole stacked pool
+    as its carry: under the programs' donation of the pool, argument, loop
+    state and result are one buffer, written and gathered in place by
+    (layer, block, offset). (``generate._forward_fused`` scans its cache as
+    stacked inputs and outputs; a pool of gigabytes cannot afford the slice
+    out and the write back that costs, every layer of every run.)"""
+    desc = describe(cfg)
+    if not desc.plain:
+        return _forward_described(params, fused_blocks, tokens, pool, tables,
+                                  positions, wblk, woff, valid, desc)
     h = llama.embed(params, tokens, cfg)
     layers = jnp.arange(pool["k"].shape[0], dtype=jnp.int32)
 
@@ -223,7 +330,7 @@ def _forward_paged(params: dict, fused_blocks: dict, tokens: jnp.ndarray,
     with jax.named_scope("layers"):
         (h, pk, pv), _ = lax.scan(body, (h, pool["k"], pool["v"]),
                                   (layers, fused_blocks))
-    return h, {"k": pk, "v": pv}
+    return h, {"k": pk, "v": pv}, None
 
 
 def _sample_slot(key, logits: jnp.ndarray, temperature: jnp.ndarray,
@@ -275,9 +382,10 @@ def make_prefill_chunk(cfg: LlamaConfig, paged: PagedKVConfig,
         blk_idx = jnp.minimum(pos // bl, mb - 1)
         wblk = jnp.where(valid, table_row[blk_idx], TRASH_BLOCK)
         woff = pos % bl
-        h, pool = _forward_paged(params, fused, tokens[None], pool,
-                                 table_row[None], pos[None],
-                                 wblk[None], woff[None], cfg)
+        h, pool, stats = _forward_paged(
+            params, fused, tokens[None], pool, table_row[None], pos[None],
+            wblk[None], woff[None], cfg,
+            (jnp.arange(chunk_len) < n_valid)[None])
         # Logits of the last valid row only — the [1, 1, D] head matmul
         # ``generate`` performs (never the full [Tc, V] logits).
         last = jnp.take_along_axis(
@@ -286,6 +394,8 @@ def make_prefill_chunk(cfg: LlamaConfig, paged: PagedKVConfig,
         with jax.named_scope("sample"):
             key, sub = jax.random.split(key)
             tok = _sample_slot(sub, logits, temperature, top_k, top_p)
+        if stats is not None:       # a model with expert layers
+            return pool, tok[0], key, stats
         return pool, tok[0], key
 
     return prefill_chunk
@@ -323,9 +433,9 @@ def make_decode_step(cfg: LlamaConfig, paged: PagedKVConfig,
         own = jnp.take_along_axis(tables, blk_idx[:, None], axis=1)[:, 0]
         wblk = jnp.where(active, own, TRASH_BLOCK)
         woff = pos % bl
-        h, pool = _forward_paged(params, fused, last_tok[:, None], pool,
-                                 tables, pos[:, None],
-                                 wblk[:, None], woff[:, None], cfg)
+        h, pool, stats = _forward_paged(
+            params, fused, last_tok[:, None], pool, tables, pos[:, None],
+            wblk[:, None], woff[:, None], cfg, active[:, None])
         logits = llama.head(params, h, cfg)[:, 0, :]               # [S, V]
         with jax.named_scope("sample"):
             split = jax.vmap(jax.random.split)(keys)               # [S, 2, 2]
@@ -339,6 +449,8 @@ def make_decode_step(cfg: LlamaConfig, paged: PagedKVConfig,
             toks = jax.vmap(
                 lambda k, l, t: _sample_slot(k, l[None], t, top_k, top_p)[0]
             )(subs, logits, temps)
+        if stats is not None:       # a model with expert layers
+            return pool, toks, new_keys, stats
         if not return_probs:
             return pool, toks, new_keys
         # Greedy slots' q is unused (their acceptance is the argmax
@@ -408,7 +520,13 @@ class Engine:
     decoding slots, ``live_positions`` = the cache positions the step must
     attend to, sum of ``pos + 1`` over them, ``gathered_positions`` =
     ``num_slots`` x table width passed x ``block_len``, what the program
-    reads as built; ``engine.decode.book``: ``emitted``).
+    reads as built; ``engine.decode.book``: ``emitted``). A model with
+    expert layers adds ``pairs_routed`` to both dispatch spans and, read
+    with the sampled tokens at the host's wait for the device,
+    ``pairs_held``, ``experts_hit``, ``max_pairs`` to
+    ``engine.decode.book``, with the same counts of the prefill chunks
+    dispatched since the last wait as ``chunk_*`` there or on
+    ``engine.prefill.fetch`` (``_note_routing``; totals in ``routing``).
     """
 
     def __init__(self, params: dict, cfg: LlamaConfig, paged: PagedKVConfig,
@@ -422,6 +540,15 @@ class Engine:
             raise ValueError(f"num_slots={num_slots}, "
                              f"prefill_chunk={prefill_chunk}")
         self.cfg = cfg
+        # What the programs dispatch on: the kind of each layer and of the
+        # attention, the cache's row, the experts held here.
+        self.desc = describe(cfg)
+        if not self.desc.plain and (speculate is not None or prefix_share):
+            raise NotImplementedError(
+                "speculation and prefix sharing serve LlamaConfig models "
+                "only: a draft for, and shared blocks of latent rows under, "
+                f"a model of layers {set(self.desc.layer_kinds)} with "
+                "latent attention are not built (ROADMAP.md)")
         self.paged = paged
         self.num_slots = num_slots
         self.prefill_chunk_len = prefill_chunk
@@ -430,8 +557,14 @@ class Engine:
         # N-engine run's 2N compile events attribute per engine) and rides
         # through the scheduler into request_*/route/deploy telemetry.
         self.engine_id = engine_id
-        self.params = params
-        self.fused = generate._fuse_blocks(params["blocks"])  # hoisted once
+        # ONE copy of each weight, in the layout the programs read: the
+        # embedding, final norm and head as given, the layers fused
+        # (``generate._fuse_blocks``: q/k/v and gate/up concatenated, once)
+        # or, for a described model, its stacked runs as they are. The
+        # caller's tree is not kept; ``boot`` remembers its shapes and
+        # placement for the hot-swap contract, and ``params`` rebuilds it.
+        self.boot = jax.tree.map(LeafSpec, params)
+        self._set_weights(params)
         self.pool = init_pool(cfg, paged)
         self.allocator = BlockAllocator(paged.num_blocks)
         self._admit_seq = 0
@@ -492,6 +625,17 @@ class Engine:
         self.spec = speculate
         self.last_spec: Optional[dict] = None
         self.spans = Spans()           # host seconds of a step, by cause
+        # Expert layers: each program hands back, per expert layer, the
+        # pairs computed here, the held experts hit and the most one took
+        # (``models/experts.py::STATS``). They are read with the sampled
+        # tokens, at the host's next wait for the device and never at one
+        # of their own; a prefill chunk's wait there until then.
+        ex = self.desc.experts
+        self._pairs_a_token = (0 if ex is None else ex.top_k
+                               * self.desc.layer_kinds.count("experts"))
+        self._chunk_stats: list = []
+        self.routing = {"pairs_routed": 0, "pairs_held": 0,
+                        "experts_hit": 0, "max_pairs": 0}
         self.decode_dispatches = 0     # verify or plain decode calls
         self.decode_tokens = 0         # tokens those dispatches emitted
         self.draft_dispatches = 0
@@ -518,6 +662,65 @@ class Engine:
         if self.spec is not None:
             ws += [self._verify, self.draft._prefill, self.draft._decode]
         return ws
+
+    # --------------------------------------------------------------- weights
+    def _set_weights(self, params: dict, fused=None) -> None:
+        layers = "blocks" if self.desc.plain else "runs"
+        self._head = {k: v for k, v in params.items() if k != layers}
+        if not self.desc.plain:
+            self.fused = tuple(params["runs"])
+        else:
+            self.fused = (fused if fused is not None
+                          else generate._fuse_blocks(params["blocks"]))
+
+    @property
+    def weights(self) -> tuple:
+        """What the engine holds on the device, one copy of each weight."""
+        return self._head, self.fused
+
+    @property
+    def params(self) -> dict:
+        """The tree the engine was given (or last swapped to), rebuilt from
+        what it holds: a described model's as it is, a ``LlamaConfig``
+        model's with the fused q/k/v and gate/up split again (copies, made
+        on each call: for tests and tools, not for a hot path)."""
+        if not self.desc.plain:
+            return {**self._head, "runs": self.fused}
+        f = self.fused
+        wq, wk, wv = jnp.split(f["w_qkv"], 3, axis=-1)
+        w_gate, w_up = jnp.split(f["w_gu"], 2, axis=-1)
+        return {**self._head, "blocks": {
+            "attn_norm": f["attn_norm"], "wq": wq, "wk": wk, "wv": wv,
+            "wo": f["wo"], "mlp_norm": f["mlp_norm"], "w_gate": w_gate,
+            "w_up": w_up, "w_down": f["w_down"]}}
+
+    def _note_routing(self, span, stats=None, tokens: int = 0) -> None:
+        """At a wait for the device: write this step's routing counters,
+        and those of the prefill chunks dispatched since the last wait
+        (``chunk_*``), on ``span`` and add them to ``self.routing``."""
+        if not self._pairs_a_token:
+            return
+        found = {}
+        for prefix, batch in (("", [] if stats is None
+                               else [(stats, tokens)]),
+                              ("chunk_", self._chunk_stats)):
+            if not batch:
+                continue
+            st = np.stack([np.asarray(s) for s, _ in batch])   # [n, L, 3]
+            got = {"pairs_routed": self._pairs_a_token
+                   * sum(n for _, n in batch),
+                   "pairs_held": int(st[..., 0].sum()),
+                   "experts_hit": int(st[..., 1].sum()),
+                   "max_pairs": int(st[..., 2].max())}
+            for k, v in got.items():
+                found[prefix + k] = v
+                self.routing[k] = (max(self.routing[k], v)
+                                   if k == "max_pairs"
+                                   else self.routing[k] + v)
+            if prefix:
+                found["chunks"] = len(batch)
+        self._chunk_stats = []
+        span.set_metadata(**found)
 
     # ------------------------------------------------------------- admission
     def required_blocks(self, prompt_len: int, max_new: int) -> int:
@@ -656,11 +859,10 @@ class Engine:
         ``fused`` (the ``generate._fuse_blocks`` view of ``params``) can
         be passed precomputed so an N-engine fleet fuses once per publish,
         not once per engine."""
-        check_swappable(self.params, params)
-        self.params = _match_placement(params, self.params)
-        self.fused = (_match_placement(fused, self.fused)
-                      if fused is not None
-                      else generate._fuse_blocks(self.params["blocks"]))
+        check_swappable(self.boot, params)
+        self._set_weights(_match_placement(params, self.boot),
+                          None if fused is None
+                          else _match_placement(fused, self.fused))
 
     def step(self) -> List[TokenEvent]:
         """One token boundary: one prefill chunk (if a slot is mid-prefill),
@@ -713,11 +915,16 @@ class Engine:
             scalars = (jnp.int32(off), jnp.int32(n_valid),
                        jnp.int32(write_from))
             temp = jnp.float32(self.temps[s])
+        routed = ({"pairs_routed": n_valid * self._pairs_a_token}
+                  if self._pairs_a_token else {})
         with self.spans("engine.prefill.dispatch", slot=s, seq=slot.seq,
-                        off=off, n_valid=n_valid, final=int(is_final)):
-            self.pool, tok, new_key = self._prefill(
-                self.pool, self.params, self.fused,
+                        off=off, n_valid=n_valid, final=int(is_final),
+                        **routed):
+            self.pool, tok, new_key, *stats = self._prefill(
+                self.pool, self._head, self.fused,
                 table_row, chunk_j, *scalars, self.keys[s], temp)
+            if stats:
+                self._chunk_stats.append((stats[0], n_valid))
             if self.draft is not None:
                 # Mirror the chunk into the draft pool (same table row,
                 # same positions, the draft's weights) so proposals can
@@ -742,8 +949,9 @@ class Engine:
             # key are discarded so the slot's RNG stream stays exactly
             # generate's (one split for the whole prefill).
             return []
-        with self.spans("engine.prefill.fetch"):
+        with self.spans("engine.prefill.fetch") as fetch:
             first = int(tok)            # the host waits for the device
+            self._note_routing(fetch)
         slot.phase = "decode"
         slot.produced = 1
         self.pos[s] = len(slot.prompt)
@@ -782,7 +990,9 @@ class Engine:
 
     def _dispatch_counters(self, active: np.ndarray, tables) -> dict:
         """The counters of ``engine.decode.dispatch`` (class docstring)."""
-        return {"dispatch": self.decode_dispatches,
+        routed = ({"pairs_routed": int(active.sum()) * self._pairs_a_token}
+                  if self._pairs_a_token else {})
+        return {**routed, "dispatch": self.decode_dispatches,
                 "active": int(active.sum()),
                 "live_positions": int((self.pos[active] + 1).sum()),
                 "gathered_positions": (self.num_slots * int(tables.shape[1])
@@ -798,8 +1008,8 @@ class Engine:
                     jnp.array(self.temps), jnp.array(active))
         with self.spans("engine.decode.dispatch",
                         **self._dispatch_counters(active, tables)):
-            self.pool, toks, new_keys = self._decode(
-                self.pool, self.params, self.fused, *args)
+            self.pool, toks, new_keys, *stats = self._decode(
+                self.pool, self._head, self.fused, *args)
         with self.spans("engine.decode.fetch"):
             toks = np.asarray(toks)     # the host waits for the device
         self.keys = new_keys
@@ -819,6 +1029,8 @@ class Engine:
             self.decode_dispatches += 1
             self.decode_tokens += len(events)
             book.set_metadata(emitted=len(events))
+            self._note_routing(book, stats[0] if stats else None,
+                               int(active.sum()))
         return events
 
     def _advance_spec_decode(self) -> List[TokenEvent]:
@@ -854,7 +1066,7 @@ class Engine:
             window = jnp.concatenate([jnp.array(self.last_tok)[:, None],
                                       drafts], axis=1)
             self.pool, out, accepted, new_keys = self._verify(
-                self.pool, self.params, self.fused, tables, window,
+                self.pool, self._head, self.fused, tables, window,
                 draft_probs, pos, live_j, self.keys, temps, active_j)
         with self.spans("engine.decode.fetch"):
             out = np.asarray(out)

@@ -171,6 +171,11 @@ class ServingFleet:
                  clock: Callable[[], float] = time.monotonic):
         if num_engines < 1:
             raise ValueError(f"num_engines={num_engines}")
+        if not isinstance(cfg, LlamaConfig):
+            raise NotImplementedError(
+                "the fleet routes over engines of LlamaConfig models only: "
+                "its publish path fuses and swaps that tree "
+                f"({type(cfg).__name__} is served by one Engine; ROADMAP.md)")
         self.cfg = cfg
         self.paged = paged
         self.clock = clock
@@ -281,12 +286,12 @@ class ServingFleet:
                 f"({len(self._swap['remaining'])} engines to go)")
         from ..models import generate
         from .engine import _match_placement, check_swappable
-        check_swappable(self.engines[0].params, params)
+        check_swappable(self.engines[0].boot, params)
         # Normalize placement ONCE against the fleet's boot params (every
         # engine was built from the same tree, so one reference serves
         # all): each engine's swap then re-validates but never re-copies,
         # and the fused view is computed from the already-normalized tree.
-        params = _match_placement(params, self.engines[0].params)
+        params = _match_placement(params, self.engines[0].boot)
         self._swap = {"version": version, "params": params,
                       "fused": generate._fuse_blocks(params["blocks"]),
                       "remaining": deque(range(len(self.engines)))}

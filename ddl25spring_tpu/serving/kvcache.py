@@ -44,7 +44,7 @@ from typing import List, Optional
 
 import jax.numpy as jnp
 
-from ..config import LlamaConfig
+from ..config import describe
 
 # Block index 0 is never allocated: it absorbs the writes of inactive
 # slots / padded prefill tails so every compiled step can write
@@ -82,31 +82,53 @@ def blocks_for(n_tokens: int, block_len: int) -> int:
     return -(-max(0, n_tokens) // block_len)
 
 
-def init_pool(cfg: LlamaConfig, paged: PagedKVConfig) -> dict:
-    """Zeroed block pool: {"k","v"} each [L, num_blocks, block_len, H, Dh].
-    Layer-major like ``init_cache``, but the engine does not scan the
-    leading axis: the whole stacked pool is its layer scan's carry, and each
-    layer scatters into and gathers from it at (layer, block, offset)."""
+LANES = 128
+
+
+def row_stride(desc) -> int:
+    """Values a latent pool keeps for one position in one layer: the row
+    (``LatentAttention.row_dim``) rounded up to whole vectors of ``LANES``,
+    the rest zero. The chip tiles an array's last dimension in 128 lanes, so
+    a gatherable row of 576 occupies 640 whatever is declared; declared as
+    576 the runtime lays the pool out with the block index last instead, and
+    each program then copies the whole pool in and out (PERF.md, PR 29)."""
+    return -(-desc.attention.row_dim // LANES) * LANES
+
+
+def init_pool(cfg, paged: PagedKVConfig) -> dict:
+    """Zeroed block pool, sized from the model's description
+    (``config.describe``). K and V per head: {"k","v"} each [L, num_blocks,
+    block_len, H, Dh]. Latent attention: {"c"} [L, num_blocks, block_len,
+    row_dim], ONE row a position a layer (``models/latent.py::latent_row``)
+    and nothing per head, in ``row_stride`` lanes. Layer-major like ``init_cache``, but the engine
+    does not scan the leading axis: the whole stacked pool is its layer
+    scan's carry, and each layer scatters into and gathers from it at
+    (layer, block, offset)."""
     dt = jnp.dtype(paged.kv_dtype or cfg.dtype)
-    shape = (cfg.n_layers, paged.num_blocks, paged.block_len,
-             cfg.num_heads, cfg.head_dim)
+    desc = describe(cfg)
+    lead = (desc.n_layers, paged.num_blocks, paged.block_len)
+    if desc.attention is not None:
+        return {"c": jnp.zeros(lead + (row_stride(desc),), dt)}
+    shape = lead + (cfg.num_heads, cfg.head_dim)
     return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
 
 
-def kv_bytes_per_token(cfg: LlamaConfig,
-                       kv_dtype: Optional[str] = None) -> int:
-    """K+V bytes one cache position occupies across all layers."""
+def kv_bytes_per_token(cfg, kv_dtype: Optional[str] = None) -> int:
+    """Bytes one cache position occupies across all layers: K and V of
+    every head, or one latent row a layer as the pool stores it."""
     dt = jnp.dtype(kv_dtype or cfg.dtype)
-    return 2 * cfg.n_layers * cfg.num_heads * cfg.head_dim * dt.itemsize
+    desc = describe(cfg)
+    row = desc.cache_row if desc.attention is None else row_stride(desc)
+    return desc.n_layers * row * dt.itemsize
 
 
-def pool_bytes(cfg: LlamaConfig, paged: PagedKVConfig) -> int:
+def pool_bytes(cfg, paged: PagedKVConfig) -> int:
     """Total device bytes of the block pool (the serving KV footprint)."""
     return (paged.num_blocks * paged.block_len
             * kv_bytes_per_token(cfg, paged.kv_dtype))
 
 
-def naive_cache_bytes(cfg: LlamaConfig, n_streams: int, max_len: int,
+def naive_cache_bytes(cfg, n_streams: int, max_len: int,
                       kv_dtype: Optional[str] = None) -> int:
     """What ``generate`` would allocate for ``n_streams`` concurrent
     requests: one whole ``max_len`` cache each. The smoke asserts

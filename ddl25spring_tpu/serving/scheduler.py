@@ -228,7 +228,7 @@ class Scheduler:
             from ..telemetry.memory import MemoryMeter, tree_state_bytes
             self.memory_meter = MemoryMeter(events, source="serve")
             self.memory_meter.note(
-                params_bytes=tree_state_bytes(engine.params))
+                params_bytes=tree_state_bytes(engine.weights))
             try:
                 from .kvcache import kv_bytes_per_token
                 self._bytes_per_block = (

@@ -273,7 +273,7 @@ def make_verify_step(cfg: LlamaConfig, paged: PagedKVConfig,
         own = jnp.take_along_axis(tables, blk_idx, axis=1)     # [S, k+1]
         wblk = jnp.where(writable, own, TRASH_BLOCK)
         woff = positions % bl
-        h, pool = _forward_paged(params, fused, window, pool, tables,
+        h, pool, _ = _forward_paged(params, fused, window, pool, tables,
                                  positions, wblk, woff, cfg)
         logits = llama.head(params, h, cfg)                    # [S, k+1, V]
 

@@ -261,7 +261,9 @@ def preflight(model_cfg, train_cfg=None, *, mesh=None, n_data=None,
     - ``window_bytes`` — the ``[K, B, T]`` int32 dispatch window's
       per-device shard (K = steps_per_dispatch, B = per-replica batch);
     - ``kv_pool_bytes`` — the paged serving pool (kvcache.pool_bytes)
-      when ``paged`` is given (``serve_cfg`` defaults to ``model_cfg``).
+      when ``paged`` is given (``serve_cfg`` defaults to ``model_cfg``);
+      sized from the model's description, so a latent-attention model's
+      is one row a position a layer (``config.ModelDescription``).
 
     ``device_bytes`` totals the components — the number to hold against
     an accelerator's HBM (or slo_monitor's ``--device-bytes`` budget)
@@ -278,8 +280,17 @@ def preflight(model_cfg, train_cfg=None, *, mesh=None, n_data=None,
     except Exception:
         return None
     try:
-        abstract = jax.eval_shape(
-            lambda: llama.init_llama(jax.random.key(0), model_cfg))
+        from ..config import describe
+        desc = describe(model_cfg)
+        if desc.plain:
+            abstract = jax.eval_shape(
+                lambda: llama.init_llama(jax.random.key(0), model_cfg))
+        else:
+            # a described model (latent attention, expert layers): its own
+            # tree, and through ``pool_bytes`` below its own pool
+            from ..models import latent
+            abstract = jax.eval_shape(
+                lambda: latent.init_params(jax.random.key(0), desc))
         params_bytes = int(tree_bytes(abstract))
         count = sum(int(_math.prod(leaf.shape))
                     for leaf in jax.tree.leaves(abstract))

@@ -1129,6 +1129,12 @@ def train_llm_dp(model_cfg: Optional[LlamaConfig] = None,
     serving fleet that hot-swaps them live. Guarded: a broken hook is
     logged and skipped, never fatal.
     """
+    if model_cfg is not None and not isinstance(model_cfg, LlamaConfig):
+        raise NotImplementedError(
+            "the trainer takes LlamaConfig models only: a loss, a backward "
+            "pass and expert-parallel exchange for a described model "
+            f"({type(model_cfg).__name__}: latent attention, routed experts) "
+            "are not built (ROADMAP.md)")
     tok = tokenizer or load_tokenizer()
     model_cfg = (model_cfg or LlamaConfig()).replace(vocab_size=tok.vocab_size)
     train_cfg = train_cfg or TrainConfig()

@@ -202,3 +202,56 @@ def test_zero1_step_on_four_chips_has_its_collectives(topo, monkeypatch):
     # The gradient's sync and the parameters' (which may carry the loss).
     assert len(over_all_four) >= 2, over_all_four
     assert "tpu_custom_call" in text
+
+
+def test_latent_expert_serving_programs_at_published_widths(one_chip):
+    """The engine's two programs for a described model (latent attention,
+    routed experts) at A.X-K1's published widths, two layers (one dense,
+    one of experts), 4 slots of 1024 positions. In the compiled
+    ``decode_step`` no array holds a key or a value per head (positions x
+    64 heads x 128, 192 or 256): the step attends over the latent rows as
+    they lie; ``prefill_chunk`` expands them. Neither program copies the
+    whole pool (declared 576 wide instead of ``row_stride``'s 640, both
+    did, in and out), and the softmax's maximum is no row-wide
+    ``reduce-window``."""
+    import json
+
+    from ddl25spring_tpu.config import ModelDescription
+    from ddl25spring_tpu.models import latent
+    from ddl25spring_tpu.serving import engine as eng
+    from ddl25spring_tpu.serving.kvcache import PagedKVConfig, init_pool
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmarks", "configs", "a.x-k1.json")) as f:
+        cfg = dict(json.load(f), num_hidden_layers=2)
+    desc = ModelDescription.from_published(
+        cfg, ctx_size=1024, dtype="bfloat16", param_dtype="bfloat16")
+    assert desc.runs() == (("dense", 0, 1), ("experts", 1, 1))
+    assert (desc.attention.row_dim, desc.experts.n_experts,
+            desc.experts.held_count) == (576, 192, 12)
+    paged = PagedKVConfig(num_blocks=257, block_len=16, max_blocks_per_seq=64,
+                          kv_dtype="bfloat16")
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,  # noqa: E731
+                                                 sharding=one_chip)
+    params = _abstract_state(
+        lambda: latent.init_params(jax.random.key(0), desc), one_chip)
+    head = {k: v for k, v in params.items() if k != "runs"}
+    pool = _abstract_state(lambda: init_pool(desc, paged), one_chip)
+    assert pool["c"].shape == (2, 257, 16, 640)
+    s, mb, tc = 4, 64, 512
+    i32, f32, u32 = jnp.int32, jnp.float32, jnp.uint32
+    decode = eng.make_decode_step(desc, paged, s, None, None).lower(
+        pool, head, params["runs"], sds((s, mb), i32), sds((s,), i32),
+        sds((s,), i32), sds((s, 2), u32), sds((s,), f32),
+        sds((s,), jnp.bool_)).compile().as_text()
+    prefill = eng.make_prefill_chunk(desc, paged, tc, None, None).lower(
+        pool, head, params["runs"], sds((mb,), i32), sds((tc,), i32),
+        sds((), i32), sds((), i32), sds((), i32), sds((2,), u32),
+        sds((), f32)).compile().as_text()
+    per_head = re.compile(r"\[(?:\d+,)*1024,64,(?:128|192|256)\]")
+    assert not per_head.search(decode)
+    assert per_head.search(prefill)
+    for text in (decode, prefill):
+        assert not re.search(r"= bf16\[2,257,16,640\]\S* copy\(", text)
+        assert "reduce-window" not in text
+        assert "ragged-dot" in text
