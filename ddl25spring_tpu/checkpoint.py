@@ -196,7 +196,12 @@ class Checkpointer:
         resume: the on-disk entry is then a stale (possibly the corrupt)
         remnant of the pre-fallback lineage, and a blind save would be an
         orbax StepAlreadyExistsError. Default False so double-save bugs
-        still fail loudly."""
+        still fail loudly. An overwrite first waits for saves in flight:
+        the entry it replaces may be one of them (a periodic save at a
+        chunk edge, then a re-mesh persisting the new layout under the
+        same index), and deleting under its commit races the rename."""
+        if overwrite:
+            self._mgr.wait_until_finished()
         if step in self.all_steps():
             if not overwrite:
                 # Fail fast and outside the retry loop: a double-save is a

@@ -58,36 +58,37 @@ class LlamaConfig:
     rope_theta: float = 10000.0
     dtype: str = "float32"             # computation dtype ("bfloat16" on TPU)
     param_dtype: str = "float32"
-    # Attention backend: "xla" (fused-softmax dot_generals), "pallas" (the
-    # flash kernel), or "auto" (pallas iff running on TPU and the sequence is
-    # at least ``flash_min_seq``). The crossover is measured, not guessed:
-    # with the dh-major wide-block kernel the flash path wins at every swept
-    # length on v5e — fwd+bwd 4.65 vs 4.77 ms at T=256 (and 25x at T=8192),
-    # +7% end-to-end on the train step (experiments/results/attn_bench.csv,
-    # BENCH_r04) — so "auto" takes it from the canonical T=256 up. Below 256
-    # it is unmeasured and auto stays on XLA.
+    # Attention inner: "xla" (batched dot_generals over a materialized
+    # score tensor), "pallas" (the flash kernel, ops/flash_attention.py), or
+    # "auto": the flash kernel iff the backend is a TPU and the sequence is
+    # at least ``flash_min_seq`` (models/llama.py::attention_path decides
+    # where the step is traced). The training cell runs "auto" at T=4096,
+    # so the kernel; the ledger reads it at 25% of its compute roofline at
+    # head size 128 (PERF.md section 5, ``flash_attn_roofline.train``).
+    # Where the two inners cross is not measured at published widths
+    # (ROADMAP S5).
     attention_impl: str = "auto"
     flash_min_seq: int = 256
     # Stream flash-kernel operands in the dense [BH, Dh, T] layout instead of
-    # [BH, T, Dh]. At head dims below 128 lanes (this model's 48) the
-    # row-major layout pads every q/k/v/o and gradient transfer to 128 lanes
-    # — 2.67x the useful HBM bytes at Dh=48 — while dh-major is exactly
-    # dense. Same math and MXU shapes (ops/flash_attention.py); on by
-    # default since the on-chip measurement (attn_bench.csv) says it wins
-    # at every swept length when combined with ``flash_block`` wide blocks.
+    # [BH, T, Dh]. At head sizes below 128 lanes the row-major layout pads
+    # every q/k/v/o and gradient transfer to 128 lanes (2.67x the bytes at
+    # Dh=48); dh-major is dense at any head size. Same math and MXU shapes
+    # (ops/flash_attention.py). The training cell runs it at Dh=128, where
+    # row-major pads nothing: the two are not compared at published widths
+    # (ROADMAP S5).
     flash_dh_major: bool = True
-    # Pallas block size cap (block_q = block_k = min(T, flash_block)). The
-    # kernel default 128 keeps VMEM small for long sequences; at T ≤ 512 a
-    # whole-sequence block ("wide": one grid step per (b, h), no
-    # online-softmax recurrence) is measured fastest on v5e at every swept
-    # length (experiments/results/attn_bench.csv) — 512 is therefore the
-    # default cap.
+    # Pallas block size cap (block_q = block_k = min(T, flash_block)). At
+    # T <= flash_block one block holds the whole sequence (one grid step per
+    # (b, h), no online-softmax recurrence); the training cell runs T=4096
+    # in blocks of 512. The kernel's own default of 128 keeps VMEM smaller.
+    # Which block size is fastest is not measured at published widths
+    # (ROADMAP S5).
     flash_block: int = 512
     # Dtype of the materialized [B·H, T, T] attention score tensor. The
     # default fp32 is what the PP/SP equivalence tests are calibrated to;
-    # "bfloat16" halves the attention leg's dominant HBM tensor (softmax
-    # max/denominator stay fp32) at ~1e-2 logit drift — an opt-in throughput
-    # knob, measured ~9% on standalone attention fwd+bwd (ROOFLINE.md).
+    # "bfloat16" halves that tensor's bytes (softmax max/denominator stay
+    # fp32) at ~1e-2 logit drift; no cell sets it, and what it buys is not
+    # measured at published widths (ROADMAP S5).
     # Applies to the XLA attention path only: the pallas flash kernel never
     # materializes the score tensor in the first place (fp32 accumulators,
     # tile-local scores), and SP's ring attention owns its own fp32

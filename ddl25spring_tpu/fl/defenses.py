@@ -198,13 +198,17 @@ def selection_defense(rule: Callable[..., jnp.ndarray], **kw) -> Callable:
     ``hook.flat_hook``: consumers that already hold the flat stack (the
     fleet engine streams per-client deltas off-device as flat rows) apply
     it directly instead of round-tripping through the stacked pytree —
-    same ops, so both entry points agree bitwise."""
+    same ops, so both entry points agree bitwise. The survivors are summed
+    by ``tree_weighted_fold``, whose order is the index order wherever the
+    hook is traced: XLA associates (and contracts) ``(x * w).sum(0)`` one
+    way inside the server's jitted round and another when the fleet engine
+    calls the hook op by op (a few units in the last place on the CPU)."""
 
     def flat_hook(flat: jnp.ndarray, weights: jnp.ndarray) -> jnp.ndarray:
         idx = jnp.atleast_1d(rule(flat, **kw))
         w = weights[idx]
         w = w / jnp.maximum(w.sum(), 1e-12)
-        return (flat[idx] * w[:, None]).sum(axis=0)
+        return pt.tree_weighted_fold(flat[idx], w)
 
     def hook(deltas: PyTree, weights: jnp.ndarray) -> PyTree:
         flat, unflatten = stack_flat(deltas)

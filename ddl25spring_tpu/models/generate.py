@@ -37,9 +37,9 @@ def init_cache(cfg: LlamaConfig, batch: int, max_len: int,
     """Zeroed KV cache: {"k","v"} each [L, B, max_len, H, Dh]. ``max_len``
     bounds prompt + generated tokens. ``kv_dtype`` overrides the storage
     dtype (default: the compute dtype): serving decode re-reads the whole
-    cache every step, so bf16 storage halves the per-step KV traffic — the
-    dominant HBM stream once the batch amortizes the weights (see
-    experiments/ROOFLINE.md, decode section). K is stored post-RoPE and
+    cache every step, so bf16 storage halves the per-step KV bytes (the
+    serving cells run bf16 weights and cache; PERF.md section 5 has the
+    decode step's parts). K is stored post-RoPE and
     attention runs fp32 softmax either way; the only precision change is
     the rounding of cached K/V."""
     dt = jnp.dtype(kv_dtype or cfg.dtype)

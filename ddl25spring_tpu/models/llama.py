@@ -147,14 +147,14 @@ def _xla_attention(q, k, v, *, causal: bool, q_offset: int = 0,
 
     Heads are folded into the batch dimension and the two O(T²) contractions
     are explicit batched dot_generals in [B·H, T, Dh] layout — identical math
-    to the einsum formulation but measurably faster on TPU at small head_dim
-    (the einsum path's backward introduces extra layout transposes; at the
-    bench config this halves attention fwd+bwd time, experiments/attn_bench).
+    to the einsum formulation, whose backward introduces extra layout
+    transposes. The training cell runs the flash kernel, not this path:
+    it is not measured at published widths (ROADMAP S5).
 
     ``softmax_dtype="bfloat16"`` (opt-in via LlamaConfig) materializes the
-    [B·H, T, T] score tensor in bf16 — halving the dominant HBM tensor of
-    the attention leg (measured ~9% on standalone attention fwd+bwd at the
-    bench config) — while the softmax max/sum still accumulate in fp32.
+    [B·H, T, T] score tensor in bf16 — halving the largest tensor of the
+    attention leg (what that buys is not measured at published widths)
+    — while the softmax max/sum still accumulate in fp32.
     Off by default: the ~1e-2 drift is outside the PP/SP equivalence-test
     tolerances.
     """

@@ -15,7 +15,7 @@ Here the whole update rule is one jnp expression per leaf —
 up to float re-association (asserted ≤1e-6 in tests/test_core.py).
 
 Drop-in: ``fused_adam(8e-4)`` anywhere an ``optax.GradientTransformation``
-is accepted (dp/pp/ep steps, train.llm, bench.py).
+is accepted (dp/pp/ep steps, train.llm).
 
 ZeRO-1 note (parallel/dp.py): Adam is elementwise — the update at
 coordinate i depends only on (g, m, v) at i — so applying it to a 1/N
@@ -134,3 +134,25 @@ def fused_adam(learning_rate: float, b1: float = 0.9, b2: float = 0.999,
         return updates, FusedAdamState(count, mu, nu)
 
     return optax.GradientTransformation(init_fn, update_fn)
+
+
+def make_optimizer(opt_name: str, lr: float = 8e-4):
+    """``TrainConfig.optimizer`` other than "adam" -> optimizer instance.
+    "fused" = ``fused_adam`` above (same update as optax.adam, asserted
+    ≤1e-6 in tests/test_core.py); "pallas" = the fully-fused Pallas apply
+    (ops/pallas_adam.py — moments + param write in one kernel pass per
+    leaf); "master" = fp32-master-weight Adam for bf16 params
+    (ops/mixed_precision.py — pair with ``param_dtype="bfloat16"``). Which
+    of them is fastest is not measured at published widths (PERF.md
+    section 7)."""
+    if opt_name == "pallas":
+        from .pallas_adam import FusedApplyAdam
+        return FusedApplyAdam(lr)
+    if opt_name == "master":
+        from .mixed_precision import master_weight_adam
+        return master_weight_adam(lr)
+    if opt_name != "fused":
+        raise ValueError(
+            f"unknown optimizer {opt_name!r}: expected one of "
+            "'fused', 'pallas', 'master'")
+    return fused_adam(lr)

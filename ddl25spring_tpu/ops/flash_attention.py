@@ -716,11 +716,11 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     zeroed in the backward).
 
     ``dh_major=True`` streams operands in the [BH, Dh, T] layout, which is
-    exactly dense on TPU for head dims like this model's 48 (a [_, T, 48]
-    operand is lane-padded to 128, costing 2.67x HBM bytes on every q/k/v/o
-    and gradient transfer). Same math, same MXU shapes — a pure
-    memory-bandwidth variant; see experiments/attn_bench.py for the
-    measured comparison.
+    exactly dense on TPU at any head size (a [_, T, 48] operand is
+    lane-padded to 128, 2.67x the HBM bytes on every q/k/v/o and gradient
+    transfer; at the cells' head size of 128 row-major pads nothing). Same
+    math, same MXU shapes; the two layouts are not compared at published
+    widths (ROADMAP S5).
     """
     if interpret is None:
         interpret = default_interpret()

@@ -23,7 +23,7 @@ drops into every step factory here (dp/pp/zero1/compressed):
   within 2×, i.e. for Adam-sized steps; tests/test_mixed_precision.py).
 
 The decode path composes: train in bf16+master, serve the bf16 params
-directly (bench.py's decode sidebar measures the same layout).
+directly (the serving cells' weights are bf16).
 """
 
 from __future__ import annotations

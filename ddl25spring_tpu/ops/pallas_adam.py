@@ -4,10 +4,11 @@ Why another Adam: ``ops.adam.fused_adam`` collapses optax's multi-stage
 update into one jnp expression per leaf, which XLA fuses into a single
 elementwise kernel — but the *apply* (``p + u``) still lives outside the
 optimizer contract, and XLA's fusion decisions over a 13-leaf tree are its
-own. The optimizer leg is pure HBM bandwidth (24 M params × fp32 × {p, m, v,
-g} read + {p, m, v} write ≈ 0.8 ms at v5e's 819 GB/s); the measured XLA leg
-runs ~3.5× that floor (experiments/ROOFLINE.md). This module commits the
-whole update rule
+own. The optimizer leg is pure HBM bandwidth: seven parameter-sized fp32
+streams ({p, m, v, g} read, {p, m, v} written) a step. The training cell
+runs optax Adam, 22.4 ms of its step (PERF.md section 5); what this kernel
+buys is not measured at published widths (PERF.md section 7). This module
+commits the whole update rule
 
     m ← β1·m + (1−β1)·g
     v ← β2·v + (1−β2)·g²

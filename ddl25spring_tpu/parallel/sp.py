@@ -24,14 +24,11 @@ Design notes:
   positions; each shard passes its global window offsets.
 - Composes with data parallelism on a ``(data, seq)`` mesh: batch sharded
   over ``data``, sequence over ``seq``, grads psum over both.
-- The per-hop inner attention stays the XLA einsum + online-softmax, NOT the
-  Pallas flash kernel, deliberately: each hop sees a [T/n_seq, T/n_seq]
-  block, and at this model's head_dim=48 the flash kernel only beats XLA
-  from seq ≈4096 up (lane padding 48→128 wastes ~62% of each MXU pass —
-  measured, experiments/attn_bench.py). A ring large enough to make hops
-  flash-profitable (T/n_seq ≥ 4096) is exactly the regime where plain
-  single-device flash would already fit; the ring exists to shard memory,
-  and its chunks sit below the crossover.
+- The per-hop inner attention is the XLA einsum + online-softmax, not the
+  Pallas flash kernel: each hop sees a [T/n_seq, T/n_seq] block and owns
+  its own fp32 online-softmax carry across hops, which the kernel's
+  interface does not take. Whether a flash inner would pay at a hop's
+  length is not measured at published widths (ROADMAP S5, S8).
 """
 
 from __future__ import annotations

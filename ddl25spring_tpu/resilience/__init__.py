@@ -48,7 +48,7 @@ from .retry import backoff_schedule, retry_call, with_retry  # noqa: F401
 # (elastic defers its parallel/ imports into recover()).
 # Load it lazily (PEP 562) so jax-free supervisors — experiments/watchdog.py
 # pulling in backoff_schedule — don't pay jax's import time and memory.
-_GUARD_EXPORTS = ("StepGuard", "measure_overhead")
+_GUARD_EXPORTS = ("StepGuard",)
 __all__ = ["Autoscaler", "AutoscalePolicy", "ElasticController",
            "FaultEvent", "FaultPlan", "RemeshRecord", "ReplicaLossError",
            "ReplicaReturnSignal", "Resume", "ScaleDecision",

@@ -21,7 +21,7 @@ It can, at chosen steps/rounds:
   cluster scheduler handing capacity back at a dispatch boundary —
   resilience/elastic.py turns it into a scale-UP re-mesh).
 
-Plans parse from a compact spec string so bench.py / experiments can take
+Plans parse from a compact spec string so experiments and tests can take
 them straight off a CLI flag or config field::
 
     "nan_grad@10"                 NaN gradient at step 10 (all leaves)

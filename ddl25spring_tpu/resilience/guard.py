@@ -27,9 +27,8 @@ outputs untouched, so a guarded run is bit-identical to an unguarded one
 copy of the state per step — required because every step factory in
 parallel/ donates its input buffers (``donate_argnums=(0,)``), so the
 pre-step state would otherwise be unreadable for skip/rollback — plus one
-host sync for the finiteness verdict. Both are measured, not guessed:
-``measure_overhead`` reports the fault-free guard tax, and bench.py carries
-it in the headline JSON.
+host sync for the finiteness verdict. What the two cost a step is not
+measured at published widths: no cell runs a guarded step (ROADMAP D4).
 
 For a sync-free in-step alternative (skip only, no EMA/rollback), see
 ``parallel/dp.py``'s ``guard_nonfinite`` — the post-allreduce finiteness
@@ -176,38 +175,3 @@ class StepGuard:
             self._consecutive_bad = 0
             return restored, out
         return old, out
-
-
-def measure_overhead(make_state_and_step, batch, *, steps: int = 20,
-                     warmup: int = 3) -> Tuple[float, ResilienceStats]:
-    """Fault-free guard tax: time ``steps`` raw steps vs ``steps`` guarded
-    steps of the same factory output and return
-    ``(100 · (t_guarded / t_raw − 1), guard_stats)`` — the stats being
-    all-zero is the evidence the measurement really was fault-free.
-
-    ``make_state_and_step()`` must return a fresh ``(state, step_fn)`` pair
-    per call (fresh, because the step donates its state and the two timings
-    must not share buffers). Used by bench.py so the headline JSON carries
-    the guard's measured cost rather than a claim.
-    """
-    import time
-
-    stats = ResilienceStats()
-
-    def run(guarded: bool) -> float:
-        state, step = make_state_and_step()
-        fn = StepGuard(step, stats=stats) if guarded else step
-        loss = None
-        for _ in range(warmup):
-            state, loss = fn(state, batch)
-        if loss is not None:
-            float(loss)
-        t0 = time.perf_counter()
-        for _ in range(steps):
-            state, loss = fn(state, batch)
-        float(loss)
-        return time.perf_counter() - t0
-
-    t_raw = run(False)
-    t_guarded = run(True)
-    return 100.0 * (t_guarded / max(t_raw, 1e-9) - 1.0), stats

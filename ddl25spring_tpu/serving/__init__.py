@@ -10,9 +10,8 @@ Six layers (ISSUE 6 + ISSUE 11 / ROADMAP item 1), bottom-up:
               when speculating) compiled ONCE over a fixed slot axis;
               chunked prefill interleaves with in-flight decode;
               token-boundary weight hot-swap seam (``swap_params``);
-              CoW prefix sharing (``prefix_share``) and bucketed gather
-              narrowing (``gather_buckets``); bitwise-parity with
-              ``models.generate`` pinned in tests.
+              CoW prefix sharing (``prefix_share``); bitwise-parity
+              with ``models.generate`` pinned in tests.
 - speculate — draft-propose / one-dispatch-verify speculative decoding
               (``SpecConfig``, ``DraftEngine``, ``make_verify_step``):
               greedy streams bitwise ``generate()``'s, stochastic via
@@ -25,8 +24,8 @@ Six layers (ISSUE 6 + ISSUE 11 / ROADMAP item 1), bottom-up:
 - frontend  — seeded Poisson load generator, now multi-tenant
               (``TrafficClass`` / ``multi_tenant_workload``: per-class
               rates, SLO targets, admission priorities) + ``run_serving``
-              driver and the latency aggregation behind bench.py's
-              serving row and ``experiments/obs_report.py``.
+              driver (the tests' and ``chip_smoke.py``'s) and the
+              latency aggregation ``experiments/obs_report.py`` renders.
 - fleet     — N engines behind an SLO-aware ``Router`` (least-loaded /
               predicted-TTFT over slo_monitor-shaped rolling windows)
               with live weight hot-swap rolled out one engine per token

@@ -28,8 +28,8 @@ of layers of one kind over a pool of one latent row a position a layer
 only then; checked against a float32 reference, tests/test_latent_experts.py).
 The engine keeps ONE copy of each weight, in the layout its programs read.
 
-Each is compiled exactly once per engine (static shapes — with gather
-narrowing, once per bucketed table width). The stacked pool is donated and
+Each is compiled exactly once per engine (static shapes: every dispatch
+passes the block table at full width). The stacked pool is donated and
 is the layer scan's carry, written and gathered by (layer, block, offset),
 so argument, loop state and result are one buffer and no program slices a
 layer's pool out of it or writes one back. All are built from the same
@@ -472,10 +472,7 @@ def make_decode_step(cfg: LlamaConfig, paged: PagedKVConfig,
     own blocks (inactive slots write to trash), and samples with its own
     key/temperature. Admission, retirement and raggedness are pure data —
     the program never recompiles. The table WIDTH is read from the
-    argument shape, not the pool config: with gather narrowing
-    (``Engine(gather_buckets=True)``) the host passes a bucketed slice of
-    the block table and each bucket width is its own (once-compiled)
-    specialization of this one program.
+    argument shape, not the pool config.
 
     ``return_probs=True`` is the DRAFT variant (serving/speculate.py):
     identical cache indexing, key discipline and sampling, but the program
@@ -601,8 +598,7 @@ class Engine:
                  top_k: Optional[int] = None, top_p: Optional[float] = None,
                  engine_id: Optional[int] = None,
                  speculate: Optional["SpecConfig"] = None,
-                 prefix_share: bool = False,
-                 gather_buckets: bool = False):
+                 prefix_share: bool = False):
         if num_slots < 1 or prefill_chunk < 1:
             raise ValueError(f"num_slots={num_slots}, "
                              f"prefill_chunk={prefill_chunk}")
@@ -663,26 +659,13 @@ class Engine:
         self.prefix_share = prefix_share
         self._prefix_blocks: Dict[tuple, int] = {}
         self._block_key: Dict[int, tuple] = {}
-        # Gather narrowing (opt-in): decode/verify gathers walk only a
-        # BUCKETED prefix of the block table — the fleet-wide max live
-        # block count this dispatch, rounded up to a power of two so the
-        # shape set is bounded (one compile per bucket, zero retraces
-        # after). Byte savings are accounted analytically per dispatch.
-        self.gather_buckets = gather_buckets
-        mb = paged.max_blocks_per_seq
-        self._buckets = sorted({min(1 << i, mb)
-                                for i in range(mb.bit_length() + 1)} | {mb})
-        n_shapes = len(self._buckets) if gather_buckets else 1
-        self.gather_bytes = 0          # gathered KV bytes, as narrowed
-        self.gather_bytes_saved = 0    # bytes the full-width walk would add
         # Compile/retrace observability (telemetry/introspect.py): the
         # engine's contract is a DOCUMENTED program set — two programs
         # (prefill_chunk + decode_step) without speculation, three
         # (+ verify_step; decode_step idles) plus the draft's two with it
         # — admission, retirement and raggedness are data, never shapes.
-        # Gather narrowing widens each decode/verify budget to one compile
-        # per bucket width. The watches enforce the budgets (growth past
-        # them is a flagged retrace) and emit ``compile`` events once the
+        # The watches enforce a budget of one compile each (growth past it
+        # is a flagged retrace) and emit ``compile`` events once the
         # scheduler binds its event stream (introspect.bind_events).
         from ..telemetry import introspect
         tag = "" if engine_id is None else f"[{engine_id}]"
@@ -691,7 +674,7 @@ class Engine:
             name=f"serving/prefill_chunk{tag}", max_caches=1)
         self._decode = introspect.watch(
             make_decode_step(cfg, paged, num_slots, top_k, top_p),
-            name=f"serving/decode_step{tag}", max_caches=n_shapes)
+            name=f"serving/decode_step{tag}", max_caches=1)
         # Speculative decoding (serving/speculate.py): the draft engine
         # (own pool over the SAME block tables, own two programs) and the
         # one-dispatch k+1-position verify program.
@@ -717,11 +700,11 @@ class Engine:
             self.draft = DraftEngine(
                 speculate, cfg, paged, num_slots,
                 prefill_chunk=prefill_chunk, top_k=top_k, top_p=top_p,
-                engine_id=engine_id, decode_shapes=n_shapes)
+                engine_id=engine_id)
             self._verify = introspect.watch(
                 make_verify_step(cfg, paged, num_slots, speculate.k,
                                  top_k, top_p),
-                name=f"serving/verify_step{tag}", max_caches=n_shapes)
+                name=f"serving/verify_step{tag}", max_caches=1)
         else:
             self.draft = None
             self._verify = None
@@ -1034,33 +1017,6 @@ class Engine:
             self._retire(s)
         return [TokenEvent(s, first, first=True, done=done)]
 
-    def _gathered_tables(self, active: np.ndarray, tq: int) -> np.ndarray:
-        """The block-table slice a decode/verify dispatch gathers through.
-        Full width by default; with ``gather_buckets``, narrowed to the
-        smallest bucket covering every active slot's LIVE blocks (reads
-        reach positions < pos + tq, all ≤ the slot's written-or-writing
-        frontier), with the avoided gather traffic counted analytically
-        — the decode table's KV read line in ROOFLINE.md is per live
-        position, and this is the knob that makes the gather live-length
-        instead of worst-case."""
-        bl, mb = self.paged.block_len, self.paged.max_blocks_per_seq
-        from .kvcache import kv_bytes_per_token
-        per_block = bl * kv_bytes_per_token(self.cfg, self.paged.kv_dtype)
-        if not self.gather_buckets:
-            self.gather_bytes += self.num_slots * mb * per_block
-            return self.tables
-        need = 1
-        for s in np.nonzero(active)[0]:
-            need = max(need, -(-(int(self.pos[s]) + tq) // bl))
-        # A verify window near the horizon can ask past the table (pos +
-        # k + 1 spills over a full-width reservation); the overflow rows
-        # are live-masked to trash in-program and the blk_idx clamp tops
-        # out at the table width, so the host need caps at mb.
-        cols = next(b for b in self._buckets if b >= min(need, mb))
-        self.gather_bytes += self.num_slots * cols * per_block
-        self.gather_bytes_saved += self.num_slots * (mb - cols) * per_block
-        return self.tables[:, :cols]
-
     def _dispatch_counters(self, active: np.ndarray, tables,
                            tq: int) -> dict:
         """The counters of ``engine.decode.dispatch`` (class docstring), for
@@ -1082,12 +1038,11 @@ class Engine:
         with self.spans("engine.decode.stage"):
             active = np.array([sl is not None and sl.phase == "decode"
                                for sl in self.slots])
-            tables = self._gathered_tables(active, 1)
-            args = (jnp.array(tables), jnp.array(self.last_tok),
+            args = (jnp.array(self.tables), jnp.array(self.last_tok),
                     jnp.array(self.pos), self.keys,
                     jnp.array(self.temps), jnp.array(active))
         with self.spans("engine.decode.dispatch",
-                        **self._dispatch_counters(active, tables, 1)):
+                        **self._dispatch_counters(active, self.tables, 1)):
             self.pool, toks, new_keys, *stats = self._decode(
                 self.pool, self._head, self.fused, *args)
         with self.spans("engine.decode.fetch"):
@@ -1131,14 +1086,14 @@ class Engine:
                                  np.int32)
             live = np.minimum(k + 1,
                               np.maximum(remaining, 1)).astype(np.int32)
-            narrowed = self._gathered_tables(active, k + 1)
-            tables = jnp.array(narrowed)
+            tables = jnp.array(self.tables)
             pos = jnp.array(self.pos)
             temps = jnp.array(self.temps)
             active_j = jnp.array(active)
             live_j = jnp.array(live)
         with self.spans("engine.decode.dispatch",
-                        **self._dispatch_counters(active, narrowed, k + 1)):
+                        **self._dispatch_counters(active, self.tables,
+                                                  k + 1)):
             drafts, draft_probs = self.draft.propose(
                 tables, jnp.array(self.last_tok), pos, temps, active_j,
                 live_j)

@@ -201,16 +201,14 @@ class ServingReport:
     # Compile/retrace accounting (telemetry/introspect.py CompileWatch on
     # the engine's program set): the contract is compiles == the
     # documented set (2 plain; 4 with speculation — prefill + verify +
-    # the draft's two, decode_step idling; gather narrowing adds one per
-    # extra bucket width actually hit) and retraces == 0 for ANY
+    # the draft's two, decode_step idling) and retraces == 0 for ANY
     # workload — raggedness is data, not shapes.
     compiles: int = 0
     retraces: int = 0
     # Speculative decoding accounting (serving/speculate.py): target
     # decode dispatches (verify dispatches when speculating), tokens they
     # emitted, and the draft's (cheap) dispatch count. tokens_per_dispatch
-    # = decode_tokens / decode_dispatches — the dispatch-bound hosts'
-    # headline (ROOFLINE.md "speculative decode" row); ≈1×avg-batch
+    # = decode_tokens / decode_dispatches, a count: ≈1×avg-batch
     # without speculation, ×(accepted+1) with it.
     decode_dispatches: int = 0
     decode_tokens: int = 0
@@ -219,18 +217,13 @@ class ServingReport:
     spec_proposed: int = 0
     spec_accepted: int = 0
     acceptance_rate: Optional[float] = None
-    # Gather-narrowing accounting (Engine(gather_buckets=True)): KV bytes
-    # the decode/verify gathers walked, and the bytes the full
-    # max_blocks_per_seq walk would have added on top.
-    gather_bytes: int = 0
-    gather_bytes_saved: int = 0
 
 
 def aggregate_latency(records: Dict[str, RequestRecord],
                       busy_span_s: Optional[float] = None) -> dict:
     """p50/p95/p99 queue wait + TTFT, per-request tok/s, and the sustained
-    throughput — the serving row's numbers, shared by bench.py,
-    serving_bench and the tests so no consumer re-derives them
+    throughput — the serving row's numbers, shared by serving_bench,
+    chip_smoke.py and the tests so no consumer re-derives them
     differently. ``busy_span_s`` (run_serving supplies it) is the
     engine's accumulated working time; without it the fallback span is
     first admission → last completion, which is only honest when the
@@ -279,20 +272,18 @@ def run_serving(params: dict, cfg: LlamaConfig, paged: PagedKVConfig,
                 top_p: Optional[float] = None,
                 events: Optional[EventLog] = None,
                 token_events: bool = True,
-                speculate=None, prefix_share: bool = False,
-                gather_buckets: bool = False) -> ServingReport:
+                speculate=None,
+                prefix_share: bool = False) -> ServingReport:
     """Replay ``workload`` (arrival offsets in seconds) through a fresh
     engine + scheduler; returns per-request records and the aggregate row.
     Every request is guaranteed retired on return — reservation-based
     admission cannot deadlock (scheduler.py), so the loop's only exit is
     completion. ``speculate`` (a ``SpecConfig``) turns on draft-propose /
     one-dispatch-verify decoding; ``prefix_share`` maps identical
-    full-block prompt prefixes copy-on-write; ``gather_buckets`` narrows
-    the decode gather to bucketed live-block counts."""
+    full-block prompt prefixes copy-on-write."""
     engine = Engine(params, cfg, paged, num_slots,
                     prefill_chunk=prefill_chunk, top_k=top_k, top_p=top_p,
-                    speculate=speculate, prefix_share=prefix_share,
-                    gather_buckets=gather_buckets)
+                    speculate=speculate, prefix_share=prefix_share)
     clock = _Clock()
     sched = Scheduler(engine, events=events, token_events=token_events,
                       clock=clock.now)
@@ -332,7 +323,5 @@ def run_serving(params: dict, cfg: LlamaConfig, paged: PagedKVConfig,
                              if engine.decode_dispatches else None),
         spec_proposed=spec_prop,
         spec_accepted=spec_acc,
-        acceptance_rate=(spec_acc / spec_prop if spec_prop else None),
-        gather_bytes=engine.gather_bytes,
-        gather_bytes_saved=engine.gather_bytes_saved)
+        acceptance_rate=(spec_acc / spec_prop if spec_prop else None))
     return report

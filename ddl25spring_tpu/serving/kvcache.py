@@ -13,8 +13,8 @@ static-shape/one-compile discipline):
   carries the whole pair and writes and gathers it in place by (layer,
   block, offset) — it never slices one layer's pool out or writes one back).
   ``kv_dtype`` reuses ``init_cache``'s storage-dtype option: bf16 blocks
-  halve the decode loop's dominant HBM stream (experiments/ROOFLINE.md,
-  decode section — the batch-32 KV-bound regime is the serving case).
+  halve the cache bytes a decode step reads (the serving cells run bf16;
+  PERF.md section 5 has the step's parts).
 - The host side is a free-list allocator handing out block *indices*; each
   live sequence owns a row of a ``[num_slots, max_blocks_per_seq]`` block
   table mapping its logical positions to pool blocks. Attention gathers a

@@ -1,9 +1,10 @@
 """Speculative decoding on the paged serving engine: draft-propose, verify.
 
-On dispatch-bound hosts each generated token costs one full engine
-dispatch — ~70 matVecs streaming every weight byte at batch 1
-(experiments/ROOFLINE.md, decode table) — so tokens-per-dispatch, not
-FLOPs, is the decode lever. Speculative decoding buys tokens per dispatch
+Each generated token costs one full engine dispatch, which streams every
+weight byte whatever the batch (PERF.md section 5: the dense part of a
+decode step runs at 90% of weights-once) — so tokens-per-dispatch, not
+FLOPs, is the decode lever. No cell runs speculation: what it buys is not
+measured at published widths. Speculative decoding buys tokens per dispatch
 (ROADMAP item 2b): a cheap DRAFT model proposes ``k`` tokens with ``k``
 single-token decode steps over its OWN paged pool, then the target model
 scores all ``k + 1`` window positions in ONE donated dispatch over the
@@ -112,8 +113,7 @@ class DraftEngine:
     def __init__(self, spec: SpecConfig, target_cfg: LlamaConfig,
                  paged: PagedKVConfig, num_slots: int, *,
                  prefill_chunk: int, top_k: Optional[int],
-                 top_p: Optional[float], engine_id: Optional[int] = None,
-                 decode_shapes: int = 1):
+                 top_p: Optional[float], engine_id: Optional[int] = None):
         from . import engine as _engine
         from ..telemetry import introspect
 
@@ -136,14 +136,11 @@ class DraftEngine:
         # The TARGET's decode factory in its return_probs variant — one
         # paged-cache body serves both models, so cache-indexing fixes
         # can never drift between them (the bitwise bar depends on the
-        # two pools agreeing op-for-op). ``decode_shapes`` is the parent's
-        # gather-narrowing bucket count: propose() runs over the SAME
-        # narrowed table slice as the verify dispatch, so the draft decode
-        # legitimately compiles once per bucket width too.
+        # two pools agreeing op-for-op).
         self._decode = introspect.watch(
             _engine.make_decode_step(self.cfg, paged, num_slots, top_k,
                                      top_p, return_probs=True),
-            name=f"serving/draft_decode{tag}", max_caches=decode_shapes)
+            name=f"serving/draft_decode{tag}", max_caches=1)
 
     def admit_key(self, s: int, temperature: float, key) -> None:
         """Seed slot ``s``'s draft proposal stream: an independent child of

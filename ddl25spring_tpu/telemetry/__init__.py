@@ -10,8 +10,7 @@ One observability subsystem the whole stack reports through:
   collectives in parallel/{dp,tp,sp,ep,pp,compress}.py — bytes per
   psum/all-gather per step, computed statically, zero in-jit overhead.
 - ``costs``: compiled-HLO cost analysis via lower().compile()
-  .cost_analysis(); cross-checks bench.py's
-  analytic FLOPs.
+  .cost_analysis(), recorded by the compile watches.
 - ``memory``: unified memory observability (schema v9) — guarded
   ``memory_analysis()`` program footprints, the jax-free ``MemoryMeter``
   live sampler (host RSS, state/mirror bytes, KV pool occupancy +
@@ -37,7 +36,7 @@ from __future__ import annotations
 import os
 from typing import Optional
 
-from .costs import flops_crosscheck, hlo_cost
+from .costs import hlo_cost
 from .events import (EventLog, SCHEMA_VERSION, default_run_id, read_events,
                      validate_event)
 from .heartbeat import Heartbeat, read_heartbeat
@@ -69,7 +68,7 @@ __all__ = [
     "SCHEMA_VERSION",
     "Span", "SpanContext", "Spans", "Telemetry", "Tracer",
     "allocator_census", "bind_events", "compiled_memory",
-    "default_run_id", "device_peaks", "device_trace", "flops_crosscheck",
+    "default_run_id", "device_peaks", "device_trace",
     "hlo_cost", "host_rss_bytes", "make_summarizer", "measure_comm",
     "preflight", "program_memory", "read_events",
     "read_heartbeat", "trace_trees", "tree_check", "validate_event", "watch",

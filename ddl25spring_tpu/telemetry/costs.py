@@ -1,10 +1,9 @@
 """Compiled-HLO cost accounting.
 
-`bench.py` computes MFU from a hand-derived analytic FLOP formula
-(``train_step_flops_per_token``). This module pulls the OTHER source of
-truth — XLA's own cost model for the compiled step, via
+XLA's own cost model for a compiled program, via
 ``jitted.lower(...).compile().cost_analysis()`` (a dict with ``"flops"`` and
-``"bytes accessed"``) — so the two can cross-check each other. A callable
+``"bytes accessed"``): what the compile watches record beside a program's
+memory (``introspect.CompileWatch``). A callable
 that is not jitted, a program that does not compile and a backend that
 reports no count (``-1``) give None rather than an exception: the callers
 are observers (CompileWatch, reports) that must not sink what they observe.
@@ -22,8 +21,8 @@ def hlo_cost(jitted_fn, *args, **kwargs) -> Optional[dict]:
     when any link of the lower→compile→cost_analysis chain is unavailable
     (module docstring). Arguments may be real pytrees or
     ``jax.ShapeDtypeStruct``s. NOTE: compiles the program if it isn't
-    already — call where a compile is acceptable (bench/report time), not
-    on a hot path.
+    already — call where a compile is acceptable (report time), not on a
+    hot path.
     """
     lower = getattr(jitted_fn, "lower", None)
     if lower is None:
@@ -57,26 +56,3 @@ def _normalize(analysis: Any) -> Optional[dict]:
     return {"flops": float(flops),
             "bytes_accessed": (float(bytes_accessed)
                                if bytes_accessed is not None else None)}
-
-
-def flops_crosscheck(analytic_flops: float, hlo: Optional[dict],
-                     tolerance: float = 0.10) -> dict:
-    """Compare the analytic FLOP count against the compiled program's.
-
-    Returns ``{"flops_source", "hlo_flops", "rel_err"}``:
-    - ``"hlo"`` when the compiled-program count is available and within
-      ``tolerance`` relative error of the analytic formula — the formula is
-      then cross-checked by the compiler;
-    - ``"analytic"`` when cost_analysis is unavailable or
-      the two diverge beyond tolerance (caller should warn: either the
-      formula or the lowering changed).
-
-    Both counts must cover the SAME program (same config, batch, seq).
-    """
-    if hlo is None or not analytic_flops:
-        return {"flops_source": "analytic", "hlo_flops": None,
-                "rel_err": None}
-    rel = abs(hlo["flops"] - analytic_flops) / analytic_flops
-    source = "hlo" if rel <= tolerance else "analytic"
-    return {"flops_source": source, "hlo_flops": hlo["flops"],
-            "rel_err": rel}

@@ -10,8 +10,8 @@ pieces sharing one schema-v9 ``memory`` event shape:
   that can't account a field reports it negative and the field is
   dropped; one that accounts nothing gives None.
   ``introspect.CompileWatch`` stamps these onto every ``compile``
-  event; the two benches that used to call ``memory_analysis()`` ad hoc
-  (sp_bench, pp_schedules) route through here.
+  event; ``experiments/pp_schedules.py`` reads its programs through here
+  too.
 - **Live accounting** — ``MemoryMeter``, a jax-free sampler emitting one
   ``memory`` event per cadence point (trainer chunk edges, scheduler
   ticks): host RSS (``host_rss_bytes``), training-state / elastic-mirror
@@ -66,9 +66,8 @@ _DEVICE_COMPONENTS = ("params_bytes", "opt_state_bytes", "residual_bytes",
 
 def compiled_memory(compiled) -> Optional[dict]:
     """Static footprint of an ALREADY-compiled program, or None when the
-    backend can't account it. Shared by the repo's three
-    ``memory_analysis()`` call sites (CompileWatch, sp_bench,
-    pp_schedules)."""
+    backend can't account it. Shared by the repo's two
+    ``memory_analysis()`` call sites (CompileWatch, pp_schedules)."""
     return _normalize_stats(compiled.memory_analysis())
 
 
@@ -313,7 +312,7 @@ def preflight(model_cfg, train_cfg=None, *, mesh=None, n_data=None,
             if name == "adam":
                 optimizer = optax.adam(lr)
             else:
-                from ..bench_utils import make_optimizer
+                from ..ops.adam import make_optimizer
                 optimizer = make_optimizer(name, lr)
         except Exception:
             return None

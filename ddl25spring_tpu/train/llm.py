@@ -108,10 +108,10 @@ def eval_llm(params, model_cfg: LlamaConfig, *, n_batches: int = 16,
 def _make_trainer_optimizer(train_cfg: TrainConfig):
     """TrainConfig.optimizer -> optimizer instance, shared by both trainers:
     "adam" is the reference's plain optax.adam; everything else dispatches
-    through bench_utils.make_optimizer ("fused"/"pallas"/"master")."""
+    through ops.adam.make_optimizer ("fused"/"pallas"/"master")."""
     if train_cfg.optimizer == "adam":
         return optax.adam(train_cfg.lr)
-    from ..bench_utils import make_optimizer
+    from ..ops.adam import make_optimizer
     return make_optimizer(train_cfg.optimizer, train_cfg.lr)
 
 

@@ -4,7 +4,7 @@ The cache's directory is part of its key, so a directory that moves never
 hits. Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and this
 module sets no other; where it is not, the cache lives at ``<repo>/.jax_cache``
 (git-ignored), the same path for every process of a checkout. The tests
-(``tests/conftest.py``), ``bench.py`` and ``chip_smoke.py`` all go through
+(``tests/conftest.py``) and ``chip_smoke.py`` go through
 ``enable_compilation_cache``.
 """
 

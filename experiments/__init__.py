@@ -18,7 +18,6 @@ table the reference notebook displays:
 - ``generative``   — centralized heart classifier + VAE synthetic-data
                      evaluation (lab/tutorial_2a).
 - ``pp_schedules`` — GPipe vs 1F1B schedule time/memory measurements.
-- ``attn_bench``   — XLA vs Pallas-flash attention at long sequence lengths.
 - ``plots``        — accuracy-curve rendering from the persisted CSVs
                      (lab/hw03/Tea_Pula_03.ipynb cell 11).
 

@@ -1,14 +1,16 @@
-"""Perf-trajectory comparator over bench headline JSON files.
+"""Trajectory comparator over JSON rows the smokes print.
 
-Reads any mix of the driver's per-round wrapper format
-(``{"parsed": {headline row}, "tail": <bench.py stdout>, ...}``),
-``BASELINE.json`` and raw ``bench.py`` stdout. (The ``BENCH_rNN.json``
-files it used to default to were deleted in PR 21, and tier1.yml no longer
-runs ``bench.py``: name the files to compare.) This tool — pure stdlib, no
-jax — prints the trajectory per (metric, platform, variant) group, and
-exits nonzero when the newest comparable row regresses more than
-``--max-regression`` percent against the best row of the SAME platform
-tag: a CPU number must never be judged against a TPU row.
+Not the yardstick: the driver judges a PR by ``BENCHMARK.json`` and
+``python3 benchmarks/run.py --workload <cell>`` (PERF.md). This tool
+compares the ``rows`` the CI smokes write (``experiments/*_smoke.py``,
+``serving_bench.py``: counts such as wire bytes a step, tokens per
+dispatch, overlap fraction) between named files: a bare row object, JSON
+lines between human lines, or a wrapper ``{"parsed": {row}, "tail": <text
+with JSON lines>}``. Pure stdlib, no jax. It prints the trajectory per
+(metric, platform, variant) group, and exits nonzero when the newest
+comparable row regresses more than ``--max-regression`` percent against
+the best row of the SAME platform tag: a CPU count must never be judged
+against a TPU row.
 Rows are direction-aware: throughput-like metrics regress downward, while
 ``wire_bytes_*`` / ``payload_bytes_*`` rows (the comm-wire smoke's) are
 lower-is-better and gate when the candidate RISES above the best (lowest)
@@ -76,9 +78,9 @@ def lower_is_better(metric: str) -> bool:
 
 def parse_rows(path: str) -> List[Dict[str, Any]]:
     """Headline rows from one file, tolerating all three shapes: the
-    driver wrapper (``parsed``, plus any JSON lines in ``tail``), raw
-    bench.py stdout (human lines interleaved with JSON rows), or a bare
-    row object. A row is any JSON object with ``metric`` and a numeric
+    wrapper (``parsed``, plus any JSON lines in ``tail``), a smoke's
+    stdout (human lines interleaved with JSON rows), or a bare row
+    object. A row is any JSON object with ``metric`` and a numeric
     ``value``. Rows carrying a numeric ``mfu``/``attainment`` field AND a
     platform tag additionally yield a derived row per field (see
     ``DERIVED_FIELDS``)."""

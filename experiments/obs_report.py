@@ -347,8 +347,8 @@ def report_run(events: list, heartbeat_path: str = None) -> None:
     peaks = (manifest or {}).get("peaks")
     if compiles and peaks:
         # Attainment section: what each dispatch ACHIEVED vs the roofline
-        # peaks the manifest recorded (ROOFLINE.md numbers on chip, the
-        # calibrated baseline on CPU fallback). Numerators: the compiled
+        # peaks the manifest recorded (the chip's published peaks by
+        # device_kind, the calibrated baseline on the CPU). Numerators: the compiled
         # program's HLO flops/bytes normalized PER STEP by the compile
         # event's own steps_per_dispatch (same rule as slo_monitor — a
         # ragged tail chunk's smaller program must not be costed as a

@@ -25,11 +25,6 @@ def _df(name: str):
     return pd.read_csv(path) if os.path.exists(path) else None
 
 
-def pd_notna(x) -> bool:
-    import pandas as pd
-    return bool(pd.notna(x))
-
-
 def _final_acc(df, **filters) -> Optional[float]:
     for k, v in filters.items():
         if k not in df.columns:
@@ -422,75 +417,13 @@ def render() -> str:
         w("dominates wall time.")
         w("")
 
-    # ------------------------------------------------------------------ SP
-    spb = _df("sp_bench.csv")
-    if spb is not None:
-        w("## Sequence parallelism — ring attention memory scaling "
-          "(this framework's addition)")
-        w("")
-        w("The reference fixes seq=256 on one device; this framework's ring")
-        w("attention (parallel/sp.py) shards the sequence over an ICI ring.")
-        w("XLA-compiled per-device temp memory of the full train step")
-        w("(hardware-independent, same methodology as the pipeline table):")
-        w("")
-        w("| global seq | ring size | per-device temp MB |")
-        w("|---|---|---|")
-        for _, r in spb.iterrows():
-            w(f"| {int(r['seq_len'])} | {int(r['n_seq'])} | "
-              f"{r['temp_bytes']/1e6:.0f} |")
-        w("")
-        w("Per-device memory drops near-linearly with the ring (the per-hop")
-        w("score block is [T/n, T/n]), so maximum context scales with")
-        w("devices — the long-context design goal, exact-equivalence-tested")
-        w("against single-device attention in tests/test_sp.py.")
-        w("")
-
-    # ----------------------------------------------------------- attention
-    ab = _df("attn_bench.csv")
-    plat = (str(ab["platform"].iloc[0]) if ab is not None
-            and "platform" in ab.columns else "pending")
-    w(f"## Attention backends — XLA vs Pallas flash (platform: {plat})")
+    w("## Speed")
     w("")
-    if ab is not None:
-        variants = [c.replace("_fwdbwd_ms", "") for c in ab.columns
-                    if c.endswith("_fwdbwd_ms")]
-        w("| batch×seq | " + " | ".join(f"{v} f+b ms" for v in variants)
-          + " | winner |")
-        w("|---|" + "---|" * (len(variants) + 1))
-        for _, r in ab.iterrows():
-            times = {v: r[f"{v}_fwdbwd_ms"] for v in variants
-                     if pd_notna(r[f"{v}_fwdbwd_ms"])}
-            win = min(times, key=times.get) if times else "—"
-            w(f"| {int(r['batch'])}×{int(r['seq'])} | "
-              + " | ".join(str(r[f"{v}_fwdbwd_ms"]) for v in variants)
-              + f" | {win} |")
-        w("")
-        if plat == "tpu":
-            w("`attention_impl='auto'` takes the dh-major wide-block flash")
-            w("kernel from T=256 up on TPU — it wins every row above (the")
-            w("dense [BH, Dh, T] layout un-pads head_dim 48's HBM traffic,")
-            w("and whole-sequence blocks drop the online-softmax")
-            w("recurrence); row-major flash loses below T≈4096 to its 2.67x")
-            w("lane-padding tax. (Builder's capture from round 4; not")
-            w("re-measured on today's code.)")
-        else:
-            w(f"Measured on `{plat}` — only a TPU run evidences the")
-            w("`flash_min_seq` crossover encoded in config.py; rerun")
-            w("on the chip.")
-        w("")
-    else:
-        w("(attn_bench.csv pending — requires the real chip.)")
-        w("")
-
-    w("## Headline bench")
-    w("")
-    w("Not measured on today's code. `PERF_LEDGER.jsonl` is the driver's")
-    w("record and `PERF.md` says what each number means; `python")
-    w("chip_smoke.py` proves that the trainer and the serving engine run on")
-    w("the chip and claims no speed. Earlier captures (round 4, toy shape:")
-    w("563,695 tok/s/chip at batch 64 by the builder's own log; 404,796 by")
-    w("the driver, 29 July) predate PRs 1-20 and their record files were")
-    w("deleted in PR 21. Kernel analysis: experiments/ROOFLINE.md.")
+    w("Nothing in this report is a speed. `PERF_LEDGER.jsonl` is the")
+    w("driver's record of `python3 benchmarks/run.py --workload <cell>` on")
+    w("a TPU v5e and `PERF.md` says what each number means (section 5:")
+    w("where the time goes); `python chip_smoke.py` proves that the trainer")
+    w("and the serving engine run on the chip and claims no speed.")
     w("")
     return "\n".join(lines)
 

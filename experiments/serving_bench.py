@@ -26,8 +26,7 @@ speculate on/off × k grid with the documented compile sets (2 plain /
 4 speculating), acceptance rate in [0, 1] (== 1 here), and
 ``tokens_per_dispatch`` ≥ 2× the plain engine's at k ≥ 3, recorded in
 the JSON. ``--prefix-share`` arms CoW prefix sharing on the same runs
-(streams must not move); ``--gather-buckets`` narrows the decode gather
-and reports the avoided bytes.
+(streams must not move).
 
 ``--engines N`` (N > 1) generalizes the smoke to the SERVING FLEET
 (serving/fleet.py): a two-class multi-tenant Poisson workload (priorities
@@ -145,8 +144,7 @@ def run(a) -> dict:
     t0 = time.perf_counter()
     report = run_serving(params, cfg, paged, workload, num_slots=a.slots,
                          prefill_chunk=a.prefill_chunk, events=events,
-                         prefix_share=a.prefix_share,
-                         gather_buckets=a.gather_buckets)
+                         prefix_share=a.prefix_share)
     wall = time.perf_counter() - t0
 
     spec_block = None
@@ -171,7 +169,7 @@ def run(a) -> dict:
         plain_sat = run_serving(
             params, cfg, paged, saturated, num_slots=1,
             prefill_chunk=a.prefill_chunk,
-            prefix_share=a.prefix_share, gather_buckets=a.gather_buckets)
+            prefix_share=a.prefix_share)
         # Its own telemetry stream (telemetry-dir/spec): sharing the
         # plain run's would double every (request, index) token event
         # and fail the exactly-once contract.
@@ -181,7 +179,7 @@ def run(a) -> dict:
             params, cfg, paged, saturated, num_slots=1,
             prefill_chunk=a.prefill_chunk,
             events=spec_tel.events if spec_tel else None,
-            prefix_share=a.prefix_share, gather_buckets=a.gather_buckets,
+            prefix_share=a.prefix_share,
             speculate=SpecConfig(k=a.speculate, draft_params=params))
         if spec_tel:
             spec_tel.close()
@@ -204,11 +202,9 @@ def run(a) -> dict:
             report.retraces == 0 and plain_sat.retraces == 0
             and spec_report.retraces == 0)
         # Documented compile sets: 2 plain, 4 speculating (prefill +
-        # verify + draft's two; decode_step idles) — per bucket width
-        # when the gather is narrowed.
-        if not a.gather_buckets:
-            checks["spec_compile_contract"] = (report.compiles == 2
-                                               and spec_report.compiles == 4)
+        # verify + draft's two; decode_step idles).
+        checks["spec_compile_contract"] = (report.compiles == 2
+                                           and spec_report.compiles == 4)
         checks["spec_acceptance_sane"] = (
             spec_report.acceptance_rate is not None
             and 0.0 <= spec_report.acceptance_rate <= 1.0)
@@ -316,7 +312,6 @@ def run(a) -> dict:
         "tokens_per_dispatch": report.tokens_per_dispatch,
         "speculate": spec_block,
         "prefix_share": bool(a.prefix_share),
-        "gather_bytes_saved": report.gather_bytes_saved,
         "checks": checks,
         "ok": all(checks.values()),
     }
@@ -534,9 +529,6 @@ def main(argv=None) -> int:
                          "tokens-per-dispatch >= 2x plain (K >= 3)")
     ap.add_argument("--prefix-share", action="store_true",
                     help="arm CoW prefix sharing (streams must not move)")
-    ap.add_argument("--gather-buckets", action="store_true",
-                    help="narrow the decode gather to bucketed live "
-                         "block counts; avoided bytes land in the JSON")
     ap.add_argument("--quick", action="store_true",
                     help="reduced request count (CI variance smoke)")
     ap.add_argument("--engines", type=int, default=1,

@@ -25,7 +25,7 @@ Objectives (each enabled by passing its threshold):
   window) × the window's step count ÷ the window's step time, against
   the manifest's recorded roofline peaks (the chip's published peaks
   by device_kind, the calibrated baseline on the CPU; schema v5).
-  Caveat, same as bench.py's FLOP crosscheck: ``cost_analysis`` counts
+  Caveat: ``cost_analysis`` counts
   a ``lax.scan`` body once (still so under jax 0.9.0), so a fused
   K-step program's flops read as ONE step's and chunked-mode MFU is
   biased low by ~K — set the floor from the same stream's observed

@@ -7,8 +7,8 @@ the TP column:
 
 1. the MODEL-AXIS activation wire of the relaxed PSA modes
    (TrainConfig.psa = "defer:L" / "int8_ef") is ≤ the ANALYTIC budget
-   (tp.psa_sync_wire_bytes — the same formulas, stated in
-   experiments/ROOFLINE.md) AND below the full-sync baseline measured
+   (tp.psa_sync_wire_bytes, whose docstring states the formulas) AND
+   below the full-sync baseline measured
    from the SAME run family (psa="full" routes the identical sync
    positions through the telemetry wrappers, so the comparison is
    trace-measured, not hand-computed);

@@ -194,7 +194,7 @@ def test_bench_compare_direction_aware_gating(tmp_path):
         return json.dumps({"metric": metric, "value": value,
                            "platform": "cpu", "variant": "v"})
 
-    committed = str(tmp_path / "BENCH_r01.json")
+    committed = str(tmp_path / "base_r01.json")
     with open(committed, "w") as f:
         f.write(row("wire_bytes_per_train_step", 100.0) + "\n"
                 + row("tps", 1000.0) + "\n")
@@ -225,7 +225,7 @@ def test_bench_compare_direction_aware_gating(tmp_path):
 
     # overlap_fraction gates downward too: a shrinking overlap window
     # (first hop waiting on more of the backward) is the regression.
-    committed2 = str(tmp_path / "BENCH_r02.json")
+    committed2 = str(tmp_path / "base_r02.json")
     with open(committed2, "w") as f:
         f.write(row("overlap_fraction", 0.8) + "\n")
     shrunk = str(tmp_path / "cand_shrunk.json")

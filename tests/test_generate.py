@@ -362,8 +362,8 @@ def test_speculative_greedy_neighbors_unperturbed_by_sampling(params):
 
 
 def test_bf16_kv_cache_close_to_fp32(params):
-    """kv_dtype="bfloat16" halves cache storage (the serving lever measured
-    in bench.py's decode sidebar); the decode must stay the same computation
+    """kv_dtype="bfloat16" halves cache storage (what the serving cells
+    run); the decode must stay the same computation
     up to bf16 rounding of cached K/V: logits within bf16 tolerance, and
     greedy tokens identical for a short horizon at this scale."""
     prompt = jax.random.randint(jax.random.PRNGKey(7), (2, 6), 0,

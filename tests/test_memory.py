@@ -112,7 +112,7 @@ def test_normalize_stats_variants():
 
 
 def test_program_memory_guard_and_this_jaxlib():
-    """The one shared reader (CompileWatch, sp_bench, pp_schedules): a
+    """The one shared reader (CompileWatch, pp_schedules): a
     non-jitted callable gives None; a jitted program accounts real
     bytes."""
     assert program_memory(lambda x: x, 1) is None

@@ -12,6 +12,7 @@ import os
 import re
 import subprocess
 import sys
+import time
 
 import jax
 import jax.numpy as jnp
@@ -565,13 +566,34 @@ def test_run_loop_totals_and_train_annotations_cover_the_same_intervals(
         tmp_path, devices, k):
     """`_run_loop`'s `Spans` totals (keys `data`, `dispatch`, `sink`,
     `checkpoint`) and the `train.*` annotations of the same phases: as
-    many of each, and the same seconds (an annotation holds its span)."""
+    many of each, and the same seconds. An annotation holds its span and,
+    after the span's clock has stopped, the append of the span's own event
+    to the JSONL stream (`Tracer._finish`): a write that takes 0.1 ms as a
+    rule and 1 to 20 ms when the workers of a whole tier-1 run share the
+    disk (read 4.8 ms on a 3.7 ms `sink` span here). So the seconds of
+    those appends are measured and taken out of the comparison, and what
+    is left, the cost of entering and leaving the annotation (0.01 to 0.25
+    ms a phase in 24 runs on the CPU), is held to 2 ms a span."""
     from ddl25spring_tpu.train.llm import train_llm_dp
 
     iters = 6
+    stream_name = {"data": "stage", "dispatch": "compute", "sink": "sink",
+                   "checkpoint": "checkpoint"}
+    append_s = dict.fromkeys(stream_name.values(), 0.0)
 
     def run():
         with Telemetry(str(tmp_path / "run"), step_every=2) as tel:
+            emit = tel.events.emit
+
+            def timed_emit(type, **fields):
+                t0 = time.perf_counter()
+                try:
+                    return emit(type, **fields)
+                finally:
+                    if type == "span" and fields["name"] in append_s:
+                        append_s[fields["name"]] += time.perf_counter() - t0
+
+            tel.events.emit = timed_emit
             train_llm_dp(
                 model_cfg=TINY,
                 train_cfg=TrainConfig(batch_size=2, seq_len=16, iters=iters,
@@ -589,7 +611,8 @@ def test_run_loop_totals_and_train_annotations_cover_the_same_intervals(
         held = sum(e["end"] - e["start"] for e in mine) / 1e9
         total = snap["gauges"][f"phase/{phase}_s"]
         assert total <= held + 1e-4 * len(mine)
-        assert held - total < 2e-3 * len(mine)
+        assert (held - total - append_s[stream_name[phase]]
+                < 2e-3 * len(mine)), (phase, held, total, append_s)
     its = [e["counters"]["it"] for e in events
            if e["name"] == "train.dispatch"]
     assert its == list(range(0, iters, k))

@@ -259,6 +259,24 @@ def test_save_overwrite_replaces_stale_step_after_fallback(tmp_path, devices):
         assert ckpt.restored_step == 3
         np.testing.assert_array_equal(np.asarray(restored["w"]),
                                       np.arange(8, dtype=np.float32) * 30)
+        # The stale entry may still be landing: a periodic save, then a
+        # re-mesh that persists the new layout under the same index
+        # (tests/test_elastic.py's preempt-remesh-resume pair failed now and
+        # then on exactly this: the delete raced the commit's rename). The
+        # overwrite deletes only once no save is in flight, and the
+        # replacement is what restores.
+        in_flight = []
+        delete = ckpt._mgr.delete
+        ckpt._mgr.delete = lambda s: (
+            in_flight.append(ckpt._mgr.is_saving_in_progress()), delete(s))
+        ckpt.save(4, {"w": tree["w"] * 4})
+        ckpt.save(4, {"w": tree["w"] * 40}, force=True, overwrite=True)
+        ckpt.wait()
+        assert in_flight == [False]
+        restored = ckpt.restore(tree)
+        assert ckpt.restored_step == 4
+        np.testing.assert_array_equal(np.asarray(restored["w"]),
+                                      np.arange(8, dtype=np.float32) * 40)
 
 
 def test_checkpoint_digest_catches_silent_bitflip(tmp_path):

@@ -334,53 +334,37 @@ def test_cow_under_poisson_load_bitwise_and_saves_blocks(params):
     assert rep_cow.peak_blocks_in_use < rep_pln.peak_blocks_in_use
 
 
-# ------------------------------------------------------ gather narrowing
+# ------------------------------------- a verify window past the table
 
-def test_gather_narrowing_bitwise_with_bounded_compiles(params):
-    """Opt-in decode-gather narrowing: streams stay bitwise generate()'s
-    (the dropped table columns contribute exact zeros through the
-    masked softmax), compile count stays within one per bucket width,
-    zero retraces, and the avoided gather bytes are accounted."""
-    wl = synthetic_workload(seed=11, n_requests=8, rate_rps=300.0,
-                            vocab_size=CFG.vocab_size,
-                            prompt_lens=(2, 5, 9), max_news=(3, 6),
-                            temperatures=(0.0, 0.7))
-    rep = run_serving(params, CFG, PAGED, wl, num_slots=3, prefill_chunk=4,
-                      gather_buckets=True)
-    for r in wl:
-        assert rep.records[r.rid].tokens == reference_stream(
-            params, CFG, PAGED, r), r.rid
-    assert rep.retraces == 0
-    buckets = len({1, 2, 4, 8})                  # mb=8 → 1/2/4/8
-    assert 2 <= rep.compiles <= 1 + buckets      # prefill + used widths
-    assert rep.gather_bytes_saved > 0
-    assert rep.gather_bytes > 0
-
-
-def test_gather_narrowing_with_speculation_at_the_horizon(params,
-                                                          draft_params):
-    """Regression: buckets × speculation on a full-width reservation. A
-    late verify window's host-side block need ceil((pos + k + 1) / bl)
-    spills one past the table width, and no bucket covers it — the need
-    must cap at max_blocks_per_seq (the overflow rows are trash-masked
-    in-program) instead of StopIteration off the bucket list. Stream
-    stays bitwise; nothing retraces."""
-    # One run covers both regressions: the edge request's 31-position
-    # full-width reservation drives a late window (pos ≥ 29) to ask for
-    # a 9th block, and the short prompt narrows the gather so the run
-    # spans two bucket widths — the DRAFT decode runs over the same
-    # narrowed slice as the verify, so its compile budget must cover one
-    # program per bucket width too (a spurious retrace when the draft's
-    # budget stayed at 1).
+def test_speculation_at_the_horizon_of_a_full_width_reservation(
+        params, draft_params):
+    """The edge request reserves the table's full width (24 + 8 positions,
+    eight blocks of four), and a late verify window (pos ≥ 29, k + 1 = 4
+    rows) asks for a ninth block: in-program the clamp ``blk_idx =
+    min(pos // bl, mb - 1)`` tops out at the table width and the overflow
+    rows are live-masked to trash. Streams stay bitwise ``generate()``'s
+    beside a short request, nothing retraces, and the engine's programs
+    are exactly the documented five, each with a budget of one compile
+    (``decode_step`` idles while speculation is on)."""
     wl = [Request(rid="short", prompt=(3, 5), max_new=4),
           Request(rid="edge", prompt=(4,) * 24, max_new=8)]
-    rep = run_serving(params, CFG, PAGED, wl, num_slots=2,
-                      prefill_chunk=8, gather_buckets=True,
-                      speculate=SpecConfig(k=3, draft_params=draft_params))
+    eng = Engine(params, CFG, PAGED, 2, prefill_chunk=8,
+                 speculate=SpecConfig(k=3, draft_params=draft_params))
+    sched = Scheduler(eng)
     for q in wl:
-        assert rep.records[q.rid].tokens == reference_stream(
+        sched.submit(q)
+    while sched.outstanding:
+        sched.tick()
+    for q in wl:
+        assert sched.records[q.rid].tokens == reference_stream(
             params, CFG, PAGED, q), q.rid
-    assert rep.retraces == 0
+    ws = eng.watches()
+    assert [w.name for w in ws] == [
+        "serving/prefill_chunk", "serving/decode_step",
+        "serving/verify_step", "serving/draft_prefill",
+        "serving/draft_decode"]
+    assert all(w.max_caches == 1 and w.retraces == 0 for w in ws)
+    assert [len(w.compiles) for w in ws] == [1, 0, 1, 1, 1]
 
 
 # ----------------------------------------------------- telemetry (v7)
@@ -423,7 +407,7 @@ def test_bench_compare_tokens_per_dispatch_higher_is_better(tmp_path):
                       "platform": "cpu", "variant": "spec-k4"}]}) + "\n")
         return str(p)
 
-    good = write("BENCH_r01.json", 4.5)
+    good = write("base_r01.json", 4.5)
     bad = write("cand.json", 2.0)
     _, regressions = compare([good], bad, max_regression_pct=10.0)
     assert regressions and "tokens_per_dispatch" in regressions[0]

@@ -25,8 +25,7 @@ from ddl25spring_tpu.metrics import ResilienceStats
 from ddl25spring_tpu.models import llama
 from ddl25spring_tpu.parallel import compress, dp, make_mesh
 from ddl25spring_tpu.telemetry import (EventLog, Heartbeat, MetricsRegistry,
-                                       SCHEMA_VERSION, Telemetry,
-                                       flops_crosscheck, hlo_cost,
+                                       SCHEMA_VERSION, Telemetry, hlo_cost,
                                        measure_comm, read_events,
                                        read_heartbeat, validate_event)
 from ddl25spring_tpu.tokenizers import ByteTokenizer
@@ -409,8 +408,7 @@ def test_measure_comm_handles_cached_trace():
 
 def test_hlo_cost_on_this_jaxlib():
     """The lower→compile→cost_analysis chain works on the installed jax,
-    and a single matmul's count matches 2*M*N*K, so flops_crosscheck
-    reports source='hlo'."""
+    and a single matmul's count matches 2*M*N*K within a tenth."""
     m, k, n = 32, 64, 16
     f = jax.jit(lambda a, b: a @ b)
     a = jax.ShapeDtypeStruct((m, k), jnp.float32)
@@ -418,21 +416,16 @@ def test_hlo_cost_on_this_jaxlib():
     hlo = hlo_cost(f, a, b)
     analytic = 2.0 * m * k * n
     assert hlo is not None and hlo["flops"] > 0
-    check = flops_crosscheck(analytic, hlo)
-    assert check["flops_source"] == "hlo"
-    assert check["rel_err"] < 0.10
+    assert abs(hlo["flops"] - analytic) / analytic < 0.10
 
 
 def test_hlo_cost_unavailable_paths():
     assert hlo_cost(lambda x: x, 1) is None          # not jitted: no .lower
-    assert flops_crosscheck(100.0, None) == {
-        "flops_source": "analytic", "hlo_flops": None, "rel_err": None}
-    # >10% divergence: the analytic formula stays authoritative.
-    far = flops_crosscheck(100.0, {"flops": 150.0, "bytes_accessed": None})
-    assert far["flops_source"] == "analytic"
-    assert far["rel_err"] == pytest.approx(0.5)
-    near = flops_crosscheck(100.0, {"flops": 105.0, "bytes_accessed": None})
-    assert near["flops_source"] == "hlo"
+    # A program that does not lower (shapes that cannot be multiplied)
+    # gives None, never an exception: the callers are observers.
+    f = jax.jit(lambda a, b: a @ b)
+    bad = jax.ShapeDtypeStruct((3, 5), jnp.float32)
+    assert hlo_cost(f, bad, bad) is None
 
 
 def test_hlo_cost_normalize_variants():
