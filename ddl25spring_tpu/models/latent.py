@@ -20,7 +20,10 @@ head. Attention over such rows has two arithmetic forms with one result:
 
 ``expand_pays(t, att, heads)`` says which needs fewer operations for ``t``
 queries a row; a program picks by the query length it is compiled for (a
-decode step folds, a prefill chunk expands), not by an option.
+decode step folds, a prefill chunk expands), not by an option. After the
+expansion, ``attend_expanded``'s scores, softmax and weighted sum are XLA
+operations here; on a TPU the engine hands it one kernel in their place
+(``ops/chunk_attention.py``; ``serving/engine.py::chunk_attention_path``).
 
 Weights (``init_params``; the layers stacked by run of one kind, in
 ``params["runs"]``): ``attn_norm``, ``w_qa`` [D, q_rank], ``q_norm``,
@@ -183,17 +186,23 @@ def attend_folded(w_kvb: jnp.ndarray, q: jnp.ndarray, rows: jnp.ndarray,
 
 def attend_expanded(w_kvb: jnp.ndarray, q: jnp.ndarray, rows: jnp.ndarray,
                     q_positions: jnp.ndarray, desc: ModelDescription,
-                    q_block: int = 128):
+                    q_block: int = 128, fused=None):
     """The same result with the rows expanded to per-head keys and values
     first: fewer operations where a row meets many queries. The queries go
     ``q_block`` at a time, so the float32 scores of all heads exist for
-    one block only."""
+    one block only. ``fused(q, kv, k_rope, q_positions)``, where the caller
+    has one (``ops/chunk_attention.py``), takes the place of everything
+    after the expansion: it is handed the expansion's product ``kv`` [S, K,
+    H (nope + v)] and the rows' rotated part [S, K, rope], the very numbers
+    the keys and values below are made of."""
     att = desc.attention
     s, k_len, _ = rows.shape
     t, h = q.shape[1], desc.num_heads
     rows = rows.astype(q.dtype)
-    kv = (rows[..., :att.kv_rank] @ w_kvb.astype(q.dtype)).reshape(
-        s, k_len, h, att.nope_dim + att.v_dim)
+    kv = rows[..., :att.kv_rank] @ w_kvb.astype(q.dtype)
+    if fused is not None:
+        return fused(q, kv, rows[..., att.kv_rank:att.row_dim], q_positions)
+    kv = kv.reshape(s, k_len, h, att.nope_dim + att.v_dim)
     k_rope = jnp.broadcast_to(rows[:, :, None, att.kv_rank:att.row_dim],
                               (s, k_len, h, att.rope_dim))
     keys = jnp.concatenate([kv[..., :att.nope_dim], k_rope], axis=-1)
@@ -216,11 +225,12 @@ def attend_expanded(w_kvb: jnp.ndarray, q: jnp.ndarray, rows: jnp.ndarray,
     return out.swapaxes(0, 1).reshape(s, t, h, att.v_dim)
 
 
-def attend(w_kvb, q, rows, q_positions, desc: ModelDescription):
-    """Folded or expanded by the query length ``q`` was traced with."""
-    fn = (attend_expanded if expand_pays(q.shape[1], desc.attention,
-                                         desc.num_heads) else attend_folded)
-    return fn(w_kvb, q, rows, q_positions, desc)
+def attend(w_kvb, q, rows, q_positions, desc: ModelDescription, fused=None):
+    """Folded or expanded by the query length ``q`` was traced with;
+    ``fused``: ``attend_expanded``'s."""
+    if expand_pays(q.shape[1], desc.attention, desc.num_heads):
+        return attend_expanded(w_kvb, q, rows, q_positions, desc, fused=fused)
+    return attend_folded(w_kvb, q, rows, q_positions, desc)
 
 
 # ------------------------------------------------------------------ weights

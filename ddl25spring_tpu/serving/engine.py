@@ -26,6 +26,10 @@ of this path, and its lowered text is the parent's), or, for a
 of layers of one kind over a pool of one latent row a position a layer
 (``_forward_described``; ``models/latent.py``, ``models/experts.py``, imported
 only then; checked against a float32 reference, tests/test_latent_experts.py).
+On a TPU that model's prefill chunk attends through a kernel over the
+expanded rows that stops at the chunk's live keys (``ops/chunk_attention.py``;
+``chunk_attention_path`` picks it as ``paged_attention_path`` picks the decode
+kernel below; tests/test_chunk_attention.py holds it to the XLA form).
 The engine keeps ONE copy of each weight, in the layout its programs read.
 
 Each is compiled exactly once per engine (static shapes: every dispatch
@@ -281,19 +285,68 @@ def _kernel_attention(pool: dict, tables: jnp.ndarray,
     return attend
 
 
+def chunk_attention_path(t: int, k_len: int, desc: ModelDescription) -> dict:
+    """The attention a ``_block_latent`` call of ``t`` query rows a slot
+    over ``k_len`` gathered positions traces to on this backend, as
+    ``paged_attention_path`` says it for the decode program of a
+    ``LlamaConfig`` model. The kernel (``ops/chunk_attention.py``) is the
+    prefill chunk's on a TPU: where ``t`` rows expand the latent rows to
+    per-head K and V (``latent.expand_pays``) and head sizes, blocks and
+    dtype are whole tiles. A decode step (folded attention) and every
+    program on another backend keep ``models/latent.py``'s XLA forms.
+    Looked up where the program is traced and where the engine is built
+    (what ``attended_positions`` counts): a test that wants the kernel in
+    the program replaces this function."""
+    from ..models import latent
+    att = desc.attention
+    xla = {"impl": "xla", "interpret": None}
+    if (jax.default_backend() != "tpu"
+            or not latent.expand_pays(t, att, desc.num_heads)):
+        return xla
+    from ..ops import chunk_attention as ca     # Pallas: only where it runs
+    if not ca.supported(t, k_len, att.nope_dim, att.rope_dim, att.v_dim,
+                        desc.dtype):
+        return xla
+    return {"impl": "pallas", "interpret": False}
+
+
+def _chunk_attention(desc: ModelDescription, k_len: int,
+                     positions: jnp.ndarray, valid: Optional[jnp.ndarray]):
+    """``latent.attend``'s ``fused`` where ``chunk_attention_path`` names
+    the kernel for this program, else None. The live length is the same for
+    every layer, so it is reckoned here, once, outside the layer scans: the
+    keys a slot's chunk may see end with its last real token's own
+    (``valid`` [S, T] marks the real ones)."""
+    path = chunk_attention_path(positions.shape[1], k_len, desc)
+    if path["impl"] != "pallas":
+        return None
+    from ..models import latent
+    from ..ops import chunk_attention as ca
+    att = desc.attention
+    live = jnp.max(positions if valid is None
+                   else jnp.where(valid, positions, -1), axis=1) + 1
+
+    def fused(q, kv, k_rope, q_positions):
+        return ca.chunk_attention(
+            q, kv, k_rope, q_positions, live, nope_dim=att.nope_dim,
+            scale=latent.softmax_scale(att), interpret=path["interpret"])
+    return fused
+
+
 def _block_latent(block: dict, kind: str, layer: jnp.ndarray,
                   pc: jnp.ndarray, x: jnp.ndarray, positions: jnp.ndarray,
                   tables: jnp.ndarray, wblk: jnp.ndarray, woff: jnp.ndarray,
                   valid: jnp.ndarray, desc: ModelDescription,
-                  group_offset=None):
+                  group_offset=None, fused=None):
     """One layer of a latent-attention model (``models/latent.py``), layer
     number ``layer`` of kind ``kind``, over x [S, T, D]: the paged twin of
     ``_block_paged`` for a pool ``pc`` [L, num_blocks, block_len, row_stride]
     of ONE row a position a layer. The row is written after its norm and
     rotation; attention reads the gathered rows as they lie, folded into
     latent space for a decode step, expanded for a prefill chunk
-    (``latent.attend`` picks by T). Returns (x, pc, routing stats of an
-    expert layer or None)."""
+    (``latent.attend`` picks by T; ``fused``: ``_chunk_attention``'s
+    kernel over the expanded rows, or None). Returns (x, pc, routing stats
+    of an expert layer or None)."""
     from ..models import latent
 
     s, t, _ = x.shape
@@ -311,7 +364,7 @@ def _block_latent(block: dict, kind: str, layer: jnp.ndarray,
     with jax.named_scope("latent.gather"):
         rows = pc[layer, tables].reshape(s, -1, pc.shape[-1])
     with jax.named_scope("latent.attend"):
-        out = latent.attend(block["w_kvb"], q, rows, positions, desc)
+        out = latent.attend(block["w_kvb"], q, rows, positions, desc, fused)
     with jax.named_scope("attn_out"):
         x = x + out.reshape(s, t, -1) @ block["w_o"].astype(x.dtype)
     x, stats = latent.second_half(block, kind, x, desc, valid, group_offset)
@@ -329,6 +382,8 @@ def _forward_described(head: dict, runs: tuple, tokens: jnp.ndarray,
     with jax.named_scope("embed"):
         h = head["embed"][tokens].astype(jnp.dtype(desc.dtype))
     pc, stats = pool["c"], []
+    fused = _chunk_attention(desc, tables.shape[1] * pc.shape[2], positions,
+                             valid)
     for (kind, start, count), blocks in zip(desc.runs(), runs):
         layers = start + jnp.arange(count, dtype=jnp.int32)
         whole = {}
@@ -347,7 +402,7 @@ def _forward_described(head: dict, runs: tuple, tokens: jnp.ndarray,
             x, pc, st = _block_latent(
                 dict(block, **whole), kind, layer, pc, x, positions, tables,
                 wblk, woff, valid, desc,
-                (layer - start) * held if whole else None)
+                (layer - start) * held if whole else None, fused)
             return (x, pc), st
 
         with jax.named_scope("layers"):
@@ -575,7 +630,11 @@ class Engine:
     ``engine.decode.{stage,dispatch,fetch,book}``. Under a live profiler
     the same spans stand on its timeline, the dispatch spans with the
     step's work as counters (``engine.prefill.dispatch``: ``slot``,
-    ``seq``, ``off``, ``n_valid``, ``final``; ``engine.decode.dispatch``:
+    ``seq``, ``off``, ``n_valid``, ``final``, ``attended_positions`` = the
+    key positions the chunk's attention visits: the table's full width
+    where it gathers, the live keys ``off + n_valid`` rounded up to the
+    kernel's key block where the kernel is its attention
+    (``chunk_attention_path``); ``engine.decode.dispatch``:
     ``dispatch`` = ``decode_dispatches`` as the step began, ``active``
     decoding slots, ``live_positions`` = the cache positions the step must
     attend to, sum of ``pos + 1`` over them, ``gathered_positions`` = what
@@ -635,6 +694,16 @@ class Engine:
         self._decode_reads_live_blocks = self.desc.plain and (
             paged_attention_path(1, *self.pool["k"].shape[3:],
                                  self.pool["k"].dtype)["impl"] == "pallas")
+        # The key block of the prefill program's attention where that is the
+        # kernel, which visits a chunk's live keys in whole blocks and no
+        # padded one, else 0: what ``attended_positions`` counts.
+        self._chunk_key_block = 0
+        if not self.desc.plain and chunk_attention_path(
+                prefill_chunk, paged.max_seq_len,
+                self.desc)["impl"] == "pallas":
+            from ..ops import chunk_attention as ca
+            self._chunk_key_block = ca.blocks(prefill_chunk,
+                                              paged.max_seq_len)[1]
         self.allocator = BlockAllocator(paged.num_blocks)
         self._admit_seq = 0
         self.slots: List[Optional[_Slot]] = [None] * num_slots
@@ -973,9 +1042,12 @@ class Engine:
             temp = jnp.float32(self.temps[s])
         routed = ({"pairs_routed": n_valid * self._pairs_a_token}
                   if self._pairs_a_token else {})
+        attended, bk = self.paged.max_seq_len, self._chunk_key_block
+        if bk:
+            attended = min(blocks_for(off + n_valid, bk) * bk, attended)
         with self.spans("engine.prefill.dispatch", slot=s, seq=slot.seq,
                         off=off, n_valid=n_valid, final=int(is_final),
-                        **routed):
+                        attended_positions=attended, **routed):
             self.pool, tok, new_key, *stats = self._prefill(
                 self.pool, self._head, self.fused,
                 table_row, chunk_j, *scalars, self.keys[s], temp)

@@ -85,7 +85,8 @@ SERVE_SPANS = {
     "engine.step": ("serve.tick", set()),
     "engine.prefill.stage": ("engine.step", set()),
     "engine.prefill.dispatch": ("engine.step",
-                                {"slot", "seq", "off", "n_valid", "final"}),
+                                {"slot", "seq", "off", "n_valid", "final",
+                                 "attended_positions"}),
     "engine.prefill.fetch": ("engine.step", set()),
     "engine.decode.stage": ("engine.step", set()),
     "engine.decode.dispatch": ("engine.step",
@@ -197,8 +198,11 @@ def test_dispatch_counters_give_what_the_slot_state_ledger_reckons(served):
     apart = {i: (a[0] - b[0], a[1] - b[1])
              for i, (a, b) in enumerate(zip(rows, reckoned)) if a != b}
     assert len(rows) == len(reckoned) and apart == {5: (1, 1 * 8 + 1)}
+    # the chunk gathers its table whole on this path (where the kernel is
+    # the attention, the live keys in key blocks: tests/test_chunk_attention.py)
     assert any(e["counters"] == {"slot": 2, "seq": 3, "off": 8, "n_valid": 1,
-                                 "final": 1}
+                                 "final": 1,
+                                 "attended_positions": PAGED.max_seq_len}
                for e in events if e["name"] == "engine.prefill.dispatch")
     assert steps == [{k: s[k] for k in ("tick", "active", "live_positions")}
                      for s in ledger.decode_steps]

@@ -259,7 +259,8 @@ def test_decode_step_at_the_7b_cells_shapes_holds_the_paged_kernel(
     assert "tpu_custom_call" not in on_cpu
 
 
-def test_latent_expert_serving_programs_at_published_widths(one_chip):
+def test_latent_expert_serving_programs_at_published_widths(one_chip,
+                                                            monkeypatch):
     """The engine's two programs for a described model (latent attention,
     routed experts) at A.X-K1's published widths, two layers (one dense,
     one of experts), 4 slots of 1024 positions. In the compiled
@@ -268,7 +269,10 @@ def test_latent_expert_serving_programs_at_published_widths(one_chip):
     they lie; ``prefill_chunk`` expands them. Neither program copies the
     whole pool (declared 576 wide instead of ``row_stride``'s 640, both
     did, in and out), and the softmax's maximum is no row-wide
-    ``reduce-window``."""
+    ``reduce-window``. With ``jax.default_backend`` steered as above so
+    that ``engine.chunk_attention_path`` names the kernel, ``prefill_chunk``
+    holds it over the expansion's product as it lies and no float32 scores,
+    and ``decode_step``'s lowered text is what it is without."""
     import json
 
     from ddl25spring_tpu.config import ModelDescription
@@ -295,18 +299,40 @@ def test_latent_expert_serving_programs_at_published_widths(one_chip):
     assert pool["c"].shape == (2, 257, 16, 640)
     s, mb, tc = 4, 64, 512
     i32, f32, u32 = jnp.int32, jnp.float32, jnp.uint32
-    decode = eng.make_decode_step(desc, paged, s, None, None).lower(
-        pool, head, params["runs"], sds((s, mb), i32), sds((s,), i32),
-        sds((s,), i32), sds((s, 2), u32), sds((s,), f32),
-        sds((s,), jnp.bool_)).compile().as_text()
+    step_args = (pool, head, params["runs"], sds((s, mb), i32),
+                 sds((s,), i32), sds((s,), i32), sds((s, 2), u32),
+                 sds((s,), f32), sds((s,), jnp.bool_))
+    chunk_args = (pool, head, params["runs"], sds((mb,), i32),
+                  sds((tc,), i32), sds((), i32), sds((), i32), sds((), i32),
+                  sds((2,), u32), sds((), f32))
+    lowered = eng.make_decode_step(desc, paged, s, None, None).lower(
+        *step_args)
+    decode = lowered.compile().as_text()
     prefill = eng.make_prefill_chunk(desc, paged, tc, None, None).lower(
-        pool, head, params["runs"], sds((mb,), i32), sds((tc,), i32),
-        sds((), i32), sds((), i32), sds((), i32), sds((2,), u32),
-        sds((), f32)).compile().as_text()
+        *chunk_args).compile().as_text()
     per_head = re.compile(r"\[(?:\d+,)*1024,64,(?:128|192|256)\]")
     assert not per_head.search(decode)
     assert per_head.search(prefill)
-    for text in (decode, prefill):
+    assert "chunk_attention" not in prefill
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert eng.chunk_attention_path(tc, mb * 16, desc) == {
+        "impl": "pallas", "interpret": False}
+    assert eng.make_decode_step(desc, paged, s, None, None).lower(
+        *step_args).as_text() == lowered.as_text()
+    compiled = eng.make_prefill_chunk(desc, paged, tc, None, None).lower(
+        *chunk_args).compile()
+    fused = compiled.as_text()
+    assert re.search(r"chunk_attention\S* = bf16\[1,512,8192\]\S* custom-call",
+                     fused)
+    # the kernel reads the product [positions, 64 heads x 256] as it lies;
+    # no scores [64 heads, queries, 1024 keys] are left, in any order
+    assert re.search(r"bf16\[1,1024,16384\]", fused)
+    assert not per_head.search(fused)
+    assert not re.search(r"f32\[(?:\d+,)*1024\]", fused)
+    assert not re.search(r"f32\[(?:\d+,)*64,(?:\d+,)*1024", fused)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
+    for text in (decode, prefill, fused):
         assert not re.search(r"= bf16\[2,257,16,640\]\S* copy\(", text)
         assert "reduce-window" not in text
         assert "ragged-dot" in text
