@@ -165,11 +165,69 @@ class ExpertLayer:
 
 
 @dataclass(frozen=True)
+class StateSpaceMixer:
+    """A Mamba-2 mixer as ``models/state_space.py`` computes it: ``heads``
+    heads of ``head_dim`` (``d_inner`` together, set by the config and not by
+    an expansion factor), ``groups`` groups of B and C of ``state`` values, a
+    causal depthwise convolution of ``conv`` taps over ``[x | B | C]``, a
+    chunked scan in chunks of ``chunk`` for a prefill chunk, and a gated
+    RMSNorm over each group's ``d_inner / groups`` values after the gate.
+    What a slot carries is a state ``[heads, head_dim, state]`` in
+    ``state_dtype`` (the recurrence's arithmetic is in that type too) and the
+    convolution's last ``conv - 1`` inputs ``[conv - 1, conv_dim]``."""
+
+    d_inner: int
+    heads: int
+    head_dim: int
+    groups: int
+    state: int
+    conv: int
+    chunk: int
+    state_dtype: str = "float32"
+
+    def __post_init__(self):
+        if (self.heads * self.head_dim != self.d_inner
+                or self.heads % self.groups or self.d_inner % self.groups):
+            raise ValueError(f"bad state-space mixer: {self}")
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels of the convolution: x beside every group's B and C."""
+        return self.d_inner + 2 * self.groups * self.state
+
+    @property
+    def proj_dim(self) -> int:
+        """Width of the input projection: ``[z | x | B | C | dt]``."""
+        return self.d_inner + self.conv_dim + self.heads
+
+
+@dataclass(frozen=True)
+class Multipliers:
+    """The muP multipliers of a ``falcon_h1`` config, by its keys; ``ssm``
+    scales the parts of the mixer's projection (z, x, B, C, dt in that
+    order), ``mlp`` the gate before its silu and the down projection's
+    result. All 1: no multiplier."""
+
+    embedding: float = 1.0
+    lm_head: float = 1.0
+    attention_in: float = 1.0
+    attention_out: float = 1.0
+    key: float = 1.0
+    ssm_in: float = 1.0
+    ssm_out: float = 1.0
+    ssm: Tuple[float, float, float, float, float] = (1.0,) * 5
+    mlp: Tuple[float, float] = (1.0, 1.0)
+
+
+@dataclass(frozen=True)
 class ModelDescription:
     """A decoder as the serving engine reads it: one kind per layer
     (``"dense"``: SwiGLU of width ``ffn_hidden``; ``"experts"``:
-    ``experts``), one attention kind for all layers (``attention`` None:
-    K and V per head with heads of ``dmodel / num_heads``; else latent),
+    ``experts``; ``"parallel"``: a state-space ``mixer`` and attention side
+    by side on one normed input, their outputs summed into one residual,
+    then the SwiGLU), one attention kind for all layers (``attention``
+    None: K and V per head, ``kv_heads`` of them (None: as many as query
+    heads) of ``head_size`` (None: ``dmodel / num_heads``); else latent),
     and the sizes that follow. ``LlamaConfig`` models are described by
     ``describe``; a published ``config.json`` by ``from_published``."""
 
@@ -186,31 +244,56 @@ class ModelDescription:
     attention: Optional[LatentAttention] = None
     experts: Optional[ExpertLayer] = None
     init_std: float = 0.02         # ``initializer_range``
+    kv_heads: Optional[int] = None
+    head_size: Optional[int] = None
+    mixer: Optional[StateSpaceMixer] = None
+    multipliers: Optional[Multipliers] = None
 
     def __post_init__(self):
-        bad = set(self.layer_kinds) - {"dense", "experts"}
+        bad = set(self.layer_kinds) - {"dense", "experts", "parallel"}
         if bad or not self.layer_kinds:
             raise ValueError(f"layer kinds {sorted(bad)}: the engine has "
-                             "'dense' and 'experts'")
+                             "'dense', 'experts' and 'parallel'")
         if "experts" in self.layer_kinds and self.experts is None:
             raise ValueError("expert layers without an ExpertLayer")
+        if ("parallel" in self.layer_kinds) != (self.mixer is not None) \
+                or (self.mixer is not None
+                    and (set(self.layer_kinds) != {"parallel"}
+                         or self.attention is not None)):
+            raise ValueError("a state-space mixer stands beside K and V per "
+                             "head in every layer, all of kind 'parallel'")
+        if self.num_heads % self.num_kv_heads:
+            raise ValueError(f"{self.num_heads} query heads over "
+                             f"{self.num_kv_heads} key/value heads")
 
     @property
     def n_layers(self) -> int:
         return len(self.layer_kinds)
 
     @property
+    def num_kv_heads(self) -> int:
+        return self.num_heads if self.kv_heads is None else self.kv_heads
+
+    @property
+    def head_dim(self) -> int:
+        return (self.dmodel // self.num_heads if self.head_size is None
+                else self.head_size)
+
+    @property
     def plain(self) -> bool:
-        """K and V per head and dense layers only: what ``LlamaConfig``
-        states, and the engine's original path."""
-        return self.attention is None and set(self.layer_kinds) == {"dense"}
+        """K and V per head, as many key/value heads as query heads, heads
+        of ``dmodel / num_heads`` and dense layers only: what
+        ``LlamaConfig`` states, and the engine's original path."""
+        return (self.attention is None and set(self.layer_kinds) == {"dense"}
+                and self.num_kv_heads == self.num_heads
+                and self.head_dim * self.num_heads == self.dmodel)
 
     @property
     def cache_row(self) -> int:
         """Values one cache position holds in one layer."""
         if self.attention is not None:
             return self.attention.row_dim
-        return 2 * self.dmodel
+        return 2 * self.num_kv_heads * self.head_dim
 
     def runs(self) -> Tuple[Tuple[str, int, int], ...]:
         """The layers as runs of one kind: (kind, first layer, count)."""
@@ -235,6 +318,8 @@ class ModelDescription:
         n = int(cfg["num_hidden_layers"])
         attention = experts = None
         kinds = ("dense",) * n
+        if cfg.get("model_type") == "falcon_h1":
+            return cls._falcon_h1(cfg, ctx_size, dtype, param_dtype)
         if "kv_lora_rank" in cfg:
             rs = cfg.get("rope_scaling") or {}
             attention = LatentAttention(
@@ -252,7 +337,9 @@ class ModelDescription:
                 mscale_all_dim=float(rs.get("mscale_all_dim", 0)))
         elif cfg.get("num_key_value_heads",
                      cfg["num_attention_heads"]) != cfg["num_attention_heads"]:
-            raise ValueError("grouped K/V heads: the engine has none")
+            raise ValueError("grouped K/V heads: the engine has them beside "
+                             "a state-space mixer only (model_type "
+                             "falcon_h1)")
         if cfg.get("n_routed_experts"):
             held = int(cfg["n_routed_experts"])
             experts = ExpertLayer(
@@ -279,6 +366,55 @@ class ModelDescription:
                    ctx_size=ctx_size, dtype=dtype, param_dtype=param_dtype,
                    attention=attention, experts=experts,
                    init_std=float(cfg.get("initializer_range", 0.02)))
+
+    @classmethod
+    def _falcon_h1(cls, cfg: dict, ctx_size: int, dtype: str,
+                   param_dtype: str) -> "ModelDescription":
+        """``model_type`` ``falcon_h1``: every block a Mamba-2 mixer beside
+        grouped-query attention (``head_dim`` stated, not ``hidden_size /
+        num_attention_heads``), the muP multipliers by their keys.
+        ``state_dtype`` (this repo's key, float32 unless stated) is the
+        type of the carried state and of the recurrence."""
+        if (cfg.get("attention_bias") or cfg.get("mlp_bias")
+                or cfg.get("mamba_proj_bias") or cfg.get("projectors_bias")
+                or not cfg.get("mamba_conv_bias", True)
+                or not cfg.get("mamba_rms_norm", True)
+                or cfg.get("mamba_norm_before_gate")
+                or cfg.get("rope_scaling") or cfg.get("tie_word_embeddings")
+                or cfg.get("attn_layer_indices") is not None):
+            raise ValueError(
+                "falcon_h1 as the engine has it: no projection biases, a "
+                "convolution bias, the gated RMSNorm after the gate, plain "
+                "RoPE, untied head, attention in every layer")
+        mixer = StateSpaceMixer(
+            d_inner=int(cfg["mamba_d_ssm"]), heads=int(cfg["mamba_n_heads"]),
+            head_dim=int(cfg["mamba_d_head"]),
+            groups=int(cfg["mamba_n_groups"]),
+            state=int(cfg["mamba_d_state"]), conv=int(cfg["mamba_d_conv"]),
+            chunk=int(cfg["mamba_chunk_size"]),
+            state_dtype=str(cfg.get("state_dtype", "float32")))
+        mult = Multipliers(
+            embedding=float(cfg["embedding_multiplier"]),
+            lm_head=float(cfg["lm_head_multiplier"]),
+            attention_in=float(cfg["attention_in_multiplier"]),
+            attention_out=float(cfg["attention_out_multiplier"]),
+            key=float(cfg["key_multiplier"]),
+            ssm_in=float(cfg["ssm_in_multiplier"]),
+            ssm_out=float(cfg["ssm_out_multiplier"]),
+            ssm=tuple(float(m) for m in cfg["ssm_multipliers"]),
+            mlp=tuple(float(m) for m in cfg["mlp_multipliers"]))
+        return cls(vocab_size=int(cfg["vocab_size"]),
+                   dmodel=int(cfg["hidden_size"]),
+                   num_heads=int(cfg["num_attention_heads"]),
+                   layer_kinds=("parallel",) * int(cfg["num_hidden_layers"]),
+                   ffn_hidden=int(cfg["intermediate_size"]),
+                   norm_eps=float(cfg["rms_norm_eps"]),
+                   rope_theta=float(cfg["rope_theta"]),
+                   ctx_size=ctx_size, dtype=dtype, param_dtype=param_dtype,
+                   init_std=float(cfg.get("initializer_range", 0.02)),
+                   kv_heads=int(cfg["num_key_value_heads"]),
+                   head_size=int(cfg["head_dim"]), mixer=mixer,
+                   multipliers=mult)
 
 
 def describe(cfg) -> ModelDescription:

@@ -77,12 +77,12 @@ _NEG_INF = -1e30
 _OTHER_HEAD = 1 << 30        # col_pos of a column that is another head's
 
 
-def supported(h: int, dh: int, kv_dtype) -> bool:
+def supported(h: int, dh: int, kv_dtype, block_len: int = 1) -> bool:
     """Whether the compiled kernel takes a pool of ``h`` heads of ``dh`` in
-    ``kv_dtype``: a block read as ``[block_len * h, dh]`` must be whole
-    tiles (lanes of 128, sublanes of 8 x 4 / itemsize)."""
+    ``kv_dtype``: ``block_len * h`` rows must be whole tiles (128 lanes, 8 x 4
+    / itemsize sublanes); ``block_len`` is given for grouped heads only."""
     sublanes = 8 * 4 // jnp.dtype(kv_dtype).itemsize
-    return dh % _LANES == 0 and h % sublanes == 0
+    return dh % _LANES == 0 and (block_len * h) % sublanes == 0
 
 
 def head_columns(positions: int, h: int) -> jnp.ndarray:
@@ -95,15 +95,15 @@ def head_columns(positions: int, h: int) -> jnp.ndarray:
 
 
 def plan(tables: jnp.ndarray, lengths: jnp.ndarray, block_len: int,
-         h: int) -> tuple:
+         h: int, group: int = 1) -> tuple:
     """What the kernel fetches and masks by, the same for every layer of a
     step (reckon it once, outside the layer scan): from the tables
     [S, width] and each slot's live length [S] (0 = not decoding),
-    (lengths, ``fetch`` [S, width], ``col_pos`` = ``head_columns`` of one
-    block). ``fetch`` is ``tables`` with every entry past a slot's last
-    live block replaced by the last live entry before it in grid order
-    (slot by slot, block by block), so that a dead grid step names the
-    block already fetched."""
+    (lengths, ``fetch`` [S, width], ``col_pos`` = ``grouped_columns`` of one
+    block: ``h`` key/value heads, ``group`` query heads reading each).
+    ``fetch`` is ``tables`` with every entry past a slot's last live block
+    replaced by the last live entry before it in grid order (slot by slot,
+    block by block): a dead grid step names the block already fetched."""
     s, width = tables.shape
     lengths = lengths.astype(jnp.int32)
     live = (jnp.arange(width, dtype=jnp.int32)[None, :] * block_len
@@ -111,15 +111,15 @@ def plan(tables: jnp.ndarray, lengths: jnp.ndarray, block_len: int,
     steps = jnp.arange(s * width, dtype=jnp.int32)
     last_live = lax.cummax(jnp.where(live, steps, 0))
     fetch = tables.astype(jnp.int32).reshape(-1)[last_live]
-    return lengths, fetch.reshape(s, width), head_columns(block_len, h)
+    return lengths, fetch.reshape(s, width), grouped_columns(block_len, h, group)
 
 
 def fold(q, k, v, visible, scale: float, m_prev, l_prev, acc_prev):
-    """One step of the online softmax over all heads at once: q [H, Dh]
-    against k, v [P*H, Dh] (P positions as they lie in the pool), of which
-    row ``head`` may see the columns ``visible`` [H, P*H] marks. Returns
-    the running maximum and sum [H, 1] and the weighted sum [H, Dh], all
-    float32."""
+    """One step of the online softmax over all heads at once: q [Hq, Dh]
+    against k, v [P*H, Dh] (P positions of H key/value heads as they lie in
+    the pool; Hq = H x group), of which row ``head`` may see the columns
+    ``visible`` [Hq, P*H] marks. Returns the running maximum and sum
+    [Hq, 1] and the weighted sum [Hq, Dh], all float32."""
     sc = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                          preferred_element_type=jnp.float32) * scale
     sc = jnp.where(visible, sc, _NEG_INF)
@@ -145,8 +145,8 @@ def _kernel(layer_ref, len_ref, fetch_ref, q_ref, col_pos_ref, k_ref, v_ref,
 
     @pl.when(j * block_len < n)
     def _body():
-        q = q_ref[...]                                        # [H, Dh]
-        rows = block_len * q.shape[0]                         # P * H
+        q = q_ref[...]                                        # [Hq, Dh]
+        rows = block_len * k_ref.shape[-2]                    # P * H
         k = k_ref[...].reshape(rows, q.shape[1]).astype(q.dtype)
         v = v_ref[...].reshape(rows, q.shape[1]).astype(q.dtype)
         m_new, l_new, acc = fold(
@@ -167,20 +167,20 @@ def _kernel(layer_ref, len_ref, fetch_ref, q_ref, col_pos_ref, k_ref, v_ref,
 def paged_attention(q: jnp.ndarray, pk: jnp.ndarray, pv: jnp.ndarray,
                     layer: jnp.ndarray, walk: tuple, *,
                     interpret: bool | None = None) -> jnp.ndarray:
-    """One query row a slot over the stacked pool: q [S, H, Dh] (after
+    """One query row a slot over the stacked pool: q [S, Hq, Dh] (after
     RoPE), pk/pv [L, num_blocks, block_len, H, Dh] WHOLE (never a layer's
     slice), ``layer`` a scalar, ``walk`` = ``plan(tables, lengths,
-    block_len, H)`` of the positions each slot attends to (its own, just
-    written, among them; 0 for a slot that is not decoding). Returns
-    [S, H, Dh] in q's dtype; zeros for a slot of length 0."""
-    s, h, dh = q.shape
-    block_len = pk.shape[2]
+    block_len, H, Hq // H)`` of the positions each slot attends to (its own,
+    just written, among them; 0 for a slot that is not decoding); query head
+    ``i`` reads key/value head ``i // (Hq // H)``. Returns [S, Hq, Dh]."""
+    s, hq, dh = q.shape
+    block_len, h = pk.shape[2], pk.shape[3]
     lengths, fetch, col_pos = walk
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
 
     def slot_spec():
-        return pl.BlockSpec((None, h, dh), lambda s_, j, *_: (s_, 0, 0))
+        return pl.BlockSpec((None, hq, dh), lambda s_, j, *_: (s_, 0, 0))
 
     def pool_spec():
         return pl.BlockSpec(
@@ -200,12 +200,29 @@ def paged_attention(q: jnp.ndarray, pk: jnp.ndarray, pv: jnp.ndarray,
                       pool_spec(), pool_spec()],
             out_specs=slot_spec(),
             scratch_shapes=[
-                pltpu.VMEM((h, _LANES), jnp.float32),         # m
-                pltpu.VMEM((h, _LANES), jnp.float32),         # l
-                pltpu.VMEM((h, dh), jnp.float32),             # acc
+                pltpu.VMEM((hq, _LANES), jnp.float32),        # m
+                pltpu.VMEM((hq, _LANES), jnp.float32),        # l
+                pltpu.VMEM((hq, dh), jnp.float32),            # acc
             ]),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
         name="paged_attention",
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), lengths, fetch, q,
       col_pos, pk, pv)
+
+
+def grouped_columns(positions: int, h: int, group: int) -> jnp.ndarray:
+    """``head_columns`` for ``group`` query heads a key/value head:
+    ``col_pos`` [H * group, positions * H], column ``t * H + head`` position
+    ``t`` in the rows of the query heads that read ``head`` (query head ``i``
+    reads ``i // group``) and the sentinel in every other row. A group of 1
+    is ``head_columns`` itself, operation for operation. (It stands down
+    here, and the functions above keep their lines, so that the kernel's
+    serialised text, which carries line numbers, stays byte for byte what
+    the dense serving cells' programs held before the kernel had groups.)"""
+    if group == 1:
+        return head_columns(positions, h)
+    cols = jnp.arange(positions * h, dtype=jnp.int32)
+    reads = jnp.arange(h * group)[:, None] // group
+    return jnp.where(cols[None, :] % h == reads, cols[None, :] // h,
+                     _OTHER_HEAD).astype(jnp.int32)

@@ -44,7 +44,8 @@ from .frontend import (ServingReport, TrafficClass,  # noqa: F401
                        aggregate_latency, class_slos, multi_tenant_workload,
                        reference_stream, run_serving, synthetic_workload)
 from .kvcache import (TRASH_BLOCK, BlockAllocator,  # noqa: F401
-                      PagedKVConfig, blocks_for, init_pool,
-                      kv_bytes_per_token, naive_cache_bytes, pool_bytes)
+                      PagedKVConfig, blocks_for, init_pool, init_state,
+                      kv_bytes_per_token, naive_cache_bytes, pool_bytes,
+                      state_bytes_per_slot)
 from .scheduler import Request, RequestRecord, Scheduler  # noqa: F401
 from .speculate import DraftEngine, SpecConfig  # noqa: F401

@@ -30,6 +30,12 @@ On a TPU that model's prefill chunk attends through a kernel over the
 expanded rows that stops at the chunk's live keys (``ops/chunk_attention.py``;
 ``chunk_attention_path`` picks it as ``paged_attention_path`` picks the decode
 kernel below; tests/test_chunk_attention.py holds it to the XLA form).
+A ``ModelDescription`` of ``parallel`` layers (a state-space mixer beside
+grouped-query attention in every block, ``models/state_space.py``) goes
+through ``_forward_parallel``: the same pool of K and V, of the key/value
+heads only, and beside it in the same donated tree a state store of one row a
+SLOT (``kvcache.init_state``), which a prefill chunk carries on for its slot
+and a decode step for every decoding slot (tests/test_state_space.py).
 The engine keeps ONE copy of each weight, in the layout its programs read.
 
 Each is compiled exactly once per engine (static shapes: every dispatch
@@ -86,7 +92,7 @@ from .. import nn
 from ..models import generate, llama
 from ..telemetry.trace import Spans
 from .kvcache import (TRASH_BLOCK, BlockAllocator, PagedKVConfig, blocks_for,
-                      init_pool)
+                      init_pool, init_state, state_bytes_per_slot)
 
 
 def check_swappable(old, new) -> None:
@@ -169,10 +175,12 @@ def _attend_paged(q: jnp.ndarray, ck: jnp.ndarray, cv: jnp.ndarray,
     return out.reshape(b, h, tq, dh).transpose(0, 2, 1, 3)
 
 
-def paged_attention_path(t: int, h: int, dh: int, kv_dtype) -> dict:
+def paged_attention_path(t: int, h: int, dh: int, kv_dtype,
+                         block_len: int = 1) -> dict:
     """The attention a ``_block_paged`` call of ``t`` query rows a slot over
-    a pool of ``h`` heads of ``dh`` in ``kv_dtype`` traces to on this
-    backend: ``{"impl": "pallas" | "xla", "interpret": bool | None}``, as
+    a pool of ``h`` heads of ``dh`` in ``kv_dtype`` (``block_len``: given
+    for grouped key/value heads, ``ops.paged_attention.supported``) traces
+    to on this backend: ``{"impl": "pallas" | "xla", "interpret": bool | None}``, as
     ``llama.attention_path`` says it for the flash kernel. The kernel
     (``ops/paged_attention.py``) is the decode program's, ``t == 1``, on a
     TPU, where the pool's blocks are whole tiles; a chunk of queries, a
@@ -184,7 +192,7 @@ def paged_attention_path(t: int, h: int, dh: int, kv_dtype) -> dict:
     if t != 1 or jax.default_backend() != "tpu":
         return xla
     from ..ops import paged_attention as pa     # Pallas: only where it runs
-    if not pa.supported(h, dh, kv_dtype):
+    if not pa.supported(h, dh, kv_dtype, block_len):
         return xla
     return {"impl": "pallas", "interpret": False}
 
@@ -262,22 +270,27 @@ def _block_paged(block: dict, layer: jnp.ndarray, pk: jnp.ndarray,
 
 
 def _kernel_attention(pool: dict, tables: jnp.ndarray,
-                      positions: jnp.ndarray, valid: Optional[jnp.ndarray]):
+                      positions: jnp.ndarray, valid: Optional[jnp.ndarray],
+                      group: int = 1):
     """``_block_paged``'s ``attend`` where ``paged_attention_path`` names
     the kernel for this program, else None. What the kernel fetches is the
     same for every layer, so it is reckoned here, once, outside the layer
     scan: a slot attends to the position it has just written and all before
     it; one that is not decoding (``valid`` [S, 1] False: it wrote to
-    trash) reads nothing."""
+    trash) reads nothing. ``group`` query heads read each of the pool's
+    key/value heads (``_block_parallel``; the path function is then asked
+    with the block's length, ``ops.paged_attention.supported``)."""
     pk = pool["k"]
-    path = paged_attention_path(positions.shape[1], *pk.shape[3:], pk.dtype)
+    grouped = () if group == 1 else (pk.shape[2],)
+    path = paged_attention_path(positions.shape[1], *pk.shape[3:], pk.dtype,
+                                *grouped)
     if path["impl"] != "pallas":
         return None
     from ..ops import paged_attention as pa
     lengths = positions[:, 0] + 1
     if valid is not None:
         lengths = jnp.where(valid[:, 0], lengths, 0)
-    walk = pa.plan(tables, lengths, pk.shape[2], pk.shape[3])
+    walk = pa.plan(tables, lengths, pk.shape[2], pk.shape[3], group)
 
     def attend(q, pk, pv, layer):
         return pa.paged_attention(q[:, 0], pk, pv, layer, walk,
@@ -412,15 +425,157 @@ def _forward_described(head: dict, runs: tuple, tokens: jnp.ndarray,
     return h, {"c": pc}, (jnp.concatenate(stats) if stats else None)
 
 
+def _attend_grouped(q: jnp.ndarray, ck: jnp.ndarray, cv: jnp.ndarray,
+                    q_positions: jnp.ndarray) -> jnp.ndarray:
+    """``_attend_paged`` for fewer key/value heads than query heads: q
+    [S, Tq, Hq, Dh] over the gathered cache [S, Tmax, H, Dh], query head
+    ``i`` reading key/value head ``i // (Hq / H)``. The queries of a group
+    are laid side by side as rows of their key/value head, so the cache is
+    read once a key/value head and never repeated."""
+    s, tq, hq, dh = q.shape
+    h = ck.shape[2]
+    g = hq // h
+    rows = q.reshape(s, tq, h, g, dh).transpose(0, 3, 1, 2, 4).reshape(
+        s, g * tq, h, dh)
+    out = _attend_paged(rows, ck, cv, jnp.tile(q_positions, (1, g)))
+    return out.reshape(s, g, tq, h, dh).transpose(0, 2, 3, 1, 4).reshape(
+        s, tq, hq, dh)
+
+
+def _block_parallel(block: dict, layer: jnp.ndarray, pool: dict,
+                    x: jnp.ndarray, positions: jnp.ndarray,
+                    tables: jnp.ndarray, wblk: jnp.ndarray,
+                    woff: jnp.ndarray, n_valid: jnp.ndarray, slot,
+                    desc: ModelDescription, attend=None):
+    """One layer of a model whose two mixers stand side by side
+    (``models/state_space.py``), layer number ``layer``, over x [S, T, D]:
+    ``u = norm(x)``; ``x + ssm_out SSM(u) + attention_out Attn(attention_in
+    u)``, then the SwiGLU with its two multipliers. Attention is
+    ``_block_paged``'s over a pool of ``kv_heads`` heads of ``head_dim``
+    (the same scopes; ``attend``: ``_kernel_attention``'s, told the group). The
+    mixer reads and writes the second kind of cache in place
+    (``kvcache.init_state``): ``pool["s"]`` and ``pool["tail"]`` WHOLE, of
+    which this call touches layer ``layer`` of slot ``slot`` (a prefill
+    chunk, S == 1; position 0 is a request's first, and starts from zeros
+    whatever the slot held) or of every slot (``slot`` None: a decode
+    step). ``n_valid`` [S] real positions: a slot with none keeps state and
+    tail."""
+    from ..models import state_space
+
+    m, mx = desc.multipliers, desc.mixer
+    s, t, d = x.shape
+    hq, h, dh = desc.num_heads, desc.num_kv_heads, desc.head_dim
+    pk, pv, st, tl = pool["k"], pool["v"], pool["s"], pool["tail"]
+    with jax.named_scope("qkv"):
+        u = nn.rmsnorm(block["in_norm"], x, eps=desc.norm_eps)
+        qkv = ((u * jnp.asarray(m.attention_in, u.dtype))
+               @ block["w_qkv"].astype(x.dtype))
+        q = qkv[..., :hq * dh].reshape(s, t, hq, dh)
+        k = (qkv[..., hq * dh:(hq + h) * dh]
+             * jnp.asarray(m.key, u.dtype)).reshape(s, t, h, dh)
+        v = qkv[..., (hq + h) * dh:].reshape(s, t, h, dh)
+        cos, sin = llama.rope_angles(positions.reshape(-1), dh,
+                                     desc.rope_theta)
+        cos = cos.reshape(s, t, -1)
+        sin = sin.reshape(s, t, -1)
+        q = _apply_rope_slots(q, cos, sin)
+        k = _apply_rope_slots(k, cos, sin)
+    with jax.named_scope("paged.write"):
+        pk = pk.at[layer, wblk, woff].set(k.astype(pk.dtype))
+        pv = pv.at[layer, wblk, woff].set(v.astype(pv.dtype))
+    if attend is not None:
+        with jax.named_scope("paged.attend"):
+            out = attend(q, pk, pv, layer)
+    else:
+        with jax.named_scope("paged.gather"):
+            ck = pk[layer, tables].reshape(s, -1, h, dh)
+            cv = pv[layer, tables].reshape(s, -1, h, dh)
+        with jax.named_scope("paged.attend"):
+            out = _attend_grouped(q, ck, cv, positions)
+    with jax.named_scope("attn_out"):
+        att = (out.reshape(s, t, hq * dh) @ block["w_o"].astype(x.dtype)
+               ) * jnp.asarray(m.attention_out, x.dtype)
+    # this call's rows of the state store: every slot's (a decode step), or
+    # one slot's, from zeros where the chunk is its request's first
+    if slot is None:
+        take = lambda a: a[layer]                           # noqa: E731
+        put = lambda a, rows: a.at[layer].set(rows)         # noqa: E731
+    else:
+        fresh = positions[0, 0] == 0
+
+        def take(a):
+            rows = lax.dynamic_slice(
+                a, (layer, slot) + (0,) * (a.ndim - 2), (1, 1) + a.shape[2:])
+            return jnp.where(fresh, jnp.zeros_like(rows[0]), rows[0])
+
+        def put(a, rows):
+            return lax.dynamic_update_slice(
+                a, rows[None], (layer, slot) + (0,) * (a.ndim - 2))
+    y, s1, t1 = state_space.mixer(block, u, take(st), take(tl), n_valid, desc)
+    with jax.named_scope("ssm.scan"):
+        st = put(st, s1)
+    with jax.named_scope("ssm.conv"):
+        tl = put(tl, t1)
+    with jax.named_scope("ssm.out"):
+        x = x + y * jnp.asarray(m.ssm_out, x.dtype) + att
+    with jax.named_scope("mlp"):
+        xn = nn.rmsnorm(block["ff_norm"], x, eps=desc.norm_eps)
+        gu = xn @ block["w_gu"].astype(x.dtype)
+        f = gu.shape[-1] // 2
+        gate = jax.nn.silu(gu[..., :f] * jnp.asarray(m.mlp[0], x.dtype))
+        x = x + ((gate * gu[..., f:]) @ block["w_down"].astype(x.dtype)
+                 ) * jnp.asarray(m.mlp[1], x.dtype)
+    return x, {"k": pk, "v": pv, "s": st, "tail": tl}
+
+
+def _forward_parallel(head: dict, runs: tuple, tokens: jnp.ndarray,
+                      pool: dict, tables, positions, wblk, woff, valid, slot,
+                      desc: ModelDescription):
+    """``_forward_paged`` for a model of ``parallel`` layers: one lax.scan
+    over (layer number, block) with the hidden state, the whole pool and the
+    whole state store as its carry; under the programs' donation each of
+    them is one buffer, argument, loop state and result."""
+    with jax.named_scope("embed"):
+        h = (head["embed"][tokens].astype(jnp.dtype(desc.dtype))
+             * jnp.asarray(desc.multipliers.embedding, jnp.dtype(desc.dtype)))
+    n_valid = jnp.sum(valid, axis=1).astype(jnp.int32)
+    attend = _kernel_attention(pool, tables, positions, valid,
+                               desc.num_heads // desc.num_kv_heads)
+    layers = jnp.arange(desc.n_layers, dtype=jnp.int32)
+
+    def body(carry, layer_block):
+        x, pool = carry
+        layer, block = layer_block
+        return _block_parallel(block, layer, pool, x, positions, tables, wblk,
+                               woff, n_valid, slot, desc, attend), None
+
+    with jax.named_scope("layers"):
+        (h, pool), _ = lax.scan(body, (h, pool), (layers, runs[0]))
+    return h, pool, None
+
+
+def _head(params: dict, h: jnp.ndarray, cfg) -> jnp.ndarray:
+    """``llama.head`` times the model's ``lm_head_multiplier``, where it has
+    one."""
+    logits = llama.head(params, h, cfg)
+    m = describe(cfg).multipliers
+    if m is None:
+        return logits
+    with jax.named_scope("head"):
+        return logits * jnp.float32(m.lm_head)
+
+
 def _forward_paged(params: dict, fused_blocks, tokens: jnp.ndarray,
                    pool: dict, tables: jnp.ndarray, positions: jnp.ndarray,
                    wblk: jnp.ndarray, woff: jnp.ndarray, cfg,
-                   valid: Optional[jnp.ndarray] = None):
+                   valid: Optional[jnp.ndarray] = None, slot=None):
     """tokens [S, T] at per-slot absolute ``positions`` [S, T] → (hidden
     [S, T, D], updated pool, routing stats or None). Dispatches on the
     model's description: a model of other layer kinds than ``LlamaConfig``
     states goes through ``_forward_described`` (``valid`` [S, T] marks its
-    real tokens for the expert layers). Else one lax.scan over (layer
+    real tokens for the expert layers) or, with a state-space mixer,
+    ``_forward_parallel`` (``slot``: the one slot a prefill chunk is of).
+    Else one lax.scan over (layer
     number, fused block) with the hidden state AND the whole stacked pool
     as its carry: under the programs' donation of the pool, argument, loop
     state and result are one buffer, written and gathered in place by
@@ -428,6 +583,9 @@ def _forward_paged(params: dict, fused_blocks, tokens: jnp.ndarray,
     stacked inputs and outputs; a pool of gigabytes cannot afford the slice
     out and the write back that costs, every layer of every run.)"""
     desc = describe(cfg)
+    if desc.mixer is not None:
+        return _forward_parallel(params, fused_blocks, tokens, pool, tables,
+                                 positions, wblk, woff, valid, slot, desc)
     if not desc.plain:
         return _forward_described(params, fused_blocks, tokens, pool, tables,
                                   positions, wblk, woff, valid, desc)
@@ -484,7 +642,10 @@ def make_prefill_chunk(cfg: LlamaConfig, paged: PagedKVConfig,
     re-writing them would scribble on another request's read-only blocks.
     The recomputed values are bitwise the shared ones (same tokens, same
     positions, same weights), so discarding them changes nothing. 0 (the
-    non-sharing case) writes everything, byte-for-byte the old program."""
+    non-sharing case) writes everything, byte-for-byte the old program.
+
+    ``slot`` (a model with a state-space mixer only): the slot whose rows
+    of the state store, donated with the pool, the chunk reads and writes."""
     bl, mb = paged.block_len, paged.max_blocks_per_seq
 
     @partial(jax.jit, donate_argnums=(0,))
@@ -492,7 +653,7 @@ def make_prefill_chunk(cfg: LlamaConfig, paged: PagedKVConfig,
                       table_row: jnp.ndarray, tokens: jnp.ndarray,
                       start: jnp.ndarray, n_valid: jnp.ndarray,
                       write_from: jnp.ndarray,
-                      key: jnp.ndarray, temperature: jnp.ndarray):
+                      key: jnp.ndarray, temperature: jnp.ndarray, slot=None):
         start = jnp.asarray(start, jnp.int32)
         pos = start + jnp.arange(chunk_len, dtype=jnp.int32)       # [Tc]
         valid = jnp.logical_and(jnp.arange(chunk_len) < n_valid,
@@ -503,12 +664,12 @@ def make_prefill_chunk(cfg: LlamaConfig, paged: PagedKVConfig,
         h, pool, stats = _forward_paged(
             params, fused, tokens[None], pool, table_row[None], pos[None],
             wblk[None], woff[None], cfg,
-            (jnp.arange(chunk_len) < n_valid)[None])
+            (jnp.arange(chunk_len) < n_valid)[None], slot)
         # Logits of the last valid row only — the [1, 1, D] head matmul
         # ``generate`` performs (never the full [Tc, V] logits).
         last = jnp.take_along_axis(
             h, (n_valid - 1).reshape(1, 1, 1).astype(jnp.int32), axis=1)
-        logits = llama.head(params, last, cfg)[:, 0, :]            # [1, V]
+        logits = _head(params, last, cfg)[:, 0, :]                 # [1, V]
         with jax.named_scope("sample"):
             key, sub = jax.random.split(key)
             tok = _sample_slot(sub, logits, temperature, top_k, top_p)
@@ -551,7 +712,7 @@ def make_decode_step(cfg: LlamaConfig, paged: PagedKVConfig,
         h, pool, stats = _forward_paged(
             params, fused, last_tok[:, None], pool, tables, pos[:, None],
             wblk[:, None], woff[:, None], cfg, active[:, None])
-        logits = llama.head(params, h, cfg)[:, 0, :]               # [S, V]
+        logits = _head(params, h, cfg)[:, 0, :]                    # [S, V]
         with jax.named_scope("sample"):
             split = jax.vmap(jax.random.split)(keys)               # [S, 2, 2]
             subs = split[:, 1]
@@ -666,11 +827,24 @@ class Engine:
         # attention, the cache's row, the experts held here.
         self.desc = describe(cfg)
         if not self.desc.plain and (speculate is not None or prefix_share):
+            if self.desc.mixer is not None:
+                raise NotImplementedError(
+                    "speculation and prefix sharing serve LlamaConfig models "
+                    "only: a slot's recurrent state (kvcache.init_state) is "
+                    "one value for the whole prefix, so it cannot be rolled "
+                    "back past a rejected draft nor shared by the block "
+                    "(ROADMAP.md)")
             raise NotImplementedError(
                 "speculation and prefix sharing serve LlamaConfig models "
                 "only: a draft for, and shared blocks of latent rows under, "
                 f"a model of layers {set(self.desc.layer_kinds)} with "
                 "latent attention are not built (ROADMAP.md)")
+        mx = self.desc.mixer
+        if mx is not None and prefill_chunk > mx.chunk \
+                and prefill_chunk % mx.chunk:
+            raise ValueError(f"prefill_chunk={prefill_chunk}: the chunked "
+                             f"scan takes whole chunks of {mx.chunk}, or "
+                             "one shorter chunk")
         self.paged = paged
         self.num_slots = num_slots
         self.prefill_chunk_len = prefill_chunk
@@ -687,18 +861,24 @@ class Engine:
         # placement for the hot-swap contract, and ``params`` rebuilds it.
         self.boot = jax.tree.map(LeafSpec, params)
         self._set_weights(params)
-        self.pool = init_pool(cfg, paged)
+        # Two kinds of cache in one donated tree: the paged pool, and for a
+        # model with a state-space mixer the state store (``kvcache.
+        # init_state``: "s" and "tail", a row a slot; else nothing).
+        self.pool = {**init_pool(cfg, paged), **init_state(cfg, num_slots)}
+        self.state_bytes_per_slot = state_bytes_per_slot(cfg)
         # Whether the decode program's attention is the kernel, which reads
         # the decoding slots' live blocks and no padded position: what
         # ``gathered_positions`` counts (``_dispatch_counters``).
-        self._decode_reads_live_blocks = self.desc.plain and (
-            paged_attention_path(1, *self.pool["k"].shape[3:],
-                                 self.pool["k"].dtype)["impl"] == "pallas")
+        self._decode_reads_live_blocks = "k" in self.pool and (
+            paged_attention_path(
+                1, *self.pool["k"].shape[3:], self.pool["k"].dtype,
+                *(() if mx is None else (paged.block_len,))
+            )["impl"] == "pallas")
         # The key block of the prefill program's attention where that is the
         # kernel, which visits a chunk's live keys in whole blocks and no
         # padded one, else 0: what ``attended_positions`` counts.
         self._chunk_key_block = 0
-        if not self.desc.plain and chunk_attention_path(
+        if self.desc.attention is not None and chunk_attention_path(
                 prefill_chunk, paged.max_seq_len,
                 self.desc)["impl"] == "pallas":
             from ..ops import chunk_attention as ca
@@ -958,6 +1138,12 @@ class Engine:
     def blocks_in_use(self) -> int:
         return self.allocator.in_use
 
+    def state_bytes_in_use(self) -> int:
+        """Bytes of the state store that admitted requests own: a slot's
+        rows from admission to retirement (0 without a state-space mixer)."""
+        return self.state_bytes_per_slot * sum(
+            sl is not None for sl in self.slots)
+
     # ------------------------------------------------------- weight hot-swap
     def swap_params(self, params: dict, *, fused: Optional[dict] = None
                     ) -> None:
@@ -1045,12 +1231,19 @@ class Engine:
         attended, bk = self.paged.max_seq_len, self._chunk_key_block
         if bk:
             attended = min(blocks_for(off + n_valid, bk) * bk, attended)
+        mx, of_slot = self.desc.mixer, ()
+        if mx is not None:
+            # the slot whose state the chunk carries on, and the chunks of
+            # the scan that hold a real position
+            of_slot = (jnp.int32(s),)
+            routed = {"state_slots": 1, "scan_chunks": blocks_for(
+                n_valid, min(mx.chunk, tc))}
         with self.spans("engine.prefill.dispatch", slot=s, seq=slot.seq,
                         off=off, n_valid=n_valid, final=int(is_final),
                         attended_positions=attended, **routed):
             self.pool, tok, new_key, *stats = self._prefill(
                 self.pool, self._head, self.fused,
-                table_row, chunk_j, *scalars, self.keys[s], temp)
+                table_row, chunk_j, *scalars, self.keys[s], temp, *of_slot)
             if stats:
                 self._chunk_stats.append((stats[0], n_valid))
             if self.draft is not None:
@@ -1095,6 +1288,9 @@ class Engine:
         a dispatch of ``tq`` query rows a slot."""
         routed = ({"pairs_routed": int(active.sum()) * self._pairs_a_token}
                   if self._pairs_a_token else {})
+        if self.desc.mixer is not None:
+            # the slots whose state the step reads and writes
+            routed = {"state_slots": int(active.sum())}
         bl, width = self.paged.block_len, int(tables.shape[1])
         live = self.pos[active].astype(np.int64) + 1
         if tq == 1 and self._decode_reads_live_blocks:

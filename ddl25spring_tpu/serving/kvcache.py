@@ -33,6 +33,12 @@ static-shape/one-compile discipline):
   absolute position (``kpos <= pos``), the same invariant that makes
   ``generate``'s unwritten cache tail safe.
 
+- A model with a state-space mixer has a second kind of cache beside the
+  pool, which no block holds and nothing pages: ``init_state``, a recurrent
+  state and the convolution's carried inputs a SLOT a layer, fixed in size
+  whatever the length. Admission reckons blocks for the pool and a slot for
+  the state; the engine keeps both in one donated tree.
+
 Sizing math (docs/COMPONENTS.md "Serving" carries the worked example):
 one block holds ``2 · L · block_len · H · Dh · itemsize`` bytes of K+V;
 a request of prompt ``P`` generating ``M`` tokens writes positions
@@ -104,7 +110,8 @@ def row_stride(desc) -> int:
 def init_pool(cfg, paged: PagedKVConfig) -> dict:
     """Zeroed block pool, sized from the model's description
     (``config.describe``). K and V per head: {"k","v"} each [L, num_blocks,
-    block_len, H, Dh]. Latent attention: {"c"} [L, num_blocks, block_len,
+    block_len, H, Dh], ``H`` the key/value heads (fewer than the query heads
+    where they are grouped). Latent attention: {"c"} [L, num_blocks, block_len,
     row_dim], ONE row a position a layer (``models/latent.py::latent_row``)
     and nothing per head, in ``row_stride`` lanes. Layer-major like ``init_cache``, but the engine
     does not scan the leading axis: the whole stacked pool is its layer
@@ -115,8 +122,42 @@ def init_pool(cfg, paged: PagedKVConfig) -> dict:
     lead = (desc.n_layers, paged.num_blocks, paged.block_len)
     if desc.attention is not None:
         return {"c": jnp.zeros(lead + (row_stride(desc),), dt)}
-    shape = lead + (cfg.num_heads, cfg.head_dim)
+    shape = lead + (desc.num_kv_heads, desc.head_dim)
     return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+
+
+def init_state(cfg, num_slots: int) -> dict:
+    """The second kind of cache, for a model with a state-space mixer
+    (``config.StateSpaceMixer``; {} for every other): what each SLOT carries
+    whatever its length, zeroed. {"s": the recurrent state [L, num_slots,
+    heads, head_dim, state] in ``state_dtype``, "tail": the convolution's
+    last ``conv - 1`` inputs [L, num_slots, conv - 1, conv_dim] in the
+    compute type}. Nothing pages it and no block holds it: a slot owns its
+    rows from admission to retirement, the engine's two programs take the
+    store donated beside the pool and hand it back written in place, and a
+    request's first prefill chunk starts from zeros whatever the slot's last
+    request left (``engine._block_parallel``)."""
+    desc = describe(cfg)
+    mx = desc.mixer
+    if mx is None:
+        return {}
+    lead = (desc.n_layers, num_slots)
+    return {"s": jnp.zeros(lead + (mx.heads, mx.head_dim, mx.state),
+                           jnp.dtype(mx.state_dtype)),
+            "tail": jnp.zeros(lead + (mx.conv - 1, mx.conv_dim),
+                              jnp.dtype(desc.dtype))}
+
+
+def state_bytes_per_slot(cfg) -> int:
+    """Bytes of ``init_state`` one slot owns across all layers; 0 for a
+    model without a state-space mixer."""
+    desc = describe(cfg)
+    mx = desc.mixer
+    if mx is None:
+        return 0
+    return desc.n_layers * (
+        mx.heads * mx.head_dim * mx.state * jnp.dtype(mx.state_dtype).itemsize
+        + (mx.conv - 1) * mx.conv_dim * jnp.dtype(desc.dtype).itemsize)
 
 
 def kv_bytes_per_token(cfg, kv_dtype: Optional[str] = None) -> int:

@@ -66,7 +66,7 @@ observable from the host), flagged with the chunk index; reassemble with
 Host time by cause (``Scheduler.spans``, a ``telemetry.trace.Spans``, with
 or without ``events=``): every ``tick()`` is a ``serve.tick`` span (counters
 ``n`` the tick's index, ``queued``, ``in_flight``, ``blocks_in_use`` as it
-began) round ``serve.admit`` (``admitted``), the engine's own
+began, and ``state_bytes_in_use`` where the model has a state-space mixer) round ``serve.admit`` (``admitted``), the engine's own
 ``engine.step`` and ``serve.emit`` (the loop over the engine's events to
 the return: ``tokens``, ``retired``). They are on real time, never in the
 event stream, and under a live profiler they stand on its timeline with
@@ -288,9 +288,12 @@ class Scheduler:
     def tick(self) -> List[Tuple[str, int]]:
         """One token boundary: admit, advance the engine, retire. Returns
         the (rid, token) pairs emitted this boundary."""
+        # a model with a state-space mixer: what its slots' state holds
+        state = ({"state_bytes_in_use": self.engine.state_bytes_in_use()}
+                 if self.engine.state_bytes_per_slot else {})
         with self.spans("serve.tick", n=self._tick_n, queued=len(self.queue),
                         in_flight=len(self._by_slot),
-                        blocks_in_use=self.engine.blocks_in_use()):
+                        blocks_in_use=self.engine.blocks_in_use(), **state):
             self._tick_n += 1
             with self.spans("serve.admit") as admit:
                 admit.set_metadata(admitted=self._admit())
@@ -477,15 +480,20 @@ class Scheduler:
         Highest priority class first; within it, FCFS — or, under "sjf"
         when the class head's reservation doesn't fit, the shortest
         fitting reservation (ties by arrival)."""
+        if self.engine.free_slot() is None:
+            # nothing admits without a slot: a full engine does not walk a
+            # backlog of a thousand requests every tick to learn that
+            return None
         top = max(r.priority for r in self.queue)
-        group = [i for i, r in enumerate(self.queue) if r.priority == top]
-        head = self.queue[group[0]]
+        first = next(i for i, r in enumerate(self.queue) if r.priority == top)
+        head = self.queue[first]
         if self.engine.can_admit(len(head.prompt), head.max_new,
                                  prompt=head.prompt):
-            return group[0]
-        if self.policy == "sjf" and self.engine.free_slot() is not None:
-            fitting = [i for i in group
-                       if self.engine.can_admit(len(self.queue[i].prompt),
+            return first
+        if self.policy == "sjf":
+            fitting = [i for i, r in enumerate(self.queue)
+                       if r.priority == top
+                       and self.engine.can_admit(len(self.queue[i].prompt),
                                                 self.queue[i].max_new,
                                                 prompt=self.queue[i].prompt)]
             if fitting:

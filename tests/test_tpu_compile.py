@@ -313,7 +313,11 @@ def test_latent_expert_serving_programs_at_published_widths(one_chip,
     per_head = re.compile(r"\[(?:\d+,)*1024,64,(?:128|192|256)\]")
     assert not per_head.search(decode)
     assert per_head.search(prefill)
-    assert "chunk_attention" not in prefill
+    # the kernel's call, not the bare name: the compiled text lists the
+    # source files of its operations' stack frames, and under xdist's
+    # ``--dist loadfile`` a worker that ran tests/test_chunk_attention.py
+    # first has cached jaxprs of jax's own helpers traced from that file
+    assert not re.search(r"chunk_attention\S* = ", prefill)
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert eng.chunk_attention_path(tc, mb * 16, desc) == {
@@ -336,3 +340,70 @@ def test_latent_expert_serving_programs_at_published_widths(one_chip,
         assert not re.search(r"= bf16\[2,257,16,640\]\S* copy\(", text)
         assert "reduce-window" not in text
         assert "ragged-dot" in text
+
+
+def test_state_space_serving_programs_at_published_widths(one_chip,
+                                                          monkeypatch):
+    """The engine's two programs for a model of state-space mixers beside
+    grouped-query attention at Falcon-H1-34B's published widths, two layers,
+    8 slots of 1024 positions, with ``jax.default_backend`` steered so that
+    the decode step's attention is the kernel (20 query heads over 4
+    key/value heads). Pool and state store are donated and aliased to the
+    results, and neither program holds a temporary of the state's size, the
+    whole store's or one layer's: the decode step updates every slot's state
+    in place, the prefill chunk one slot's. The scan's products are float32
+    (the state's type), the carried inputs of the convolution bf16."""
+    import json
+
+    from ddl25spring_tpu.config import ModelDescription
+    from ddl25spring_tpu.models import state_space
+    from ddl25spring_tpu.serving import engine as eng
+    from ddl25spring_tpu.serving.kvcache import (PagedKVConfig, init_pool,
+                                                 init_state)
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmarks", "configs",
+                           "falcon-h1-34b.json")) as f:
+        cfg = dict(json.load(f), num_hidden_layers=2)
+    desc = ModelDescription.from_published(
+        cfg, ctx_size=1024, dtype="bfloat16", param_dtype="bfloat16")
+    paged = PagedKVConfig(num_blocks=513, block_len=16, max_blocks_per_seq=64,
+                          kv_dtype="bfloat16")
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,  # noqa: E731
+                                                 sharding=one_chip)
+    params = _abstract_state(
+        lambda: state_space.init_params(jax.random.key(0), desc), one_chip)
+    head = {k: v for k, v in params.items() if k != "runs"}
+    s, mb, tc = 8, 64, 256
+    pool = _abstract_state(
+        lambda: {**init_pool(desc, paged), **init_state(desc, s)}, one_chip)
+    assert pool["k"].shape == (2, 513, 16, 4, 128)
+    assert pool["s"].shape == (2, s, 32, 128, 256)
+    assert pool["s"].dtype == jnp.float32
+    assert pool["tail"].shape == (2, s, 3, 5120)
+    donated = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(pool))
+    i32, f32, u32 = jnp.int32, jnp.float32, jnp.uint32
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert eng.paged_attention_path(1, 4, 128, jnp.dtype("bfloat16"), 16) == {
+        "impl": "pallas", "interpret": False}
+    decode = eng.make_decode_step(desc, paged, s, None, None).lower(
+        pool, head, params["runs"], sds((s, mb), i32), sds((s,), i32),
+        sds((s,), i32), sds((s, 2), u32), sds((s,), f32),
+        sds((s,), jnp.bool_)).compile()
+    prefill = eng.make_prefill_chunk(desc, paged, tc, None, None).lower(
+        pool, head, params["runs"], sds((mb,), i32), sds((tc,), i32),
+        sds((), i32), sds((), i32), sds((), i32), sds((2,), u32),
+        sds((), f32), sds((), i32)).compile()
+    layer_state = s * 32 * 128 * 256 * 4           # one layer's, every slot
+    for compiled in (decode, prefill):
+        m = compiled.memory_analysis()
+        assert m.alias_size_in_bytes >= donated
+        assert m.temp_size_in_bytes < layer_state // 2
+        text = compiled.as_text()
+        assert not re.search(r"= f32\[2,8,32,128,256\]\S* copy\(", text)
+        assert not re.search(r"= bf16\[2,513,16,4,128\]\S* copy\(", text)
+    text = decode.as_text()
+    assert "tpu_custom_call" in text and "paged_attention" in text
+    # the gather of the padded table is gone from the decode step
+    assert "[8,1024,4,128]" not in text
+    assert "tpu_custom_call" not in prefill.as_text()
