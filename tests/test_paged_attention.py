@@ -61,62 +61,55 @@ CASES = {
     "free_slot_between": [20, None, 37],
     "free_slot_first_and_last": [None, 9, 31, None],
     "all_but_one_free": [None, None, 40, None],
+    "none_decoding": [None, None, None],
 }
+# name -> (table width, blocks a work item; None: ``chunk_blocks``'s rule,
+# which at these widths is the whole table)
+WALKS = {"table_of_4": (4, None), "table_of_6": (6, None),
+         "chunks_of_3_over_4": (4, 3)}
 
 
-@pytest.mark.parametrize("width", [4, 6])
-@pytest.mark.parametrize("storage", ["float32", "bfloat16"])
+@pytest.mark.parametrize("walk", sorted(WALKS))
+@pytest.mark.parametrize("storage,group", [("float32", 1), ("bfloat16", 1),
+                                           ("bfloat16", 5)])
 @pytest.mark.parametrize("case", sorted(CASES))
-def test_kernel_matches_the_gathered_attention(case, storage, width):
-    """Random pool, scattered tables, a layer other than 0, tables of 4
-    blocks (a full slot fills its table) and of 6 (dead steps behind every
-    slot)."""
+def test_kernel_matches_the_gathered_attention(case, storage, group, walk):
+    """Random pool, scattered tables, a layer other than 0; tables of 4
+    blocks (a full slot fills its table), of 6 (dead entries behind every
+    slot), and chunks of 3 blocks over tables of 4 (a slot's last chunk is
+    part empty, and what its dead blocks' places hold must meet zeros);
+    ``group`` query heads a key/value head (5: 20 over 4, as Falcon-H1's),
+    against ``_attend_grouped``. Every block the step must not read (the
+    tables of the slots that are not decoding, as a slot in mid-prefill
+    holds real blocks, and the dead entries of the others) is NaN in the
+    kernel's pool: the results are the gather's over the clean pool."""
     pk, pv, rng = random_pool(storage)
     slots = CASES[case]
-    s = len(slots)
+    s, (width, chunk) = len(slots), WALKS[walk]
+    hq = H * group
     tables = scattered_tables(rng, s, width)
-    q = jnp.asarray(rng.normal(size=(s, H, DH)), storage)
+    q = jnp.asarray(rng.normal(size=(s, hq, DH)), storage)
     pos = jnp.asarray([p or 0 for p in slots], jnp.int32)
     decoding = np.array([p is not None for p in slots])
     lengths = jnp.where(decoding, pos + 1, 0)
+    unread = np.asarray(tables)[
+        np.arange(width)[None, :] * BL >= np.asarray(lengths)[:, None]]
+    chunk = chunk or pa.chunk_blocks(BL, width)
+    plan = pa.plan(lengths, width, BL, H, group, chunk)
+    assert int(plan[4][0]) == sum(-(-(-(-(p + 1) // BL)) // chunk)
+                                  for p in slots if p is not None)
     layer = 2
-    out = pa.paged_attention(q, pk, pv, jnp.int32(layer),
-                             pa.plan(tables, lengths, BL, H), interpret=True)
-    want = xla_path(q, pk, pv, layer, tables, pos)
+    out = pa.paged_attention(q, pk.at[:, unread].set(jnp.nan),
+                             pv.at[:, unread].set(jnp.nan), jnp.int32(layer),
+                             tables, plan, interpret=True)
+    ck = pk[layer, tables].reshape(s, -1, H, DH)
+    cv = pv[layer, tables].reshape(s, -1, H, DH)
+    want = eng._attend_grouped(q[:, None], ck, cv, pos[:, None])[:, 0]
     out, want = np.asarray(out, np.float32), np.asarray(want, np.float32)
-    assert out.dtype == want.dtype and out.shape == (s, H, DH)
+    assert out.shape == (s, hq, DH)
     np.testing.assert_allclose(out[decoding], want[decoding], rtol=0,
                                atol=TOL[storage])
     # a slot that is not decoding returns finite values that nothing uses
-    assert np.all(out[~decoding] == 0.0)
-
-
-@pytest.mark.parametrize("storage", ["float32", "bfloat16"])
-@pytest.mark.parametrize("case", ["ragged", "free_slot_first_and_last",
-                                  "position_16", "full_slot"])
-def test_the_flat_list_form_is_the_same_attention(case, storage):
-    """``experiments/paged_attention_flat.py`` (measured, not wired in:
-    PERF.md, PR 30) walks a flat list of live chunks with copies of its
-    own; chunks of 3 blocks over tables of 4, so a slot's last chunk is
-    part empty and what its dead blocks' places hold must meet zeros."""
-    from experiments import paged_attention_flat as flat
-
-    pk, pv, rng = random_pool(storage)
-    slots = CASES[case]
-    s, width = len(slots), 4
-    tables = scattered_tables(rng, s, width)
-    q = jnp.asarray(rng.normal(size=(s, H, DH)), storage)
-    pos = jnp.asarray([p or 0 for p in slots], jnp.int32)
-    decoding = np.array([p is not None for p in slots])
-    walk = flat.plan(jnp.where(decoding, pos + 1, 0), width, BL, H,
-                     chunk_blocks=3)
-    assert int(walk[4][0]) == sum(-(-(-(-(p + 1) // BL)) // 3)
-                                  for p in slots if p is not None)
-    out = np.asarray(flat.paged_attention(q, pk, pv, jnp.int32(1), tables,
-                                          walk, interpret=True), np.float32)
-    want = np.asarray(xla_path(q, pk, pv, 1, tables, pos), np.float32)
-    np.testing.assert_allclose(out[decoding], want[decoding], rtol=0,
-                               atol=TOL[storage])
     assert np.all(out[~decoding] == 0.0)
 
 
@@ -146,24 +139,36 @@ def test_a_mid_prefill_slot_reads_no_block(monkeypatch):
     assert np.all(out[1] == 0.0)
 
 
-def test_plan_repeats_the_last_live_block_through_the_dead_steps():
-    tables = jnp.asarray([[11, 12, 13], [21, 22, 23], [31, 32, 33],
-                          [41, 42, 43], [51, 52, 53]], jnp.int32)
-    lengths = jnp.asarray([0, 17, 1, 0, 48], jnp.int32)
-    got_lengths, fetch, col_pos = pa.plan(tables, lengths, 16, 2)
+def test_plan_lists_the_live_chunks_slot_by_slot():
+    lengths = jnp.asarray([0, 17, 1, 0, 48, 100], jnp.int32)
+    got_lengths, nblk, slot, chunk, n_items, col_pos = pa.plan(
+        lengths, 7, 16, 2, 1, 3)
     assert got_lengths.tolist() == lengths.tolist()
-    # before the first live step the pipeline's first fetch is all there is
-    assert fetch.tolist() == [[11, 11, 11], [21, 22, 22], [31, 31, 31],
-                              [31, 31, 31], [51, 52, 53]]
-    # so the blocks fetched, step by step, are the live ones and one more
-    flat = fetch.reshape(-1).tolist()
-    fetched = [b for a, b in zip([None] + flat, flat) if a != b]
-    assert fetched == [11, 21, 22, 31, 51, 52, 53]
+    assert nblk.tolist() == [0, 2, 1, 0, 3, 7]
+    # one item for each chunk of 3 live blocks, none for a slot of length 0
+    assert int(n_items[0]) == 1 + 1 + 1 + 3
+    assert slot.shape == chunk.shape == (6 * 3,)
+    n = int(n_items[0])
+    assert list(zip(slot[:n].tolist(), chunk[:n].tolist())) == [
+        (1, 0), (2, 0), (4, 0), (5, 0), (5, 1), (5, 2)]
     # column t * H + head is position t in row ``head`` alone
     col_pos = np.asarray(col_pos)
-    assert col_pos.shape == (2, 16 * 2)
+    assert col_pos.shape == (2, 3 * 16 * 2)
     assert col_pos[0, :4].tolist() == [0, pa._OTHER_HEAD, 1, pa._OTHER_HEAD]
     assert col_pos[1, :4].tolist() == [pa._OTHER_HEAD, 0, pa._OTHER_HEAD, 1]
+    # no slot decoding: no item
+    assert int(pa.plan(jnp.zeros(4, jnp.int32), 7, 16, 2, 1, 3)[4][0]) == 0
+
+
+@pytest.mark.parametrize("block_len,width,chunk", [
+    (16, 128, 16),      # the 7B cells: 256 positions of 2048
+    (16, 64, 16),       # Falcon-H1: 256 of 1024
+    (16, 8, 8),         # capped at the table's width
+    (4, 10, 10),
+    (512, 4, 1),        # blocks of more than 256 positions: at least one
+])
+def test_a_chunk_is_256_positions(block_len, width, chunk):
+    assert pa.chunk_blocks(block_len, width) == chunk
 
 
 @pytest.mark.parametrize("t,h,dh,dtype,backend,impl", [

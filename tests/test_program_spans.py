@@ -91,7 +91,7 @@ SERVE_SPANS = {
     "engine.decode.stage": ("engine.step", set()),
     "engine.decode.dispatch": ("engine.step",
                                {"dispatch", "active", "live_positions",
-                                "gathered_positions"}),
+                                "gathered_positions", "walk_items"}),
     "engine.decode.fetch": ("engine.step", set()),
     "engine.decode.book": ("engine.step", {"emitted"}),
     "serve.emit": ("serve.tick", {"tokens", "retired"}),
@@ -192,6 +192,7 @@ def test_dispatch_counters_give_what_the_slot_state_ledger_reckons(served):
                     1, TINY.num_heads, TINY.head_dim,
                     jnp.dtype(TINY.dtype))["impl"] == "xla"
                 assert c["gathered_positions"] == 3 * PAGED.max_seq_len
+                assert c["walk_items"] == 0
         rows.append((tokens, context))
     reckoned = [(r["tokens_processed"], r["context_sum"])
                 for r in ledger.rows]

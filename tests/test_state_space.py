@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax import lax
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path[:0] = [os.path.join(REPO, "benchmarks")]
@@ -341,7 +340,7 @@ def test_the_engine_is_built_where_the_backend_is_a_tpu(weights,
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     engine = eng.Engine(weights, description(), PAGED, 2, prefill_chunk=CHUNK)
     assert engine._chunk_key_block == 0
-    assert not engine._decode_reads_live_blocks     # heads of 8: no kernel
+    assert engine._walk_chunk == 0                  # heads of 8: no kernel
     published = eng.paged_attention_path(1, 4, 128, jnp.dtype("bfloat16"), 16)
     assert published == {"impl": "pallas", "interpret": False}
 
@@ -382,8 +381,10 @@ def test_grouped_kernel_against_the_gather():
     tables = jnp.asarray(1 + rng.permutation(s * width).reshape(s, width),
                          jnp.int32)
     lengths = jnp.asarray([0, 1, 32, 55], jnp.int32)
-    walk = pa.plan(tables, lengths, bl, h, hq // h)
-    got = pa.paged_attention(q, pk, pv, jnp.int32(1), walk, interpret=True)
+    walk = pa.plan(lengths, width, bl, h, hq // h,
+                   pa.chunk_blocks(bl, width))
+    got = pa.paged_attention(q, pk, pv, jnp.int32(1), tables, walk,
+                             interpret=True)
     ck = pk[1, tables].reshape(s, -1, h, dh)
     cv = pv[1, tables].reshape(s, -1, h, dh)
     want = eng._attend_grouped(q[:, None], ck, cv,
@@ -413,43 +414,24 @@ def test_engine_with_the_grouped_kernel_serves_what_the_gather_serves(
 
     monkeypatch.setattr(eng, "paged_attention_path", kernel_path)
     engine = eng.Engine(weights, desc, PAGED, 3, prefill_chunk=CHUNK)
-    assert engine._decode_reads_live_blocks
+    assert engine._walk_chunk == PAGED.max_blocks_per_seq     # 64 positions
     assert served(engine, requests) == want
 
 
-def test_a_group_of_one_lowers_to_what_it_did():
-    """``head_columns`` and ``plan`` as they stood before the kernel had
-    groups, word for word: with as many key/value heads as query heads the
-    kernel's call lowers to the same text with them as with today's."""
+def test_a_group_of_one_is_the_dense_columns():
+    """``grouped_columns`` with one query head a key/value head is
+    ``head_columns`` as the dense kernel had it before groups, word for
+    word: the dense cells' masking constant is what it was."""
     def head_columns(positions, h):
         cols = jnp.arange(positions * h, dtype=jnp.int32)
         return jnp.where(cols[None, :] % h == jnp.arange(h)[:, None],
                          cols[None, :] // h, pa._OTHER_HEAD).astype(jnp.int32)
 
-    def plan(tables, lengths, block_len, h):
-        s, width = tables.shape
-        lengths = lengths.astype(jnp.int32)
-        live = (jnp.arange(width, dtype=jnp.int32)[None, :] * block_len
-                < lengths[:, None]).reshape(-1)
-        steps = jnp.arange(s * width, dtype=jnp.int32)
-        last_live = lax.cummax(jnp.where(live, steps, 0))
-        fetch = tables.astype(jnp.int32).reshape(-1)[last_live]
-        return lengths, fetch.reshape(s, width), head_columns(block_len, h)
-
-    s, h, dh, bl, width = 3, 8, 128, 16, 4
-    args = (jnp.zeros((s, h, dh), jnp.float32),
-            jnp.zeros((2, 13, bl, h, dh), jnp.float32),
-            jnp.zeros((2, 13, bl, h, dh), jnp.float32),
-            jnp.zeros((s, width), jnp.int32), jnp.zeros((s,), jnp.int32))
-
-    def call(plan_fn):
-        def paged_attention(q, pk, pv, tables, lengths):
-            return pa.paged_attention(q, pk, pv, jnp.int32(1),
-                                      plan_fn(tables, lengths, bl, h),
-                                      interpret=True)
-        return jax.jit(paged_attention).lower(*args).as_text()
-
-    assert call(pa.plan) == call(plan)
+    for positions, h in ((16, 8), (128, 32), (48, 4)):
+        want = np.asarray(head_columns(positions, h))
+        assert np.array_equal(np.asarray(pa.grouped_columns(positions, h, 1)),
+                              want)
+        assert np.array_equal(np.asarray(pa.head_columns(positions, h)), want)
 
 
 # -------------------------------------------- (e) the configuration's file
