@@ -146,7 +146,9 @@ class ExpertLayer:
     """A layer of routed experts beside ``n_shared`` shared ones, as ONE chip
     of an expert-parallel deployment sees it: the router scores all
     ``n_experts`` (its published width), and this chip holds the contiguous
-    range ``[held_start, held_start + held_count)`` of them."""
+    range ``[held_start, held_start + held_count)`` of them. ``norm_eps``
+    is added to the sum the chosen weights are divided by. A selection bias
+    is the layer's weights' (``expert_bias``, ``models/experts.py``)."""
 
     n_experts: int
     top_k: int
@@ -156,6 +158,7 @@ class ExpertLayer:
     norm_topk: bool
     held_start: int
     held_count: int
+    norm_eps: float = 0.0
 
     def __post_init__(self):
         if not (0 <= self.held_start
@@ -202,6 +205,16 @@ class StateSpaceMixer:
 
 
 @dataclass(frozen=True)
+class ShortConv:
+    """A gated short convolution as ``models/short_conv.py`` computes it:
+    ``[B | C | x] = u W_in``, ``y = C * conv(B * x)`` over ``taps`` positions
+    a channel (no bias, no activation), then ``W_out``. What a slot carries
+    is the last ``taps - 1`` values of ``B * x``."""
+
+    taps: int
+
+
+@dataclass(frozen=True)
 class Multipliers:
     """The muP multipliers of a ``falcon_h1`` config, by its keys; ``ssm``
     scales the parts of the mixer's projection (z, x, B, C, dt in that
@@ -228,8 +241,13 @@ class ModelDescription:
     then the SwiGLU), one attention kind for all layers (``attention``
     None: K and V per head, ``kv_heads`` of them (None: as many as query
     heads) of ``head_size`` (None: ``dmodel / num_heads``); else latent),
-    and the sizes that follow. ``LlamaConfig`` models are described by
-    ``describe``; a published ``config.json`` by ``from_published``."""
+    and the sizes that follow. ``mixers`` (None: attention in every layer)
+    gives each layer of kind ``dense`` or ``experts`` its own mixer,
+    ``"attention"`` (K and V per head, with a norm over each query and key
+    head before RoPE) or ``"conv"`` (``conv``, a short convolution);
+    ``tie_embeddings``: the head is the embedding's transpose.
+    ``LlamaConfig`` models are described by ``describe``; a published
+    ``config.json`` by ``from_published``."""
 
     vocab_size: int
     dmodel: int
@@ -248,6 +266,9 @@ class ModelDescription:
     head_size: Optional[int] = None
     mixer: Optional[StateSpaceMixer] = None
     multipliers: Optional[Multipliers] = None
+    mixers: Optional[Tuple[str, ...]] = None
+    conv: Optional[ShortConv] = None
+    tie_embeddings: bool = False
 
     def __post_init__(self):
         bad = set(self.layer_kinds) - {"dense", "experts", "parallel"}
@@ -262,6 +283,17 @@ class ModelDescription:
                          or self.attention is not None)):
             raise ValueError("a state-space mixer stands beside K and V per "
                              "head in every layer, all of kind 'parallel'")
+        if self.mixers is not None and (
+                len(self.mixers) != self.n_layers
+                or not set(self.mixers) <= {"attention", "conv"}
+                or ("conv" in self.mixers) != (self.conv is not None)
+                or "parallel" in self.layer_kinds
+                or self.attention is not None):
+            raise ValueError("a mixer a layer, 'attention' (K and V per "
+                             "head) or 'conv' (with a ShortConv), beside "
+                             "layers of kind 'dense' or 'experts'")
+        if self.mixers is None and self.conv is not None:
+            raise ValueError("a short convolution without mixers a layer")
         if self.num_heads % self.num_kv_heads:
             raise ValueError(f"{self.num_heads} query heads over "
                              f"{self.num_kv_heads} key/value heads")
@@ -285,8 +317,38 @@ class ModelDescription:
         of ``dmodel / num_heads`` and dense layers only: what
         ``LlamaConfig`` states, and the engine's original path."""
         return (self.attention is None and set(self.layer_kinds) == {"dense"}
+                and self.mixers is None
                 and self.num_kv_heads == self.num_heads
                 and self.head_dim * self.num_heads == self.dmodel)
+
+    @property
+    def carries_state(self) -> bool:
+        """Whether a slot carries a second kind of cache beside the pool
+        (``kvcache.init_state``): a state-space mixer or a short
+        convolution."""
+        return self.mixer is not None or self.conv is not None
+
+    def count_mixer(self, mixer: str, upto: Optional[int] = None) -> int:
+        """Layers before ``upto`` (all: None) whose mixer is ``mixer``
+        (``mixers``; without it every layer attends)."""
+        mixers = self.mixers or ("attention",) * self.n_layers
+        return mixers[:upto].count(mixer)
+
+    def periods(self) -> Tuple[Tuple[str, Tuple[str, ...], int, int], ...]:
+        """Where the mixers differ from layer to layer, the layers as the
+        engine scans them: each run of one kind (``runs``) as whole periods
+        of its mixers' pattern, then what is left of one. (kind, the mixers
+        of a period, periods, first layer) a scan."""
+        out = []
+        for kind, start, count in self.runs():
+            mix = self.mixers[start:start + count]
+            p = next(p for p in range(1, count + 1)
+                     if all(mix[i] == mix[i % p] for i in range(count)))
+            out.append((kind, mix[:p], count // p, start))
+            if count % p:
+                out.append((kind, mix[:count % p], 1,
+                            start + count - count % p))
+        return tuple(out)
 
     @property
     def cache_row(self) -> int:
@@ -320,6 +382,8 @@ class ModelDescription:
         kinds = ("dense",) * n
         if cfg.get("model_type") == "falcon_h1":
             return cls._falcon_h1(cfg, ctx_size, dtype, param_dtype)
+        if cfg.get("model_type") == "lfm2_moe":
+            return cls._lfm2_moe(cfg, ctx_size, dtype, param_dtype)
         if "kv_lora_rank" in cfg:
             rs = cfg.get("rope_scaling") or {}
             attention = LatentAttention(
@@ -337,9 +401,8 @@ class ModelDescription:
                 mscale_all_dim=float(rs.get("mscale_all_dim", 0)))
         elif cfg.get("num_key_value_heads",
                      cfg["num_attention_heads"]) != cfg["num_attention_heads"]:
-            raise ValueError("grouped K/V heads: the engine has them beside "
-                             "a state-space mixer only (model_type "
-                             "falcon_h1)")
+            raise ValueError("grouped K/V heads: the engine has them in "
+                             "model_type falcon_h1 and lfm2_moe only")
         if cfg.get("n_routed_experts"):
             held = int(cfg["n_routed_experts"])
             experts = ExpertLayer(
@@ -415,6 +478,51 @@ class ModelDescription:
                    kv_heads=int(cfg["num_key_value_heads"]),
                    head_size=int(cfg["head_dim"]), mixer=mixer,
                    multipliers=mult)
+
+    @classmethod
+    def _lfm2_moe(cls, cfg: dict, ctx_size: int, dtype: str,
+                  param_dtype: str) -> "ModelDescription":
+        """``model_type`` ``lfm2_moe``: each layer's mixer by
+        ``layer_types`` (its first ``num_hidden_layers`` entries), a gated
+        short convolution or grouped-query attention with a norm over each
+        query and key head; ``num_dense_layers`` SwiGLU layers, then routed
+        experts chosen through a selection bias (``use_expert_bias``), none
+        shared. The head is tied to the embedding unless the file says
+        ``tie_word_embeddings`` false."""
+        n = int(cfg["num_hidden_layers"])
+        types = tuple(cfg["layer_types"][:n])
+        rope = cfg.get("rope_parameters") or {}
+        if (len(types) != n or cfg.get("conv_bias")
+                or rope.get("rope_type", "default") != "default"
+                or not set(types) <= {"conv", "full_attention"}):
+            raise ValueError(
+                "lfm2_moe as the engine has it: a layer type a layer, "
+                "'conv' or 'full_attention', no convolution bias, plain RoPE")
+        first = int(cfg["num_dense_layers"])
+        n_experts = int(cfg["num_experts"])
+        experts = ExpertLayer(
+            n_experts=n_experts, top_k=int(cfg["num_experts_per_tok"]),
+            width=int(cfg["moe_intermediate_size"]), n_shared=0,
+            scale=float(cfg.get("routed_scaling_factor", 1.0)),
+            norm_topk=bool(cfg.get("norm_topk_prob", False)), held_start=0,
+            held_count=n_experts, norm_eps=1e-6)
+        return cls(vocab_size=int(cfg["vocab_size"]),
+                   dmodel=int(cfg["hidden_size"]),
+                   num_heads=int(cfg["num_attention_heads"]),
+                   layer_kinds=tuple("dense" if i < first else "experts"
+                                     for i in range(n)),
+                   ffn_hidden=int(cfg["intermediate_size"]),
+                   norm_eps=float(cfg["norm_eps"]),
+                   rope_theta=float(rope["rope_theta"]),
+                   ctx_size=ctx_size, dtype=dtype, param_dtype=param_dtype,
+                   experts=experts if first < n else None,
+                   init_std=float(cfg.get("initializer_range", 0.02)),
+                   kv_heads=int(cfg["num_key_value_heads"]),
+                   mixers=tuple("conv" if t == "conv" else "attention"
+                                for t in types),
+                   conv=(ShortConv(taps=int(cfg["conv_L_cache"]))
+                         if "conv" in types else None),
+                   tie_embeddings=bool(cfg.get("tie_word_embeddings", True)))
 
 
 def describe(cfg) -> ModelDescription:

@@ -36,17 +36,25 @@ from ..config import ExpertLayer
 STATS = ("pairs_held", "experts_hit", "max_pairs")
 
 
-def route(w_r: jnp.ndarray, x: jnp.ndarray, spec: ExpertLayer):
+def route(w_r: jnp.ndarray, x: jnp.ndarray, spec: ExpertLayer,
+          bias: jnp.ndarray = None):
     """x [N, D] -> (expert ids [N, k] int32, weights [N, k] float32).
-    Sigmoid scores over all experts in float32, plain top-k over all of
-    them (``topk_method`` "none": no groups, no correction bias), the chosen
-    scores normalised to sum 1 (``norm_topk``) and scaled."""
+    Sigmoid scores over all experts in float32, top-k over all of them (no
+    groups), the chosen scores normalised to sum 1 (``norm_topk``, plus
+    ``spec.norm_eps`` in the divisor) and scaled. ``bias`` [n_experts] (a
+    selection bias) is added to the scores that choose and to none that
+    weigh; without it the top-k is plain (``topk_method`` "none")."""
     scores = jax.nn.sigmoid(jnp.dot(
         x.astype(jnp.float32), w_r.astype(jnp.float32),
         precision=lax.Precision.HIGHEST))
-    top, idx = lax.top_k(scores, spec.top_k)
+    if bias is None:
+        top, idx = lax.top_k(scores, spec.top_k)
+    else:
+        _, idx = lax.top_k(scores + bias.astype(jnp.float32), spec.top_k)
+        top = jnp.take_along_axis(scores, idx, axis=-1)
     if spec.norm_topk:
-        top = top / jnp.sum(top, axis=-1, keepdims=True)
+        total = jnp.sum(top, axis=-1, keepdims=True)
+        top = top / (total + spec.norm_eps if spec.norm_eps else total)
     return idx.astype(jnp.int32), top * spec.scale
 
 
@@ -59,7 +67,9 @@ def swiglu(x: jnp.ndarray, w_gu: jnp.ndarray, w_down: jnp.ndarray):
 def expert_layer(block: dict, x: jnp.ndarray, spec: ExpertLayer,
                  valid: jnp.ndarray = None, group_offset=None):
     """x [N, D] -> (y [N, D], stats int32 [3]): the held experts' part of
-    ``sum_e w_e SwiGLU_e(x)`` plus the shared expert. ``valid`` [N] marks
+    ``sum_e w_e SwiGLU_e(x)`` plus the shared experts (none: nothing is
+    computed for them). ``block["expert_bias"]``, where the layer's weights
+    have one, is ``route``'s selection bias. ``valid`` [N] marks
     the rows that are tokens (padding and idle slots route nowhere and are
     not counted). ``stats`` is ``STATS``: the pairs computed here, the held
     experts with at least one, the most any one took.
@@ -74,7 +84,7 @@ def expert_layer(block: dict, x: jnp.ndarray, spec: ExpertLayer,
     n, d = x.shape
     k, held = spec.top_k, spec.held_count
     with jax.named_scope("moe.router"):
-        idx, w = route(block["w_r"], x, spec)
+        idx, w = route(block["w_r"], x, spec, block.get("expert_bias"))
         local = idx - spec.held_start
         here = (local >= 0) & (local < held)
         if valid is not None:
@@ -93,8 +103,10 @@ def expert_layer(block: dict, x: jnp.ndarray, spec: ExpertLayer,
         f = gu.shape[-1] // 2
         act = jax.nn.silu(gu[:, :f]) * gu[:, f:]
         out = lax.ragged_dot(act, block["we_down"].astype(x.dtype), groups)
-    with jax.named_scope("moe.shared"):
-        shared = swiglu(x, block["ws_gu"], block["ws_down"])
+    shared = None
+    if spec.n_shared:
+        with jax.named_scope("moe.shared"):
+            shared = swiglu(x, block["ws_gu"], block["ws_down"])
     with jax.named_scope("moe.combine"):
         # back to (token, choice) order; rows behind the last group hold
         # nothing that was computed, and weigh nothing
@@ -103,7 +115,9 @@ def expert_layer(block: dict, x: jnp.ndarray, spec: ExpertLayer,
         routed = jnp.sum(jnp.where(here[..., None],
                                    out.astype(jnp.float32), 0.0)
                          * wk[..., None], axis=1)
-        y = routed.astype(x.dtype) + shared
+        y = routed.astype(x.dtype)
+        if shared is not None:
+            y = y + shared
     stats = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0, dtype=jnp.int32),
                        jnp.max(sizes)])
     return y, stats

@@ -234,7 +234,33 @@ def paged_attention(q: jnp.ndarray, pk: jnp.ndarray, pv: jnp.ndarray,
     the positions each slot attends to (its own, just written, among them;
     0 for a slot that is not decoding); query head ``i`` reads key/value
     head ``i // (Hq // H)``. Returns [S, Hq, Dh] in q's dtype; zeros for a
-    slot of length 0."""
+    slot of length 0.
+
+    Heads narrower than the pool's rows (``Dh < pk.shape[-1]``: heads of 64
+    two to a row of 128, ``serving.kvcache.kv_lanes``, so that ``H`` counts
+    rows and ``walk`` is planned over them) are read as the rows lie: each
+    query head is laid in its key/value head's place of a row, zeros in the
+    rest, so that its product with the row is its product with that head
+    alone, and keeps that place of the row's weighted sum."""
+    s, hq, dh = q.shape
+    lanes = pk.shape[-1]
+    scale = 1.0 / math.sqrt(dh)
+    if dh == lanes:
+        return _paged_attention(q, pk, pv, layer, tables, walk, scale,
+                                interpret)
+    per_row = lanes // dh
+    place = (jnp.arange(hq) // (hq // (pk.shape[3] * per_row))) % per_row
+    here = place[:, None] == jnp.arange(per_row)[None, :]     # [Hq, per_row]
+    wide = jnp.where(here[None, :, :, None], q[:, :, None, :],
+                     jnp.zeros((), q.dtype)).reshape(s, hq, lanes)
+    out = _paged_attention(wide, pk, pv, layer, tables, walk, scale,
+                           interpret).reshape(s, hq, per_row, dh)
+    return jnp.take_along_axis(out, place[None, :, None, None], axis=2)[:, :, 0]
+
+
+def _paged_attention(q, pk, pv, layer, tables, walk, scale: float,
+                     interpret: bool | None):
+    """``paged_attention`` of queries as wide as the pool's rows."""
     s, hq, dh = q.shape
     block_len, h = pk.shape[2], pk.shape[3]
     lengths, nblk, slot, chunk, n_items, col_pos = walk
@@ -245,7 +271,7 @@ def paged_attention(q: jnp.ndarray, pk: jnp.ndarray, pv: jnp.ndarray,
     whole = lambda shape: pl.BlockSpec(  # noqa: E731
         shape, lambda i, *_: (0,) * len(shape))
     kernel = functools.partial(_kernel, chunk=blocks, block_len=block_len,
-                               scale=1.0 / math.sqrt(dh))
+                               scale=scale)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(

@@ -36,6 +36,12 @@ through ``_forward_parallel``: the same pool of K and V, of the key/value
 heads only, and beside it in the same donated tree a state store of one row a
 SLOT (``kvcache.init_state``), which a prefill chunk carries on for its slot
 and a decode step for every decoding slot (tests/test_state_space.py).
+A ``ModelDescription`` with a mixer a layer (``mixers``: short convolutions,
+``models/short_conv.py``, beside grouped-query attention layers) goes through
+``_forward_hybrid``: one scan a group of whole periods of the layer pattern,
+a pool of the attention layers alone and a store of the conv layers' carried
+inputs, each indexed by the layer's place among the layers of its mixer
+(tests/test_conv_experts.py).
 The engine keeps ONE copy of each weight, in the layout its programs read.
 
 Each is compiled exactly once per engine (static shapes: every dispatch
@@ -248,9 +254,10 @@ def _block_paged(block: dict, layer: jnp.ndarray, pk: jnp.ndarray,
     # targets are guaranteed by block ownership; only TRASH_BLOCK collides
     # (inactive slots, padded tails; each layer has its own) and its
     # contents are never read un-masked.
+    lanes = (s, t) + pk.shape[3:]           # ``kvcache.kv_lanes``
     with jax.named_scope("paged.write"):
-        pk = pk.at[layer, wblk, woff].set(k.astype(pk.dtype))
-        pv = pv.at[layer, wblk, woff].set(v.astype(pv.dtype))
+        pk = pk.at[layer, wblk, woff].set(k.reshape(lanes).astype(pk.dtype))
+        pv = pv.at[layer, wblk, woff].set(v.reshape(lanes).astype(pv.dtype))
     if attend is not None:
         with jax.named_scope("paged.attend"):
             out = attend(q, pk, pv, layer)
@@ -272,17 +279,20 @@ def _block_paged(block: dict, layer: jnp.ndarray, pk: jnp.ndarray,
 
 def _kernel_attention(pool: dict, tables: jnp.ndarray,
                       positions: jnp.ndarray, valid: Optional[jnp.ndarray],
-                      group: int = 1):
+                      heads: int):
     """``_block_paged``'s ``attend`` where ``paged_attention_path`` names
     the kernel for this program, else None. What the kernel walks is the
     same for every layer, so it is reckoned here, once, outside the layer
     scan (``ops.paged_attention.plan``): a slot attends to the position it
     has just written and all before it; one that is not decoding (``valid``
     [S, 1] False: it wrote to trash) has no work item and reads nothing.
-    ``group`` query heads read each of the pool's key/value heads
-    (``_block_parallel``; the path function is then asked with the block's
-    length, ``ops.paged_attention.supported``)."""
+    ``heads`` query heads read the pool's rows, ``heads / rows`` of them
+    each: grouped key/value heads (``_block_parallel``), or heads narrower
+    than a row, laid side by side in it (``kvcache.kv_lanes``); where that
+    group is over one the path function is asked with the block's length
+    (``ops.paged_attention.supported``)."""
     pk = pool["k"]
+    group = heads // pk.shape[3]
     grouped = () if group == 1 else (pk.shape[2],)
     path = paged_attention_path(positions.shape[1], *pk.shape[3:], pk.dtype,
                                 *grouped)
@@ -446,6 +456,28 @@ def _attend_grouped(q: jnp.ndarray, ck: jnp.ndarray, cv: jnp.ndarray,
         s, tq, hq, dh)
 
 
+def _slot_rows(layer, slot, positions: jnp.ndarray):
+    """(take, put) of this call's rows of a store [L, num_slots, ...] at
+    layer ``layer`` (``kvcache.init_state``): every slot's (``slot`` None: a
+    decode step), or one slot's, read as zeros where the chunk is its
+    request's first (``positions[0, 0] == 0``) whatever the slot held."""
+    if slot is None:
+        take = lambda a: a[layer]                           # noqa: E731
+        put = lambda a, rows: a.at[layer].set(rows)         # noqa: E731
+        return take, put
+    fresh = positions[0, 0] == 0
+
+    def take(a):
+        rows = lax.dynamic_slice(
+            a, (layer, slot) + (0,) * (a.ndim - 2), (1, 1) + a.shape[2:])
+        return jnp.where(fresh, jnp.zeros_like(rows[0]), rows[0])
+
+    def put(a, rows):
+        return lax.dynamic_update_slice(
+            a, rows[None], (layer, slot) + (0,) * (a.ndim - 2))
+    return take, put
+
+
 def _block_parallel(block: dict, layer: jnp.ndarray, pool: dict,
                     x: jnp.ndarray, positions: jnp.ndarray,
                     tables: jnp.ndarray, wblk: jnp.ndarray,
@@ -484,9 +516,10 @@ def _block_parallel(block: dict, layer: jnp.ndarray, pool: dict,
         sin = sin.reshape(s, t, -1)
         q = _apply_rope_slots(q, cos, sin)
         k = _apply_rope_slots(k, cos, sin)
+    lanes = (s, t) + pk.shape[3:]           # ``kvcache.kv_lanes``
     with jax.named_scope("paged.write"):
-        pk = pk.at[layer, wblk, woff].set(k.astype(pk.dtype))
-        pv = pv.at[layer, wblk, woff].set(v.astype(pv.dtype))
+        pk = pk.at[layer, wblk, woff].set(k.reshape(lanes).astype(pk.dtype))
+        pv = pv.at[layer, wblk, woff].set(v.reshape(lanes).astype(pv.dtype))
     if attend is not None:
         with jax.named_scope("paged.attend"):
             out = attend(q, pk, pv, layer)
@@ -499,22 +532,7 @@ def _block_parallel(block: dict, layer: jnp.ndarray, pool: dict,
     with jax.named_scope("attn_out"):
         att = (out.reshape(s, t, hq * dh) @ block["w_o"].astype(x.dtype)
                ) * jnp.asarray(m.attention_out, x.dtype)
-    # this call's rows of the state store: every slot's (a decode step), or
-    # one slot's, from zeros where the chunk is its request's first
-    if slot is None:
-        take = lambda a: a[layer]                           # noqa: E731
-        put = lambda a, rows: a.at[layer].set(rows)         # noqa: E731
-    else:
-        fresh = positions[0, 0] == 0
-
-        def take(a):
-            rows = lax.dynamic_slice(
-                a, (layer, slot) + (0,) * (a.ndim - 2), (1, 1) + a.shape[2:])
-            return jnp.where(fresh, jnp.zeros_like(rows[0]), rows[0])
-
-        def put(a, rows):
-            return lax.dynamic_update_slice(
-                a, rows[None], (layer, slot) + (0,) * (a.ndim - 2))
+    take, put = _slot_rows(layer, slot, positions)
     y, s1, t1 = state_space.mixer(block, u, take(st), take(tl), n_valid, desc)
     with jax.named_scope("ssm.scan"):
         st = put(st, s1)
@@ -544,7 +562,7 @@ def _forward_parallel(head: dict, runs: tuple, tokens: jnp.ndarray,
              * jnp.asarray(desc.multipliers.embedding, jnp.dtype(desc.dtype)))
     n_valid = jnp.sum(valid, axis=1).astype(jnp.int32)
     attend = _kernel_attention(pool, tables, positions, valid,
-                               desc.num_heads // desc.num_kv_heads)
+                               desc.num_heads)
     layers = jnp.arange(desc.n_layers, dtype=jnp.int32)
 
     def body(carry, layer_block):
@@ -558,11 +576,154 @@ def _forward_parallel(head: dict, runs: tuple, tokens: jnp.ndarray,
     return h, pool, None
 
 
+def _attention_layer(block: dict, ordinal, pool: dict, x: jnp.ndarray,
+                     positions: jnp.ndarray, tables: jnp.ndarray,
+                     wblk: jnp.ndarray, woff: jnp.ndarray,
+                     desc: ModelDescription, attend=None):
+    """The mixer of a layer whose mixer is ``attention`` in a model with a
+    mixer a layer (``_forward_hybrid``): ``x + W_o Attn(norm(x))``, query
+    head ``i`` reading key/value head ``i // (Hq / H)``, each query and key
+    head normed over its values (``q_norm``, ``k_norm``) before the
+    rotation.
+    K and V go to the pool's layer ``ordinal``, the layer's place among the
+    attention layers, as ``kvcache.kv_lanes`` lays them; ``attend``:
+    ``_kernel_attention``'s, else the gather and ``_attend_grouped``.
+    Returns (x, pool)."""
+    s, t, _ = x.shape
+    hq, h, dh = desc.num_heads, desc.num_kv_heads, desc.head_dim
+    pk, pv = pool["k"], pool["v"]
+    with jax.named_scope("qkv"):
+        u = nn.rmsnorm(block["op_norm"], x, eps=desc.norm_eps)
+        qkv = u @ block["w_qkv"].astype(x.dtype)
+        q = qkv[..., :hq * dh].reshape(s, t, hq, dh)
+        k = qkv[..., hq * dh:(hq + h) * dh].reshape(s, t, h, dh)
+        v = qkv[..., (hq + h) * dh:].reshape(s, t, h, dh)
+        q = nn.rmsnorm(block["q_norm"], q, eps=desc.norm_eps)
+        k = nn.rmsnorm(block["k_norm"], k, eps=desc.norm_eps)
+        cos, sin = llama.rope_angles(positions.reshape(-1), dh,
+                                     desc.rope_theta)
+        cos = cos.reshape(s, t, -1)
+        sin = sin.reshape(s, t, -1)
+        q = _apply_rope_slots(q, cos, sin)
+        k = _apply_rope_slots(k, cos, sin)
+    lanes = (s, t) + pk.shape[3:]           # ``kvcache.kv_lanes``
+    with jax.named_scope("paged.write"):
+        pk = pk.at[ordinal, wblk, woff].set(k.reshape(lanes).astype(pk.dtype))
+        pv = pv.at[ordinal, wblk, woff].set(v.reshape(lanes).astype(pv.dtype))
+    if attend is not None:
+        with jax.named_scope("paged.attend"):
+            out = attend(q, pk, pv, ordinal)
+    else:
+        with jax.named_scope("paged.gather"):
+            ck = pk[ordinal, tables].reshape(s, -1, h, dh)
+            cv = pv[ordinal, tables].reshape(s, -1, h, dh)
+        with jax.named_scope("paged.attend"):
+            out = _attend_grouped(q, ck, cv, positions)
+    with jax.named_scope("attn_out"):
+        x = x + out.reshape(s, t, hq * dh) @ block["w_o"].astype(x.dtype)
+    return x, dict(pool, k=pk, v=pv)
+
+
+def _conv_layer(block: dict, ordinal, pool: dict, x: jnp.ndarray,
+                positions: jnp.ndarray, n_valid: jnp.ndarray, slot,
+                desc: ModelDescription):
+    """The mixer of a layer whose mixer is ``conv``: ``x + Conv(norm(x))``
+    (``models/short_conv.py``), carrying the tail of the store's layer
+    ``ordinal``, the layer's place among the conv layers, of every slot (a
+    decode step) or of ``slot`` (a prefill chunk; a request's first starts
+    from zeros). Returns (x, pool)."""
+    from ..models import short_conv
+
+    take, put = _slot_rows(ordinal, slot, positions)
+    with jax.named_scope("conv.proj"):
+        u = nn.rmsnorm(block["op_norm"], x, eps=desc.norm_eps)
+    y, tail = short_conv.mixer(block, u, take(pool["tail"]), n_valid)
+    with jax.named_scope("conv.taps"):
+        tl = put(pool["tail"], tail)
+    with jax.named_scope("conv.out"):
+        x = x + y
+    return x, dict(pool, tail=tl)
+
+
+def _forward_hybrid(head: dict, runs: tuple, tokens: jnp.ndarray,
+                    pool: dict, tables, positions, wblk, woff, valid, slot,
+                    desc: ModelDescription):
+    """``_forward_paged`` for a model with a mixer a layer
+    (``ModelDescription.mixers``): one lax.scan a group of
+    ``ModelDescription.periods`` (the leading dense layers; then whole
+    periods of the pattern, its layers written out in the body, so that the
+    program does not grow with depth), the hidden state, the pool of the
+    attention layers and the store of the conv layers' tails its carry.
+    Each layer reads the pool, or the store, at its place among the layers
+    of its mixer; the feed-forward half is ``latent.second_half`` (the
+    SwiGLU, or ``experts.expert_layer`` with each layer's experts named by
+    offset in the group's stack, as ``_forward_described`` has them). The
+    routing stats of the expert layers are the stacked result [layers, 3]."""
+    from ..models import latent
+
+    with jax.named_scope("embed"):
+        h = head["embed"][tokens].astype(jnp.dtype(desc.dtype))
+    n_valid = jnp.sum(valid, axis=1).astype(jnp.int32)
+    attend = None
+    if "k" in pool:
+        attend = _kernel_attention(pool, tables, positions, valid,
+                                   desc.num_heads)
+    stats = []
+    for (kind, pattern, repeats, first), blocks in zip(desc.periods(), runs):
+        held = desc.experts.held_count if kind == "experts" else 0
+        # each layer's routed experts stay out of the scanned tree, the
+        # group's periods side by side as one stack of groups
+        whole = [{k: b[k].reshape((-1,) + b[k].shape[2:])
+                  for k in ("we_gu", "we_down")} if held else {}
+                 for b in blocks]
+        scanned = tuple({k: v for k, v in b.items() if k not in w}
+                        for b, w in zip(blocks, whole))
+        at = {m: (desc.count_mixer(m, first), pattern.count(m))
+              for m in ("attention", "conv")}
+
+        def body(carry, xs, pattern=pattern, kind=kind, held=held,
+                 whole=whole, at=at):       # traced here, by this scan
+            x, pool = carry
+            r, blks = xs
+            st = []
+            for j, mixer in enumerate(pattern):
+                block = dict(blks[j], **whole[j])
+                start, per = at[mixer]
+                ordinal = start + r * per + pattern[:j].count(mixer)
+                if mixer == "attention":
+                    x, pool = _attention_layer(
+                        block, ordinal, pool, x, positions, tables, wblk,
+                        woff, desc, attend)
+                else:
+                    x, pool = _conv_layer(block, ordinal, pool, x, positions,
+                                          n_valid, slot, desc)
+                x, s = latent.second_half(block, kind, x, desc, valid,
+                                          r * held if held else None)
+                if s is not None:
+                    st.append(s)
+            return (x, pool), (jnp.stack(st) if st else None)
+
+        with jax.named_scope("layers"):
+            (h, pool), st = lax.scan(
+                body, (h, pool), (jnp.arange(repeats, dtype=jnp.int32),
+                                  scanned))
+        if st is not None:
+            stats.append(st.reshape(-1, st.shape[-1]))
+    return h, pool, (jnp.concatenate(stats) if stats else None)
+
+
 def _head(params: dict, h: jnp.ndarray, cfg) -> jnp.ndarray:
     """``llama.head`` times the model's ``lm_head_multiplier``, where it has
-    one."""
+    one; over the embedding's rows where the head is tied to it."""
+    desc = describe(cfg)
+    if desc.tie_embeddings:
+        with jax.named_scope("head"):
+            hn = nn.rmsnorm(params["final_norm"], h, eps=desc.norm_eps)
+            return lax.dot_general(
+                hn, params["embed"].astype(hn.dtype),
+                (((hn.ndim - 1,), (1,)), ((), ()))).astype(jnp.float32)
     logits = llama.head(params, h, cfg)
-    m = describe(cfg).multipliers
+    m = desc.multipliers
     if m is None:
         return logits
     with jax.named_scope("head"):
@@ -578,7 +739,9 @@ def _forward_paged(params: dict, fused_blocks, tokens: jnp.ndarray,
     model's description: a model of other layer kinds than ``LlamaConfig``
     states goes through ``_forward_described`` (``valid`` [S, T] marks its
     real tokens for the expert layers) or, with a state-space mixer,
-    ``_forward_parallel`` (``slot``: the one slot a prefill chunk is of).
+    ``_forward_parallel`` (``slot``: the one slot a prefill chunk is of), or,
+    with a mixer a layer, ``_forward_hybrid`` (the same ``valid`` and
+    ``slot``).
     Else one lax.scan over (layer
     number, fused block) with the hidden state AND the whole stacked pool
     as its carry: under the programs' donation of the pool, argument, loop
@@ -587,6 +750,9 @@ def _forward_paged(params: dict, fused_blocks, tokens: jnp.ndarray,
     stacked inputs and outputs; a pool of gigabytes cannot afford the slice
     out and the write back that costs, every layer of every run.)"""
     desc = describe(cfg)
+    if desc.mixers is not None:
+        return _forward_hybrid(params, fused_blocks, tokens, pool, tables,
+                               positions, wblk, woff, valid, slot, desc)
     if desc.mixer is not None:
         return _forward_parallel(params, fused_blocks, tokens, pool, tables,
                                  positions, wblk, woff, valid, slot, desc)
@@ -595,7 +761,7 @@ def _forward_paged(params: dict, fused_blocks, tokens: jnp.ndarray,
                                   positions, wblk, woff, valid, desc)
     h = llama.embed(params, tokens, cfg)
     layers = jnp.arange(pool["k"].shape[0], dtype=jnp.int32)
-    attend = _kernel_attention(pool, tables, positions, valid)
+    attend = _kernel_attention(pool, tables, positions, valid, desc.num_heads)
 
     def body(carry, layer_block):
         x, pk, pv = carry
@@ -648,8 +814,9 @@ def make_prefill_chunk(cfg: LlamaConfig, paged: PagedKVConfig,
     positions, same weights), so discarding them changes nothing. 0 (the
     non-sharing case) writes everything, byte-for-byte the old program.
 
-    ``slot`` (a model with a state-space mixer only): the slot whose rows
-    of the state store, donated with the pool, the chunk reads and writes."""
+    ``slot`` (a model that carries state, ``ModelDescription.carries_state``,
+    only): the slot whose rows of the state store, donated with the pool, the
+    chunk reads and writes."""
     bl, mb = paged.block_len, paged.max_blocks_per_seq
 
     @partial(jax.jit, donate_argnums=(0,))
@@ -834,11 +1001,12 @@ class Engine:
         # attention, the cache's row, the experts held here.
         self.desc = describe(cfg)
         if not self.desc.plain and (speculate is not None or prefix_share):
-            if self.desc.mixer is not None:
+            if self.desc.carries_state:
                 raise NotImplementedError(
                     "speculation and prefix sharing serve LlamaConfig models "
-                    "only: a slot's recurrent state (kvcache.init_state) is "
-                    "one value for the whole prefix, so it cannot be rolled "
+                    "only: a slot's recurrent state or carried inputs "
+                    "(kvcache.init_state) are "
+                    "one value for the whole prefix, so they cannot be rolled "
                     "back past a rejected draft nor shared by the block "
                     "(ROADMAP.md)")
             raise NotImplementedError(
@@ -869,8 +1037,9 @@ class Engine:
         self.boot = jax.tree.map(LeafSpec, params)
         self._set_weights(params)
         # Two kinds of cache in one donated tree: the paged pool, and for a
-        # model with a state-space mixer the state store (``kvcache.
-        # init_state``: "s" and "tail", a row a slot; else nothing).
+        # model that carries state the state store (``kvcache.init_state``:
+        # "s" and "tail", or a short convolution's "tail", a row a slot; else
+        # nothing).
         self.pool = {**init_pool(cfg, paged), **init_state(cfg, num_slots)}
         self.state_bytes_per_slot = state_bytes_per_slot(cfg)
         # Blocks a work item of the decode program's attention where that is
@@ -880,7 +1049,8 @@ class Engine:
         self._walk_chunk = 0
         if "k" in self.pool and paged_attention_path(
                 1, *self.pool["k"].shape[3:], self.pool["k"].dtype,
-                *(() if mx is None else (paged.block_len,))
+                *(() if self.pool["k"].shape[3] == self.desc.num_heads
+                  else (paged.block_len,))
         )["impl"] == "pallas":
             from ..ops import paged_attention as pa
             self._walk_chunk = pa.chunk_blocks(paged.block_len,
@@ -1243,12 +1413,13 @@ class Engine:
         if bk:
             attended = min(blocks_for(off + n_valid, bk) * bk, attended)
         mx, of_slot = self.desc.mixer, ()
-        if mx is not None:
+        if self.desc.carries_state:
             # the slot whose state the chunk carries on, and the chunks of
             # the scan that hold a real position
             of_slot = (jnp.int32(s),)
-            routed = {"state_slots": 1, "scan_chunks": blocks_for(
-                n_valid, min(mx.chunk, tc))}
+            routed = {**routed, "state_slots": 1}
+        if mx is not None:
+            routed["scan_chunks"] = blocks_for(n_valid, min(mx.chunk, tc))
         with self.spans("engine.prefill.dispatch", slot=s, seq=slot.seq,
                         off=off, n_valid=n_valid, final=int(is_final),
                         attended_positions=attended, **routed):
@@ -1299,9 +1470,9 @@ class Engine:
         a dispatch of ``tq`` query rows a slot."""
         routed = ({"pairs_routed": int(active.sum()) * self._pairs_a_token}
                   if self._pairs_a_token else {})
-        if self.desc.mixer is not None:
+        if self.desc.carries_state:
             # the slots whose state the step reads and writes
-            routed = {"state_slots": int(active.sum())}
+            routed = {**routed, "state_slots": int(active.sum())}
         bl, width = self.paged.block_len, int(tables.shape[1])
         live = self.pos[active].astype(np.int64) + 1
         items = 0

@@ -36,8 +36,10 @@ static-shape/one-compile discipline):
 - A model with a state-space mixer has a second kind of cache beside the
   pool, which no block holds and nothing pages: ``init_state``, a recurrent
   state and the convolution's carried inputs a SLOT a layer, fixed in size
-  whatever the length. Admission reckons blocks for the pool and a slot for
-  the state; the engine keeps both in one donated tree.
+  whatever the length; a model of short convolutions beside attention
+  layers has the convolutions' carried inputs alone, and a pool of its
+  attention layers alone. Admission reckons blocks for the pool and a slot
+  for the state; the engine keeps both in one donated tree.
 
 Sizing math (docs/COMPONENTS.md "Serving" carries the worked example):
 one block holds ``2 · L · block_len · H · Dh · itemsize`` bytes of K+V;
@@ -113,7 +115,9 @@ def init_pool(cfg, paged: PagedKVConfig) -> dict:
     block_len, H, Dh], ``H`` the key/value heads (fewer than the query heads
     where they are grouped). Latent attention: {"c"} [L, num_blocks, block_len,
     row_dim], ONE row a position a layer (``models/latent.py::latent_row``)
-    and nothing per head, in ``row_stride`` lanes. Layer-major like ``init_cache``, but the engine
+    and nothing per head, in ``row_stride`` lanes. K and V lie as
+    ``kv_lanes`` lays them; a model with a mixer a layer has them of its
+    attention layers alone, ``L`` of them (none: no pool). Layer-major like ``init_cache``, but the engine
     does not scan the leading axis: the whole stacked pool is its layer
     scan's carry, and each layer scatters into and gathers from it at
     (layer, block, offset)."""
@@ -122,8 +126,26 @@ def init_pool(cfg, paged: PagedKVConfig) -> dict:
     lead = (desc.n_layers, paged.num_blocks, paged.block_len)
     if desc.attention is not None:
         return {"c": jnp.zeros(lead + (row_stride(desc),), dt)}
-    shape = lead + (desc.num_kv_heads, desc.head_dim)
+    n = desc.count_mixer("attention")
+    if not n:
+        return {}
+    shape = (n,) + lead[1:] + kv_lanes(desc)
     return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+
+
+def kv_lanes(desc) -> tuple:
+    """How one position's K (or V) of one layer lies in the pool: (rows,
+    values a row). The key/value heads as they are, or, where they are
+    narrower than the ``LANES`` a row of the chip holds and fill whole rows,
+    rows of ``LANES`` with ``LANES / head_dim`` heads side by side in each
+    (heads of 64: two a row). The order of the values is the same either
+    way; declared 64 wide, a head would lie in 128 lanes, half of them
+    padding, and the decode kernel reads whole rows
+    (``ops/paged_attention.py``)."""
+    h, dh = desc.num_kv_heads, desc.head_dim
+    if dh < LANES and LANES % dh == 0 and (h * dh) % LANES == 0:
+        return h * dh // LANES, LANES
+    return h, dh
 
 
 def init_state(cfg, num_slots: int) -> dict:
@@ -136,9 +158,16 @@ def init_state(cfg, num_slots: int) -> dict:
     rows from admission to retirement, the engine's two programs take the
     store donated beside the pool and hand it back written in place, and a
     request's first prefill chunk starts from zeros whatever the slot's last
-    request left (``engine._block_parallel``)."""
+    request left (``engine._block_parallel``). A model of short convolutions
+    (``config.ShortConv``) has {"tail": the last ``taps - 1`` values of
+    ``B * x`` [conv layers, num_slots, taps - 1, dmodel]} of its conv layers
+    alone, and no state (``engine._conv_layer``)."""
     desc = describe(cfg)
     mx = desc.mixer
+    if desc.conv is not None:
+        return {"tail": jnp.zeros(
+            (desc.count_mixer("conv"), num_slots, desc.conv.taps - 1,
+             desc.dmodel), jnp.dtype(desc.dtype))}
     if mx is None:
         return {}
     lead = (desc.n_layers, num_slots)
@@ -150,9 +179,12 @@ def init_state(cfg, num_slots: int) -> dict:
 
 def state_bytes_per_slot(cfg) -> int:
     """Bytes of ``init_state`` one slot owns across all layers; 0 for a
-    model without a state-space mixer."""
+    model without a state-space mixer or a short convolution."""
     desc = describe(cfg)
     mx = desc.mixer
+    if desc.conv is not None:
+        return (desc.count_mixer("conv") * (desc.conv.taps - 1) * desc.dmodel
+                * jnp.dtype(desc.dtype).itemsize)
     if mx is None:
         return 0
     return desc.n_layers * (
@@ -165,8 +197,9 @@ def kv_bytes_per_token(cfg, kv_dtype: Optional[str] = None) -> int:
     every head, or one latent row a layer as the pool stores it."""
     dt = jnp.dtype(kv_dtype or cfg.dtype)
     desc = describe(cfg)
-    row = desc.cache_row if desc.attention is None else row_stride(desc)
-    return desc.n_layers * row * dt.itemsize
+    if desc.attention is not None:
+        return desc.n_layers * row_stride(desc) * dt.itemsize
+    return desc.count_mixer("attention") * desc.cache_row * dt.itemsize
 
 
 def pool_bytes(cfg, paged: PagedKVConfig) -> int:

@@ -66,7 +66,7 @@ observable from the host), flagged with the chunk index; reassemble with
 Host time by cause (``Scheduler.spans``, a ``telemetry.trace.Spans``, with
 or without ``events=``): every ``tick()`` is a ``serve.tick`` span (counters
 ``n`` the tick's index, ``queued``, ``in_flight``, ``blocks_in_use`` as it
-began, and ``state_bytes_in_use`` where the model has a state-space mixer) round ``serve.admit`` (``admitted``), the engine's own
+began, and ``state_bytes_in_use`` where the model carries state) round ``serve.admit`` (``admitted``), the engine's own
 ``engine.step`` and ``serve.emit`` (the loop over the engine's events to
 the return: ``tokens``, ``retired``). They are on real time, never in the
 event stream, and under a live profiler they stand on its timeline with
@@ -288,7 +288,8 @@ class Scheduler:
     def tick(self) -> List[Tuple[str, int]]:
         """One token boundary: admit, advance the engine, retire. Returns
         the (rid, token) pairs emitted this boundary."""
-        # a model with a state-space mixer: what its slots' state holds
+        # a model that carries state (a state-space mixer, short
+        # convolutions): what its slots' state holds
         state = ({"state_bytes_in_use": self.engine.state_bytes_in_use()}
                  if self.engine.state_bytes_per_slot else {})
         with self.spans("serve.tick", n=self._tick_n, queued=len(self.queue),

@@ -87,7 +87,8 @@ def main() -> int:
         s, mb, tc = tr["num_slots"], paged.max_blocks_per_seq, tr["prefill_chunk"]
         pool = jax.eval_shape(lambda: kvcache.init_pool(mcfg, paged))
         slot = ()
-        if getattr(desc, "mixer", None) is not None:
+        if (getattr(desc, "mixer", None) is not None
+                or getattr(desc, "conv", None) is not None):
             pool = {**pool, **jax.eval_shape(
                 lambda: kvcache.init_state(mcfg, s))}
             slot = (sds((), i32),)

@@ -129,7 +129,7 @@ def test_a_mid_prefill_slot_reads_no_block(monkeypatch):
     valid = jnp.asarray([[True], [False], [True]])
     monkeypatch.setattr(eng, "paged_attention_path", kernel_path)
     attend = eng._kernel_attention({"k": pk, "v": pv}, jnp.asarray(tables),
-                                   positions, valid)
+                                   positions, valid, H)
     out = np.asarray(attend(q, pk, pv, jnp.int32(1)))
     want = np.asarray(xla_path(q[:, 0], pk, pv, 1, jnp.asarray(tables),
                                positions[:, 0]))
@@ -308,3 +308,38 @@ def test_final_logits_with_the_kernel_are_the_xla_paths(monkeypatch):
         assert np.abs(logits["xla"]).max() > 0.1
         np.testing.assert_allclose(logits["kernel"], logits["xla"], rtol=0,
                                    atol=tol)
+
+
+@pytest.mark.parametrize("storage", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", ["ragged", "free_slot_between",
+                                  "all_but_one_free", "none_decoding"])
+def test_heads_of_64_two_to_a_row(case, storage):
+    """Heads of 64, 32 query heads over 8 key/value heads (a group of 4, as
+    LFM2-24B-A2B's), laid two to a row of 128 (``kvcache.kv_lanes``): a pool
+    [L, NB, BL, 4, 128] that the kernel walks as 4 rows of 128 read by 8
+    query heads each. In interpret mode against ``_attend_grouped`` over the
+    gathered heads; a slot that is not decoding reads exactly 0."""
+    h, dh, hq, width = 8, 64, 32, 4
+    rng = np.random.default_rng(7)
+    shape = (L, NB, BL, h * dh // 128, 128)
+    pk, pv = (jnp.asarray(rng.normal(size=shape), storage) for _ in range(2))
+    slots = CASES[case]
+    s = len(slots)
+    tables = scattered_tables(rng, s, width)
+    q = jnp.asarray(rng.normal(size=(s, hq, dh)), storage)
+    pos = jnp.asarray([p or 0 for p in slots], jnp.int32)
+    decoding = np.array([p is not None for p in slots])
+    lengths = jnp.where(decoding, pos + 1, 0)
+    walk = pa.plan(lengths, width, BL, shape[3], hq // shape[3],
+                   pa.chunk_blocks(BL, width))
+    out = pa.paged_attention(q, pk, pv, jnp.int32(2), tables, walk,
+                             interpret=True)
+    ck = pk[2, tables].reshape(s, -1, h, dh)
+    cv = pv[2, tables].reshape(s, -1, h, dh)
+    want = eng._attend_grouped(q[:, None], ck, cv, pos[:, None])[:, 0]
+    out, want = np.asarray(out, np.float32), np.asarray(want, np.float32)
+    assert out.shape == (s, hq, dh)
+    np.testing.assert_allclose(out[decoding], want[decoding], rtol=0,
+                               atol=TOL[storage])
+    assert np.all(out[~decoding] == 0.0)
+    assert pa.supported(shape[3], 128, jnp.bfloat16, BL)

@@ -421,7 +421,7 @@ def test_unguarded_nan_fault_poisons_run(tmp_path, devices):
 # -------------------------------------------------- SIGTERM subprocess test
 
 _TRAIN_SCRIPT = textwrap.dedent("""
-    import os, sys
+    import os, signal, sys
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
     import jax
     jax.config.update("jax_platforms", "cpu")
@@ -439,6 +439,10 @@ _TRAIN_SCRIPT = textwrap.dedent("""
             f.write(f"{it},{loss}\\n")
             f.flush()
 
+    # the trainer's handler stands in for this one while its loop runs, and
+    # restores it after: a SIGTERM that lands once the loop has ended finds
+    # the run completed and is ignored, where the default would kill it
+    signal.signal(signal.SIGTERM, signal.SIG_IGN)
     report = train_llm_dp(
         model_cfg=LlamaConfig(vocab_size=259, dmodel=16, num_heads=2,
                               n_layers=2, ctx_size=16),

@@ -407,3 +407,58 @@ def test_state_space_serving_programs_at_published_widths(one_chip,
     # the gather of the padded table is gone from the decode step
     assert "[8,1024,4,128]" not in text
     assert "tpu_custom_call" not in prefill.as_text()
+
+
+def test_conv_expert_decode_step_at_the_cells_sizes(one_chip, monkeypatch):
+    """``decode_step`` of LFM2-24B-A2B's first ten layers (two dense conv
+    layers, two periods of attention and three convs with 64 experts each)
+    at the cell's sizes, 256 slots of 1024 positions, 16,385 blocks of 16,
+    with ``jax.default_backend`` steered so that the decode step's attention
+    is the kernel: heads of 64 two to a row of 128, so the kernel is
+    ``[256, 32, 128]`` and no gather of the padded table (``[256, 1024, 8,
+    64]``) is left; pool and tails are aliased to the results, and arguments
+    and temporaries fit the 15.75 GB a program may use."""
+    import json
+    import sys
+
+    from ddl25spring_tpu.config import ModelDescription
+    from ddl25spring_tpu.serving import engine as eng
+    from ddl25spring_tpu.serving.kvcache import (PagedKVConfig, init_pool,
+                                                 init_state)
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, os.path.join(repo, "benchmarks"))
+    from references import conv_experts as ref
+
+    with open(os.path.join(repo, "benchmarks", "configs",
+                           "lfm2-24b-a2b.json")) as f:
+        cfg = json.load(f)
+    desc = ModelDescription.from_published(
+        cfg, ctx_size=1024, dtype="bfloat16", param_dtype="bfloat16")
+    paged = PagedKVConfig(num_blocks=16385, block_len=16,
+                          max_blocks_per_seq=64, kv_dtype="bfloat16")
+    s, mb = 256, 64
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,  # noqa: E731
+                                                 sharding=one_chip)
+    params = _abstract_state(lambda: ref.make_weights(
+        0, ref.Dims.from_config(cfg), "bfloat16"), one_chip)
+    head = {k: v for k, v in params.items() if k != "runs"}
+    pool = _abstract_state(
+        lambda: {**init_pool(desc, paged), **init_state(desc, s)}, one_chip)
+    assert pool["k"].shape == (2, 16385, 16, 4, 128)
+    assert pool["tail"].shape == (8, s, 2, 2048)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    i32, f32, u32 = jnp.int32, jnp.float32, jnp.uint32
+    compiled = eng.make_decode_step(desc, paged, s, None, None).lower(
+        pool, head, params["runs"], sds((s, mb), i32), sds((s,), i32),
+        sds((s,), i32), sds((s, 2), u32), sds((s,), f32),
+        sds((s,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert re.search(r"paged_attention\S* = bf16\[256,32,128\]", text)
+    assert "256,1024,8,64" not in text and "256,1024,4,128" not in text
+    assert not re.search(r"= bf16\[2,16385,16,4,128\]\S* copy\(", text)
+    assert "ragged-dot" in text
+    m = compiled.memory_analysis()
+    donated = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(pool))
+    assert m.alias_size_in_bytes >= donated
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.75e9
