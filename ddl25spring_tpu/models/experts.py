@@ -14,7 +14,10 @@ exchange (``parallel/ep.py`` has the all-to-all of the trainer's small MoE;
 No token is dropped whatever the imbalance: the pairs are sorted by expert
 into a buffer of ``tokens x top_k`` rows, the worst case, with the pairs of
 experts held elsewhere behind the last group, and the two products are
-``lax.ragged_dot`` over the groups, so every shape is static.
+grouped over the groups, so every shape is static: the Pallas kernel
+``ops/grouped_matmul.py`` where ``grouped_path`` names it (a TPU, bf16,
+whole tiles, a layer that holds all of its experts), which computes no row
+behind the last group, else ``lax.ragged_dot``.
 
 Weights of one layer (``init_layer``): ``w_r`` [D, n_experts], ``we_gu``
 [held, D, 2 F] (gate | up), ``we_down`` [held, F, D], ``ws_gu`` [D, 2 F s],
@@ -58,6 +61,39 @@ def route(w_r: jnp.ndarray, x: jnp.ndarray, spec: ExpertLayer,
     return idx.astype(jnp.int32), top * spec.scale
 
 
+def grouped_path(spec: ExpertLayer, rows: int, d: int, n: int,
+                 dtype) -> dict:
+    """The grouped product of ``rows`` rows [rows, d] by a layer's experts
+    [held, d, n] in ``dtype`` traces to on this backend: ``{"impl":
+    "pallas" | "xla", "interpret": bool | None}``, as
+    ``serving.engine.paged_attention_path`` says it for the decode
+    attention. The kernel (``ops/grouped_matmul.py``) on a TPU, where the
+    widths are whole tiles in bf16 (``grouped_matmul.supported``) and the
+    layer holds all of its experts, so that every routed pair of a valid
+    token is computed on this chip; ``lax.ragged_dot`` everywhere else.
+    Looked up where the program is traced and where the engine is built
+    (what ``grouped_kernel_layers`` counts): a test that wants the kernel
+    in the program replaces this function."""
+    xla = {"impl": "xla", "interpret": None}
+    if (jax.default_backend() != "tpu"
+            or spec.held_count != spec.n_experts):
+        return xla
+    from ..ops import grouped_matmul as gm      # Pallas: only where it runs
+    if not gm.supported(d, n, dtype):
+        return xla
+    return {"impl": "pallas", "interpret": False}
+
+
+def kernel_paths(spec: ExpertLayer, rows: int, d: int, dtype):
+    """``grouped_path`` of both of a layer's products (gate | up, then down)
+    over ``rows`` rows of width ``d``, where both name the kernel; else
+    None (``lax.ragged_dot`` for both)."""
+    f = spec.width
+    paths = (grouped_path(spec, rows, d, 2 * f, dtype),
+             grouped_path(spec, rows, f, d, dtype))
+    return paths if all(p["impl"] == "pallas" for p in paths) else None
+
+
 def swiglu(x: jnp.ndarray, w_gu: jnp.ndarray, w_down: jnp.ndarray):
     gu = x @ w_gu.astype(x.dtype)
     f = gu.shape[-1] // 2
@@ -83,6 +119,7 @@ def expert_layer(block: dict, x: jnp.ndarray, spec: ExpertLayer,
     into (PERF.md, PR 29)."""
     n, d = x.shape
     k, held = spec.top_k, spec.held_count
+    kernel = kernel_paths(spec, n * k, d, x.dtype)
     with jax.named_scope("moe.router"):
         idx, w = route(block["w_r"], x, spec, block.get("expert_bias"))
         local = idx - spec.held_start
@@ -94,22 +131,33 @@ def expert_layer(block: dict, x: jnp.ndarray, spec: ExpertLayer,
         order = jnp.argsort(gid, stable=True)
         sizes = jnp.sum(gid[:, None] == jnp.arange(held)[None, :], axis=0,
                         dtype=jnp.int32)
-        groups = sizes if group_offset is None else lax.dynamic_update_slice(
-            jnp.zeros(block["we_gu"].shape[0], jnp.int32), sizes,
-            (group_offset,))
+        if kernel is None:
+            groups = sizes if group_offset is None else \
+                lax.dynamic_update_slice(
+                    jnp.zeros(block["we_gu"].shape[0], jnp.int32), sizes,
+                    (group_offset,))
     with jax.named_scope("moe.grouped"):
         xs = x[order // k]                                  # [N k, D]
-        gu = lax.ragged_dot(xs, block["we_gu"].astype(x.dtype), groups)
+
+        def product(i, rows, name):
+            we = block[name].astype(x.dtype)
+            if kernel is None:
+                return lax.ragged_dot(rows, we, groups)
+            from ..ops import grouped_matmul as gm
+            return gm.grouped_matmul(rows, we, sizes, group_offset,
+                                     interpret=kernel[i]["interpret"])
+        gu = product(0, xs, "we_gu")
         f = gu.shape[-1] // 2
         act = jax.nn.silu(gu[:, :f]) * gu[:, f:]
-        out = lax.ragged_dot(act, block["we_down"].astype(x.dtype), groups)
+        out = product(1, act, "we_down")
     shared = None
     if spec.n_shared:
         with jax.named_scope("moe.shared"):
             shared = swiglu(x, block["ws_gu"], block["ws_down"])
     with jax.named_scope("moe.combine"):
         # back to (token, choice) order; rows behind the last group hold
-        # nothing that was computed, and weigh nothing
+        # nothing that was computed (the kernel leaves them as they were,
+        # NaN included), so they are dropped by a where, never a product
         out = out[jnp.argsort(order)].reshape(n, k, d)
         wk = jnp.where(here, w, 0.0)
         routed = jnp.sum(jnp.where(here[..., None],

@@ -96,7 +96,7 @@ from jax import lax
 
 from ..config import LlamaConfig, ModelDescription, describe
 from .. import nn
-from ..models import generate, llama
+from ..models import experts, generate, llama
 from ..telemetry.trace import Spans
 from .kvcache import (TRASH_BLOCK, BlockAllocator, PagedKVConfig, blocks_for,
                       init_pool, init_state, state_bytes_per_slot)
@@ -976,7 +976,10 @@ class Engine:
     kernel is its attention (``paged_attention_path``); their ratio is the
     share of the read that was required; ``walk_items`` = the kernel's
     work items, one chunk of a decoding slot's live blocks each
-    (``ops.paged_attention.plan``), 0 where it gathers;
+    (``ops.paged_attention.plan``), 0 where it gathers; on both dispatch
+    spans ``grouped_kernel_layers`` = the expert layers of the dispatched
+    program whose grouped product is the kernel (``experts.grouped_path``),
+    0 where it is ``lax.ragged_dot`` and in a model without experts;
     ``engine.decode.book``:
     ``emitted``). A model with
     expert layers adds ``pairs_routed`` to both dispatch spans and, read
@@ -1065,6 +1068,11 @@ class Engine:
             from ..ops import chunk_attention as ca
             self._chunk_key_block = ca.blocks(prefill_chunk,
                                               paged.max_seq_len)[1]
+        # The expert layers whose grouped product is the kernel in the
+        # prefill program and in the decode program: what
+        # ``grouped_kernel_layers`` counts.
+        self._chunk_kernel_layers = self._grouped_kernel_layers(prefill_chunk)
+        self._step_kernel_layers = self._grouped_kernel_layers(num_slots)
         self.allocator = BlockAllocator(paged.num_blocks)
         self._admit_seq = 0
         self.slots: List[Optional[_Slot]] = [None] * num_slots
@@ -1179,6 +1187,16 @@ class Engine:
             "attn_norm": f["attn_norm"], "wq": wq, "wk": wk, "wv": wv,
             "wo": f["wo"], "mlp_norm": f["mlp_norm"], "w_gate": w_gate,
             "w_up": w_up, "w_down": f["w_down"]}}
+
+    def _grouped_kernel_layers(self, tokens: int) -> int:
+        """Expert layers whose grouped product is the kernel
+        (``experts.kernel_paths``) in a program of ``tokens`` rows."""
+        ex = self.desc.experts
+        if ex is None or experts.kernel_paths(
+                ex, tokens * ex.top_k, self.desc.dmodel,
+                jnp.dtype(self.desc.dtype)) is None:
+            return 0
+        return self.desc.layer_kinds.count("experts")
 
     def _note_routing(self, span, stats=None, tokens: int = 0) -> None:
         """At a wait for the device: write this step's routing counters,
@@ -1422,7 +1440,9 @@ class Engine:
             routed["scan_chunks"] = blocks_for(n_valid, min(mx.chunk, tc))
         with self.spans("engine.prefill.dispatch", slot=s, seq=slot.seq,
                         off=off, n_valid=n_valid, final=int(is_final),
-                        attended_positions=attended, **routed):
+                        attended_positions=attended,
+                        grouped_kernel_layers=self._chunk_kernel_layers,
+                        **routed):
             self.pool, tok, new_key, *stats = self._prefill(
                 self.pool, self._head, self.fused,
                 table_row, chunk_j, *scalars, self.keys[s], temp, *of_slot)
@@ -1482,10 +1502,13 @@ class Engine:
             items = int((-(-blocks // self._walk_chunk)).sum())
         else:
             gathered = self.num_slots * width * bl
+        kernel_layers = (self._step_kernel_layers if tq == 1 else
+                         self._grouped_kernel_layers(self.num_slots * tq))
         return {**routed, "dispatch": self.decode_dispatches,
                 "active": int(active.sum()),
                 "live_positions": int(live.sum()),
-                "gathered_positions": gathered, "walk_items": items}
+                "gathered_positions": gathered, "walk_items": items,
+                "grouped_kernel_layers": kernel_layers}
 
     def _advance_decode(self) -> List[TokenEvent]:
         with self.spans("engine.decode.stage"):

@@ -14,7 +14,9 @@ periods of (attention, conv, conv) and what is left of one.
 (b) the selection bias chooses and does not weigh;
 (c) the pool holds the attention layers alone, the store the conv layers
     alone; a model of no attention layer has no pool and runs;
-(d) the decode kernel at heads of 64 (interpret mode) in the engine;
+(d) the decode kernel at heads of 64 (interpret mode) in the engine; the
+    experts' grouped-product kernel (interpret mode) against the reference,
+    and where it is chosen;
 (e) ``ModelDescription.from_published`` on the benchmark's configuration file.
 """
 
@@ -356,6 +358,58 @@ def test_the_engine_is_built_where_the_backend_is_a_tpu(weights,
     engine = eng.Engine(weights, description(), paged, 2, prefill_chunk=CHUNK)
     assert engine._chunk_key_block == 0
     assert engine._walk_chunk == 4
+    # experts of 32: no whole tile, so the grouped product stays ragged_dot
+    assert engine._step_kernel_layers == engine._chunk_kernel_layers == 0
+
+
+# ------------------------------------------- (d) the grouped-product kernel
+
+def grouped_kernel_path(spec, rows, d, n, dtype):
+    return {"impl": "pallas", "interpret": True}
+
+
+def test_the_program_with_the_grouped_kernel_holds_the_reference(
+        weights, sequences, reference_logits, monkeypatch):
+    """The float32 program with the experts' grouped product the kernel
+    (interpret mode) against the float32 reference, at the limit the
+    ``ragged_dot`` program is held to; and its decode step holds the
+    kernel in each of its 7 expert layers and no ``ragged_dot``."""
+    monkeypatch.setattr(experts, "grouped_path", grouped_kernel_path)
+    desc = description()
+    got, _ = paged_logits(weights, desc, sequences, CHUNK)
+    assert worst_gap(got, reference_logits) < TOLERANCE
+    engine = eng.Engine(weights, desc, PAGED, 3, prefill_chunk=CHUNK)
+    assert engine._step_kernel_layers == engine._chunk_kernel_layers == 7
+    jaxpr = str(jax.make_jaxpr(engine._decode)(
+        engine.pool, engine._head, engine.fused, jnp.array(engine.tables),
+        jnp.array(engine.last_tok), jnp.array(engine.pos), engine.keys,
+        jnp.array(engine.temps), jnp.zeros(3, bool)))
+    assert "grouped_matmul" in jaxpr and "ragged_dot" not in jaxpr
+
+
+@pytest.mark.parametrize("held", [16, 4])
+def test_the_kernel_is_chosen_where_a_layer_holds_all_its_experts(
+        held, monkeypatch):
+    """On a TPU (the backend steered), bf16 and whole tiles: a layer that
+    holds all 16 of its experts traces the kernel; one that holds 4 of them,
+    as A.X-K1's chip holds 12 of 192, keeps ``ragged_dot``, whose rows of
+    pairs held elsewhere are S13's to skip."""
+    import dataclasses
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    spec = dataclasses.replace(description("bfloat16").experts, width=128,
+                               held_count=held)
+    rng = np.random.default_rng(5)
+    block = {"w_r": jnp.asarray(rng.normal(size=(256, 16)), jnp.bfloat16),
+             "we_gu": jnp.zeros((2 * held, 256, 256), jnp.bfloat16),
+             "we_down": jnp.zeros((2 * held, 128, 256), jnp.bfloat16)}
+    x = jnp.zeros((32, 256), jnp.bfloat16)
+    jaxpr = str(jax.make_jaxpr(lambda b, x: experts.expert_layer(
+        b, x, spec, None, jnp.int32(held)))(block, x))
+    whole = held == spec.n_experts
+    assert (experts.kernel_paths(spec, 128, 256, jnp.bfloat16)
+            is not None) == whole
+    assert ("grouped_matmul" in jaxpr) == whole
+    assert ("ragged_dot" in jaxpr) != whole
 
 
 # ------------------------------------------- (e) the configuration's file

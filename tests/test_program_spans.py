@@ -86,12 +86,14 @@ SERVE_SPANS = {
     "engine.prefill.stage": ("engine.step", set()),
     "engine.prefill.dispatch": ("engine.step",
                                 {"slot", "seq", "off", "n_valid", "final",
-                                 "attended_positions"}),
+                                 "attended_positions",
+                                 "grouped_kernel_layers"}),
     "engine.prefill.fetch": ("engine.step", set()),
     "engine.decode.stage": ("engine.step", set()),
     "engine.decode.dispatch": ("engine.step",
                                {"dispatch", "active", "live_positions",
-                                "gathered_positions", "walk_items"}),
+                                "gathered_positions", "walk_items",
+                                "grouped_kernel_layers"}),
     "engine.decode.fetch": ("engine.step", set()),
     "engine.decode.book": ("engine.step", {"emitted"}),
     "serve.emit": ("serve.tick", {"tokens", "retired"}),
@@ -193,6 +195,7 @@ def test_dispatch_counters_give_what_the_slot_state_ledger_reckons(served):
                     jnp.dtype(TINY.dtype))["impl"] == "xla"
                 assert c["gathered_positions"] == 3 * PAGED.max_seq_len
                 assert c["walk_items"] == 0
+                assert c["grouped_kernel_layers"] == 0
         rows.append((tokens, context))
     reckoned = [(r["tokens_processed"], r["context_sum"])
                 for r in ledger.rows]
@@ -203,7 +206,8 @@ def test_dispatch_counters_give_what_the_slot_state_ledger_reckons(served):
     # the attention, the live keys in key blocks: tests/test_chunk_attention.py)
     assert any(e["counters"] == {"slot": 2, "seq": 3, "off": 8, "n_valid": 1,
                                  "final": 1,
-                                 "attended_positions": PAGED.max_seq_len}
+                                 "attended_positions": PAGED.max_seq_len,
+                                 "grouped_kernel_layers": 0}
                for e in events if e["name"] == "engine.prefill.dispatch")
     assert steps == [{k: s[k] for k in ("tick", "active", "live_positions")}
                      for s in ledger.decode_steps]
@@ -214,6 +218,59 @@ def test_dispatch_counters_give_what_the_slot_state_ledger_reckons(served):
                   if e["name"] == "serve.emit")
     assert emitted == sum(len(r.tokens)
                           for r in served["sched"].records.values())
+
+
+def tiny_engine(kind):
+    """A tiny engine of ``kind`` (the dense ``TINY``; the latent-attention
+    and short-convolution models of tests/test_latent_experts.py and
+    tests/test_conv_experts.py, with their reference's weights) and the
+    number of its expert layers."""
+    if kind == "dense":
+        return Engine(llama.init_llama(jax.random.key(0), TINY), TINY,
+                      PAGED, 2, prefill_chunk=8), 0
+    from ddl25spring_tpu.config import ModelDescription
+    mod = __import__(f"test_{kind}_experts")
+    desc = ModelDescription.from_published(
+        mod.CFG, ctx_size=mod.PAGED.max_seq_len, dtype="float32",
+        param_dtype="float32")
+    engine = Engine(mod.ref.make_weights(3, mod.DIMS, "float32"), desc,
+                    mod.PAGED, 2, prefill_chunk=8)
+    return engine, desc.layer_kinds.count("experts")
+
+
+@pytest.mark.parametrize("kind,steered", [
+    ("dense", False), ("dense", True), ("latent", False), ("conv", False),
+    ("conv", True)])
+def test_grouped_kernel_layers_on_both_dispatch_spans(kind, steered,
+                                                      monkeypatch):
+    """``grouped_kernel_layers`` on ``engine.prefill.dispatch`` and
+    ``engine.decode.dispatch``: the expert layers whose grouped product is
+    the kernel, where ``experts.grouped_path`` is steered to it (interpret
+    mode); 0 on this backend's ``ragged_dot`` and in a model without
+    experts, steered or not."""
+    from ddl25spring_tpu.models import experts
+    if steered:
+        monkeypatch.setattr(experts, "grouped_path", lambda *a: {
+            "impl": "pallas", "interpret": True})
+    engine, expert_layers = tiny_engine(kind)
+    want = expert_layers if steered else 0
+    seen = []
+    spans = engine.spans
+
+    def recording(name, **counters):
+        if name.endswith(".dispatch"):
+            seen.append((name, counters["grouped_kernel_layers"]))
+        return spans(name, **counters)
+
+    engine.spans = recording
+    rng = np.random.default_rng(1)
+    engine.admit(rng.integers(1, 250, 11).astype(np.int32), 3)
+    while engine.busy:
+        engine.step()
+    assert {n for n, _ in seen} == {"engine.prefill.dispatch",
+                                    "engine.decode.dispatch"}
+    assert all(v == want for _, v in seen)
+    assert kind != "conv" or expert_layers == 7
 
 
 # ------------------------------------------------------------ named scopes

@@ -416,8 +416,10 @@ def test_conv_expert_decode_step_at_the_cells_sizes(one_chip, monkeypatch):
     with ``jax.default_backend`` steered so that the decode step's attention
     is the kernel: heads of 64 two to a row of 128, so the kernel is
     ``[256, 32, 128]`` and no gather of the padded table (``[256, 1024, 8,
-    64]``) is left; pool and tails are aliased to the results, and arguments
-    and temporaries fit the 15.75 GB a program may use."""
+    64]``) is left; the experts' grouped products are the kernel
+    (``experts.grouped_path``: every layer holds all 64 experts); pool and
+    tails are aliased to the results, and arguments and temporaries fit the
+    15.75 GB a program may use."""
     import json
     import sys
 
@@ -457,7 +459,14 @@ def test_conv_expert_decode_step_at_the_cells_sizes(one_chip, monkeypatch):
     assert re.search(r"paged_attention\S* = bf16\[256,32,128\]", text)
     assert "256,1024,8,64" not in text and "256,1024,4,128" not in text
     assert not re.search(r"= bf16\[2,16385,16,4,128\]\S* copy\(", text)
-    assert "ragged-dot" in text
+    # every expert layer holds all 64 of its experts, so the grouped products
+    # are the kernel, under ``moe.grouped``, and ``ragged_dot`` is gone
+    assert "ragged-dot" not in text
+    kernels = re.findall(r"grouped_matmul\S* = bf16\[(\d+,\d+)\]\S* "
+                         r"custom-call\(.*op_name=\"([^\"]*)\"", text)
+    assert {shape for shape, _ in kernels} == {"1024,3072", "1024,2048"}
+    assert all("/moe.grouped/" in path and path.endswith("pallas_call")
+               for _, path in kernels)
     m = compiled.memory_analysis()
     donated = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(pool))
     assert m.alias_size_in_bytes >= donated
