@@ -298,3 +298,14 @@ def measure_comm(fn, *args, **kwargs) -> Optional[CommProfile]:
         if attempt == 0:
             jax.clear_caches()
     return CommProfile([])       # traced fresh; genuinely no collectives
+
+
+def dispatch_counters(profile: CommProfile) -> Dict[str, int]:
+    """A traced call's exchange as the integer counters the trainers put on
+    ``train.dispatch`` (docs/COMPONENTS.md): ``sync_payload_bytes``,
+    ``sync_wire_bytes`` (per device, ring factors and ``scale`` applied)
+    and ``collectives`` (calls)."""
+    return {"sync_payload_bytes": int(profile.payload_bytes_per_step),
+            "sync_wire_bytes": int(round(
+                profile.wire_bytes_per_device_per_step)),
+            "collectives": int(sum(r.scale for r in profile.records))}

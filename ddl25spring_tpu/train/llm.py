@@ -153,21 +153,21 @@ def _emit_manifest(telemetry, *, trainer: str, model_cfg, train_cfg,
                    mesh, start_step: int, step_fn, state, n_data: int,
                    steps_per_dispatch: int = 1, windowed: bool = False,
                    overlap_microbatches: int = 1,
-                   preflight: Optional[dict] = None) -> None:
+                   preflight: Optional[dict] = None):
     """Open a telemetry run: one manifest event carrying the configuration
     and the step's static communication profile (telemetry/comm.py —
     measured by abstract tracing BEFORE the first real call, so the trace
     lands in the jit cache and costs nothing extra). Must run on the
     UNGUARDED step: StepGuard's host-side logic cannot be eval_shape'd.
-    ``steps_per_dispatch > 1`` traces the fused K-step driver over its
-    [K, B, T] window — the profile then covers one DISPATCH (K steps), with
-    per-step normalization carried alongside (CommProfile.as_dict)."""
+    ``steps_per_dispatch > 1`` traces the K-step driver's [K, B, T] window:
+    the profile covers one DISPATCH, per-step normalization alongside
+    (CommProfile.as_dict). Returns the profile (None without telemetry)."""
     if telemetry is None:
-        return
+        return None
     import dataclasses
 
     from ..telemetry import measure_comm
-    comm_profile = None
+    comm_profile = profile = None
     try:
         batch_shape = (n_data * train_cfg.batch_size, train_cfg.seq_len)
         if steps_per_dispatch > 1 or windowed:
@@ -176,10 +176,9 @@ def _emit_manifest(telemetry, *, trainer: str, model_cfg, train_cfg,
             batch_shape = (steps_per_dispatch,) + batch_shape
         batch_sds = jax.ShapeDtypeStruct(batch_shape, jnp.int32)
         profile = measure_comm(step_fn, state, batch_sds)
-        comm_profile = (profile.as_dict(
+        comm_profile = None if profile is None else profile.as_dict(
             steps_per_dispatch=steps_per_dispatch,
             overlap_microbatches=overlap_microbatches)
-            if profile is not None else None)
     except Exception:
         pass                       # telemetry must never sink a trainer
     device = mesh.devices.flat[0]
@@ -206,6 +205,7 @@ def _emit_manifest(telemetry, *, trainer: str, model_cfg, train_cfg,
         # profile so obs_report's memory section can table
         # preflight-vs-measured without re-deriving the model.
         **({} if preflight is None else {"preflight": preflight}))
+    return profile
 
 
 def _fault_extra(step_fn) -> dict:
@@ -261,7 +261,7 @@ def _run_loop(step_fn, state, batches, train_cfg: TrainConfig, shard_fn, *,
               telemetry=None, steps_per_dispatch: int = 1,
               window_shard_fn=None, numerics=None,
               numerics_every: int = 0, compile_watch=None,
-              injit_guard: bool = False,
+              injit_guard: bool = False, comm_profile=None,
               on_checkpoint=None, memory_meter=None) -> LLMTrainReport:
     """The training loop both trainers share: stream replay on resume,
     per-iteration loss sinking/logging, periodic + final checkpoint saves,
@@ -351,7 +351,7 @@ def _run_loop(step_fn, state, batches, train_cfg: TrainConfig, shard_fn, *,
     # stream); chunked mode traces every dispatch (already coarse).
     tracer = Tracer(telemetry.events if telemetry is not None else None,
                     phases=spans)
-    _phase = _phase_spans(tracer, spans)
+    _phase, sync = _phase_spans(tracer, spans), _Exchange(comm_profile)
 
     last_event_t = time.perf_counter()
     last_event_it = start_step - 1
@@ -450,7 +450,7 @@ def _run_loop(step_fn, state, batches, train_cfg: TrainConfig, shard_fn, *,
                 t_iter = time.perf_counter()
                 n_compiles = (len(compile_watch.compiles)
                               if compile_watch is not None else 0)
-                with _phase("dispatch", droot, "compute", it=it) as csp:
+                with sync.dispatch(_phase, droot, it) as csp:
                     state, out = step_fn(state, shard_fn(host_batch))
                     if (droot is not None and compile_watch is not None
                             and len(compile_watch.compiles) > n_compiles):
@@ -578,7 +578,7 @@ def _run_loop(step_fn, state, batches, train_cfg: TrainConfig, shard_fn, *,
                 t_iter = time.perf_counter()
                 n_compiles = (len(compile_watch.compiles)
                               if compile_watch is not None else 0)
-                with _phase("dispatch", droot, "compute", it=it0) as csp:
+                with sync.dispatch(_phase, droot, it0) as csp:
                     state, out = step_fn(state, window_shard_fn(window))
                     if (droot is not None and compile_watch is not None
                             and len(compile_watch.compiles) > n_compiles):
@@ -1462,11 +1462,11 @@ def train_llm_dp(model_cfg: Optional[LlamaConfig] = None,
                               opt_state_bytes=pre["opt_state_bytes"],
                               residual_bytes=pre["residual_bytes"] or None,
                               window_bytes=pre["window_bytes"] or None)
-    _emit_manifest(telemetry, trainer="dp", model_cfg=model_cfg,
-                   train_cfg=train_cfg, mesh=mesh, start_step=start_step,
-                   step_fn=step_fn, state=state, n_data=n_data,
-                   steps_per_dispatch=spd, windowed=elastic,
-                   overlap_microbatches=max(1, ovl), preflight=pre)
+    comm_profile = _emit_manifest(
+        telemetry, trainer="dp", model_cfg=model_cfg, train_cfg=train_cfg,
+        mesh=mesh, start_step=start_step, step_fn=step_fn, state=state,
+        n_data=n_data, steps_per_dispatch=spd, windowed=elastic,
+        overlap_microbatches=max(1, ovl), preflight=pre)
     if fault_plan is None and resilience is not None and resilience.faults:
         fault_plan = resilience.fault_plan()   # resolve ONCE: the elastic
         #   rebuild must re-wrap the same schedule, not a fresh counter's
@@ -1508,7 +1508,7 @@ def train_llm_dp(model_cfg: Optional[LlamaConfig] = None,
                      start_step=start_step, ckpt=ckpt,
                      checkpoint_every=checkpoint_every, loss_sink=loss_sink,
                      sink_every=sink_every, log_every=log_every,
-                     log_fn=log_fn,
+                     log_fn=log_fn, comm_profile=comm_profile,
                      warmup_steps_excluded=warmup_steps_excluded,
                      stats=stats, telemetry=telemetry,
                      steps_per_dispatch=spd,
@@ -1773,11 +1773,11 @@ def train_llm_pp(model_cfg: Optional[LlamaConfig] = None,
         resilience=resilience, stats=stats)
     if done:
         return LLMTrainReport(resilience=stats)
-    _emit_manifest(telemetry, trainer="pp", model_cfg=model_cfg,
-                   train_cfg=train_cfg, mesh=mesh, start_step=start_step,
-                   step_fn=step_fn, state=state, n_data=n_data,
-                   steps_per_dispatch=spd, windowed=elastic,
-                   overlap_microbatches=max(1, ovl))
+    comm_profile = _emit_manifest(
+        telemetry, trainer="pp", model_cfg=model_cfg, train_cfg=train_cfg,
+        mesh=mesh, start_step=start_step, step_fn=step_fn, state=state,
+        n_data=n_data, steps_per_dispatch=spd, windowed=elastic,
+        overlap_microbatches=max(1, ovl))
     if fault_plan is None and resilience is not None and resilience.faults:
         fault_plan = resilience.fault_plan()   # resolve ONCE: the elastic
         #   rebuild must re-wrap the same schedule, not a fresh counter's
@@ -1816,7 +1816,7 @@ def train_llm_pp(model_cfg: Optional[LlamaConfig] = None,
                      start_step=start_step, ckpt=ckpt,
                      checkpoint_every=checkpoint_every, loss_sink=loss_sink,
                      sink_every=sink_every, log_every=log_every,
-                     log_fn=log_fn,
+                     log_fn=log_fn, comm_profile=comm_profile,
                      warmup_steps_excluded=warmup_steps_excluded,
                      stats=stats, telemetry=telemetry,
                      steps_per_dispatch=spd,
@@ -2045,11 +2045,11 @@ def train_llm_tp(model_cfg: Optional[LlamaConfig] = None,
         resilience=resilience, stats=stats)
     if done:
         return LLMTrainReport(resilience=stats)
-    _emit_manifest(telemetry, trainer="tp", model_cfg=model_cfg,
-                   train_cfg=train_cfg, mesh=mesh, start_step=start_step,
-                   step_fn=step_fn, state=state, n_data=n_data,
-                   steps_per_dispatch=spd, windowed=elastic,
-                   overlap_microbatches=max(1, ovl))
+    comm_profile = _emit_manifest(
+        telemetry, trainer="tp", model_cfg=model_cfg, train_cfg=train_cfg,
+        mesh=mesh, start_step=start_step, step_fn=step_fn, state=state,
+        n_data=n_data, steps_per_dispatch=spd, windowed=elastic,
+        overlap_microbatches=max(1, ovl))
     if fault_plan is None and resilience is not None and resilience.faults:
         fault_plan = resilience.fault_plan()   # resolve ONCE: the elastic
         #   rebuild must re-wrap the same schedule, not a fresh counter's
@@ -2090,7 +2090,7 @@ def train_llm_tp(model_cfg: Optional[LlamaConfig] = None,
                      start_step=start_step, ckpt=ckpt,
                      checkpoint_every=checkpoint_every, loss_sink=loss_sink,
                      sink_every=sink_every, log_every=log_every,
-                     log_fn=log_fn,
+                     log_fn=log_fn, comm_profile=comm_profile,
                      warmup_steps_excluded=warmup_steps_excluded,
                      stats=stats, telemetry=telemetry,
                      steps_per_dispatch=spd,
@@ -2099,3 +2099,47 @@ def train_llm_tp(model_cfg: Optional[LlamaConfig] = None,
                      numerics_every=train_cfg.numerics_every,
                      compile_watch=compile_watch,
                      on_checkpoint=on_checkpoint)
+
+
+class _Exchange:
+    """The step's gradient exchange on every ``train.dispatch`` of
+    ``_run_loop`` (docs/COMPONENTS.md): the integer counters
+    ``sync_payload_bytes``, ``sync_wire_bytes`` and ``collectives``, from
+    the ``comm`` records the step's own trace leaves, so the step is never
+    traced a second time. Each dispatch runs inside ``comm.collecting()``;
+    the one that traced (the first) adds the counters through
+    ``set_metadata`` at its end, and later ones, which hit the jit cache
+    and leave no records, pass them as keyword ints. Where the step was
+    traced before the loop (the manifest's ``measure_comm``), ``profile``
+    hands that trace's records in. It sits at the end of the module: the
+    lines of the step's call stack (``_run_loop``'s call of the step, the
+    trainers' call of ``_run_loop``) are part of the compile cache's key
+    (introspect.CompileWatch), and lines added above them would change
+    every training program's key."""
+
+    def __init__(self, profile=None):
+        from ..telemetry import comm
+
+        self.counters = (None if profile is None
+                         else comm.dispatch_counters(profile))
+
+    def dispatch(self, phase, root, it: int):
+        """``phase("dispatch", root, "compute", it=it)`` with the counters,
+        collecting the records of a dispatch that traces the step."""
+        import contextlib
+
+        from ..telemetry import comm
+
+        @contextlib.contextmanager
+        def span():
+            with phase("dispatch", root, "compute", it=it,
+                       **(self.counters or {})) as csp:
+                with comm.collecting() as records:
+                    yield csp
+                if records or self.counters is None:
+                    self.counters = comm.dispatch_counters(
+                        comm.CommProfile(records))
+                    if hasattr(csp, "set_metadata"):  # the profiler's span
+                        csp.set_metadata(**self.counters)
+
+        return span()
