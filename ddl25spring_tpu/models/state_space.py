@@ -21,7 +21,9 @@ padded position has ``dt = 0``, which carries ``S`` through unchanged
 with ``n_valid = 0`` (not decoding) keeps state and tail as they were.
 
 Two forms of the recurrence with one result. ``scan_step`` (``T == 1``, a
-decode step) is the recurrence itself, elementwise over the state. For a
+decode step) is the recurrence itself, elementwise over the state; on a TPU
+the engine's decode program runs it as a kernel over the whole store
+(``step_path``, ``ops/state_step.py``), with the same arithmetic. For a
 prefill chunk ``scan_chunked`` is the chunked scan (SSD): within a chunk of
 ``chunk`` positions the outputs are a masked product ``(C B^T * decay) x``,
 each chunk's contribution to the state is one product, the states go from
@@ -40,6 +42,7 @@ Weights of a layer (``init_layer``; stacked in ``params["runs"][0]``):
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Tuple
 
@@ -97,6 +100,25 @@ def scan_step(x, dt, a, b, c, s0, mx: StateSpaceMixer):
     return y.reshape(x.shape), s1.reshape(s0.shape)
 
 
+def step_path(mx: StateSpaceMixer, t: int) -> dict:
+    """The recurrence of a call over ``t`` positions a slot traces to on
+    this backend: ``{"impl": "pallas" | "xla", "interpret": bool | None}``,
+    as ``serving.engine.paged_attention_path`` says it for the decode
+    attention. The kernel (``ops/state_step.py``) is the decode program's,
+    ``t == 1``, on a TPU, where the state is float32 and its widths whole
+    lane tiles (``state_step.supported``); ``scan_step`` everywhere else,
+    and ``scan_chunked`` for a chunk. Looked up where the program is traced
+    and where the engine is built (what ``state_kernel_layers`` counts): a
+    test that wants the kernel in the program replaces this function."""
+    xla = {"impl": "xla", "interpret": None}
+    if t != 1 or jax.default_backend() != "tpu":
+        return xla
+    from ..ops import state_step       # Pallas: only where it runs
+    if not state_step.supported(mx.head_dim, mx.state, mx.state_dtype):
+        return xla
+    return {"impl": "pallas", "interpret": False}
+
+
 def scan_chunked(x, dt, a, b, c, s0, mx: StateSpaceMixer):
     """The recurrence over T positions as the chunked scan: x [S, T, H, P],
     dt [S, T, H] (0 at a padded position), a [H], b, c [S, T, G, N], s0
@@ -151,12 +173,16 @@ def gated_norm(block: dict, y: jnp.ndarray, z: jnp.ndarray,
 
 
 def mixer(block: dict, u: jnp.ndarray, state: jnp.ndarray, tail: jnp.ndarray,
-          n_valid: jnp.ndarray, desc: ModelDescription):
+          n_valid: jnp.ndarray, desc: ModelDescription, step=None):
     """The mixer over u [S, T, D] (normed) from each slot's carried ``state``
     [S, H, P, N] and ``tail`` [S, conv - 1, conv_dim], of which the first
     ``n_valid`` [S] positions are real -> (its output [S, T, D] before
-    ``ssm_out_multiplier``, the new state, the new tail). The scopes are how
-    a device trace tells the parts apart (docs/COMPONENTS.md)."""
+    ``ssm_out_multiplier``, the new state, the new tail). ``step`` (T == 1
+    only) does the recurrence's one step in place of ``scan_step``:
+    ``step(x, dt, a, b, c, state) -> (y, state)``, handed ``state`` as the
+    caller gave it (the engine's kernel takes the whole store there,
+    ``ops/state_step.py``). The scopes are how a device trace tells the
+    parts apart (docs/COMPONENTS.md)."""
     mx, m = desc.mixer, desc.multipliers
     s, t, _ = u.shape
     sd = jnp.dtype(mx.state_dtype)
@@ -181,8 +207,8 @@ def mixer(block: dict, u: jnp.ndarray, state: jnp.ndarray, tail: jnp.ndarray,
         c = xbc[..., mx.d_inner + gn:].reshape(s, t, mx.groups, -1)
         xs, bs, cs = x.astype(sd), b.astype(sd), c.astype(sd)
         if t == 1:
-            y, state = scan_step(xs[:, 0], dt[:, 0], a, bs[:, 0], cs[:, 0],
-                                 state, mx)
+            y, state = (step or functools.partial(scan_step, mx=mx))(
+                xs[:, 0], dt[:, 0], a, bs[:, 0], cs[:, 0], state)
             y = y[:, None]
         else:
             y, state = scan_chunked(xs, dt, a, bs, cs, state, mx)

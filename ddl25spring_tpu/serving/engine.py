@@ -35,7 +35,10 @@ grouped-query attention in every block, ``models/state_space.py``) goes
 through ``_forward_parallel``: the same pool of K and V, of the key/value
 heads only, and beside it in the same donated tree a state store of one row a
 SLOT (``kvcache.init_state``), which a prefill chunk carries on for its slot
-and a decode step for every decoding slot (tests/test_state_space.py).
+and a decode step for every decoding slot (tests/test_state_space.py); on a
+TPU the decode step's recurrence is a kernel that updates the decoding
+slots' rows of the whole store in place (``ops/state_step.py``;
+``state_space.step_path`` picks it, tests/test_state_step.py).
 A ``ModelDescription`` with a mixer a layer (``mixers``: short convolutions,
 ``models/short_conv.py``, beside grouped-query attention layers) goes through
 ``_forward_hybrid``: one scan a group of whole periods of the layer pattern,
@@ -478,11 +481,33 @@ def _slot_rows(layer, slot, positions: jnp.ndarray):
     return take, put
 
 
+def _state_step(n_valid: jnp.ndarray, t: int, desc: ModelDescription):
+    """``_block_parallel``'s ``step`` for a program of ``t`` rows a slot
+    over every slot where ``state_space.step_path`` names the kernel (the
+    decode program on a TPU), else None: ``step(layer)`` is the
+    mixer's one step over layer ``layer`` of the WHOLE store, updated in
+    place. The slots it visits, those decoding (``n_valid`` [S] > 0), are
+    the same for every layer, so they are reckoned here, once, outside the
+    layer scan (``ops.state_step.plan``)."""
+    from ..models import state_space
+
+    path = state_space.step_path(desc.mixer, t)
+    if path["impl"] != "pallas":
+        return None
+    from ..ops import state_step as ss
+    walk = ss.plan(n_valid > 0)
+
+    def step(layer):
+        return lambda x, dt, a, b, c, store: ss.state_step(
+            store, layer, x, dt, a, b, c, walk, interpret=path["interpret"])
+    return step
+
+
 def _block_parallel(block: dict, layer: jnp.ndarray, pool: dict,
                     x: jnp.ndarray, positions: jnp.ndarray,
                     tables: jnp.ndarray, wblk: jnp.ndarray,
                     woff: jnp.ndarray, n_valid: jnp.ndarray, slot,
-                    desc: ModelDescription, attend=None):
+                    desc: ModelDescription, attend=None, step=None):
     """One layer of a model whose two mixers stand side by side
     (``models/state_space.py``), layer number ``layer``, over x [S, T, D]:
     ``u = norm(x)``; ``x + ssm_out SSM(u) + attention_out Attn(attention_in
@@ -495,7 +520,10 @@ def _block_parallel(block: dict, layer: jnp.ndarray, pool: dict,
     chunk, S == 1; position 0 is a request's first, and starts from zeros
     whatever the slot held) or of every slot (``slot`` None: a decode
     step). ``n_valid`` [S] real positions: a slot with none keeps state and
-    tail."""
+    tail. ``step`` (``_state_step``'s, a decode step on a TPU): the mixer
+    is handed the whole store and ``step(layer)``, which updates this
+    layer's rows of it in place; else it is handed the layer's rows and they
+    are written back."""
     from ..models import state_space
 
     m, mx = desc.multipliers, desc.mixer
@@ -533,9 +561,14 @@ def _block_parallel(block: dict, layer: jnp.ndarray, pool: dict,
         att = (out.reshape(s, t, hq * dh) @ block["w_o"].astype(x.dtype)
                ) * jnp.asarray(m.attention_out, x.dtype)
     take, put = _slot_rows(layer, slot, positions)
-    y, s1, t1 = state_space.mixer(block, u, take(st), take(tl), n_valid, desc)
-    with jax.named_scope("ssm.scan"):
-        st = put(st, s1)
+    if step is None:
+        y, s1, t1 = state_space.mixer(block, u, take(st), take(tl), n_valid,
+                                      desc)
+        with jax.named_scope("ssm.scan"):
+            st = put(st, s1)
+    else:
+        y, st, t1 = state_space.mixer(block, u, st, take(tl), n_valid, desc,
+                                      step=step(layer))
     with jax.named_scope("ssm.conv"):
         tl = put(tl, t1)
     with jax.named_scope("ssm.out"):
@@ -563,13 +596,16 @@ def _forward_parallel(head: dict, runs: tuple, tokens: jnp.ndarray,
     n_valid = jnp.sum(valid, axis=1).astype(jnp.int32)
     attend = _kernel_attention(pool, tables, positions, valid,
                                desc.num_heads)
+    step = (_state_step(n_valid, tokens.shape[1], desc) if slot is None
+            else None)
     layers = jnp.arange(desc.n_layers, dtype=jnp.int32)
 
     def body(carry, layer_block):
         x, pool = carry
         layer, block = layer_block
         return _block_parallel(block, layer, pool, x, positions, tables, wblk,
-                               woff, n_valid, slot, desc, attend), None
+                               woff, n_valid, slot, desc, attend,
+                               step), None
 
     with jax.named_scope("layers"):
         (h, pool), _ = lax.scan(body, (h, pool), (layers, runs[0]))
@@ -980,6 +1016,9 @@ class Engine:
     spans ``grouped_kernel_layers`` = the expert layers of the dispatched
     program whose grouped product is the kernel (``experts.grouped_path``),
     0 where it is ``lax.ragged_dot`` and in a model without experts;
+    for a model with a state-space mixer ``state_kernel_layers`` on
+    ``engine.decode.dispatch`` = the mixer layers whose step is the kernel
+    (``state_space.step_path``), 0 where it is ``scan_step``;
     ``engine.decode.book``:
     ``emitted``). A model with
     expert layers adds ``pairs_routed`` to both dispatch spans and, read
@@ -1073,6 +1112,13 @@ class Engine:
         # ``grouped_kernel_layers`` counts.
         self._chunk_kernel_layers = self._grouped_kernel_layers(prefill_chunk)
         self._step_kernel_layers = self._grouped_kernel_layers(num_slots)
+        # The mixer layers whose step is the kernel in the decode program:
+        # what ``state_kernel_layers`` counts.
+        self._state_kernel_layers = 0
+        if mx is not None:
+            from ..models import state_space
+            if state_space.step_path(mx, 1)["impl"] == "pallas":
+                self._state_kernel_layers = self.desc.n_layers
         self.allocator = BlockAllocator(paged.num_blocks)
         self._admit_seq = 0
         self.slots: List[Optional[_Slot]] = [None] * num_slots
@@ -1493,6 +1539,9 @@ class Engine:
         if self.desc.carries_state:
             # the slots whose state the step reads and writes
             routed = {**routed, "state_slots": int(active.sum())}
+        if self.desc.mixer is not None:
+            routed["state_kernel_layers"] = (self._state_kernel_layers
+                                             if tq == 1 else 0)
         bl, width = self.paged.block_len, int(tables.shape[1])
         live = self.pos[active].astype(np.int64) + 1
         items = 0

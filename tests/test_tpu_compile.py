@@ -351,8 +351,11 @@ def test_state_space_serving_programs_at_published_widths(one_chip,
     key/value heads). Pool and state store are donated and aliased to the
     results, and neither program holds a temporary of the state's size, the
     whole store's or one layer's: the decode step updates every slot's state
-    in place, the prefill chunk one slot's. The scan's products are float32
-    (the state's type), the carried inputs of the convolution bf16."""
+    in place, the prefill chunk one slot's. The decode step's one step of
+    the recurrence is the kernel (``ops/state_step.py``), and no XLA
+    operation reads or writes the store there. The scan's products are
+    float32 (the state's type), the carried inputs of the convolution
+    bf16."""
     import json
 
     from ddl25spring_tpu.config import ModelDescription
@@ -406,6 +409,9 @@ def test_state_space_serving_programs_at_published_widths(one_chip,
     assert "tpu_custom_call" in text and "paged_attention" in text
     # the gather of the padded table is gone from the decode step
     assert "[8,1024,4,128]" not in text
+    assert "state_step" in text
+    assert not re.search(r"= f32\[2,8,32,128,256\]\S* fusion\(", text)
+    assert not re.search(r"= f32\[8,32,128,256\]", text)
     assert "tpu_custom_call" not in prefill.as_text()
 
 
